@@ -75,6 +75,7 @@ from repro.core import (
     random_factors,
     random_tensor,
 )
+from repro.launch.mesh import make_mesh
 from repro.plan import Problem, enumerate_schedules, make_executor, plan_sweep
 
 from .util import row, time_fn
@@ -164,7 +165,7 @@ def overlap_section(reps: int) -> dict:
         })
     measured = n_dev > 1 and OVERLAP_SHAPE[0] % n_dev == 0
     if measured:
-        mesh = jax.make_mesh((n_dev,), ("shard",))
+        mesh = make_mesh((n_dev,), ("shard",))
         x = random_tensor(jax.random.PRNGKey(2), OVERLAP_SHAPE)
         factors = random_factors(jax.random.PRNGKey(3), OVERLAP_SHAPE, OVERLAP_RANK)
         xs, fs = shard_problem(x, factors, mode_axes, mesh)
@@ -229,7 +230,7 @@ def schedule_section(reps: int) -> dict:
     if measured:
         from repro.dist.dist_mttkrp import shard_problem
 
-        mesh = jax.make_mesh((n_dev,), ("shard",))
+        mesh = make_mesh((n_dev,), ("shard",))
         x = random_tensor(jax.random.PRNGKey(4), SCHEDULE_SHAPE)
         factors = random_factors(jax.random.PRNGKey(5), SCHEDULE_SHAPE, SCHEDULE_RANK)
         xs, fs = shard_problem(x, factors, mode_axes, mesh)
@@ -333,7 +334,7 @@ def batched_section(batch: int, reps: int) -> dict:
         "batch_parallel": None,
     }
     if n_dev > 1 and batch % n_dev == 0:
-        mesh = jax.make_mesh((n_dev,), ("shard",))
+        mesh = make_mesh((n_dev,), ("shard",))
         bp = Problem(
             shape=BATCHED_SHAPE, rank=BATCHED_RANK, batch=batch,
             batch_axes=("shard",), axis_sizes={"shard": n_dev},

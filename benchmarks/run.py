@@ -8,6 +8,8 @@ Prints ``name,us_per_call,derived`` CSV.  Sections:
   Roofline table from dry-run artifacts (if present)  (roofline_report)
 
 ``--full`` restores paper-scale shapes (minutes-to-hours on one core).
+Compiles persist in ``<checkout>/.jax_cache`` unless
+``JAX_COMPILATION_CACHE_DIR`` names another directory.
 """
 
 from __future__ import annotations
@@ -27,6 +29,10 @@ def main() -> None:
         default=None,
     )
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
 
     from . import (
         bench_cpals,

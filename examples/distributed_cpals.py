@@ -17,12 +17,13 @@ import jax  # noqa: E402
 
 from repro.core import CPConfig, cp_als, cp_full, random_factors  # noqa: E402
 from repro.dist.dist_mttkrp import dist_cp_als  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.plan import Problem, plan_sweep  # noqa: E402
 
 
 def main():
     print(f"devices: {jax.device_count()}")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     shape, rank = (64, 48, 40), 6
     x = cp_full(None, random_factors(key, shape, rank))

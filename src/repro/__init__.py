@@ -1,3 +1,1 @@
 """repro: MTTKRP/CP-ALS framework + LM substrate on JAX."""
-
-from . import compat  # noqa: F401  -- installs the jax >= 0.6 aliases on old jax

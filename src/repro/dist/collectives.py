@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.launch import mesh as meshlib
 from repro.train.optimizer import OptConfig, OptState, adamw_update
 
@@ -196,7 +195,7 @@ def make_compressed_dp_step(
             new_err = jax.tree.map(lambda e: e[None], err_loc)
             return grads, new_err, loss, metrics
 
-        grads, new_err, loss, metrics = compat.shard_map(
+        grads, new_err, loss, metrics = jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(P(), P(axes), P(dspec)),

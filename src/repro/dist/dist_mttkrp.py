@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.dimtree import contract_from_partial, partial_mttkrp_range
 from repro.core.mttkrp import Method, mttkrp, mttkrp_batched
 from repro.core.tensor_ops import mode_letters
@@ -269,7 +268,7 @@ def dist_mttkrp(
             )
         return m
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -340,7 +339,7 @@ def dist_pp_pairs(
             else:
                 f_specs = [P(mode_axes.get(k), None) for k in others]
                 out_spec = P(None, mode_axes.get(n), mode_axes.get(m))
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 local_fn,
                 mesh=mesh,
                 in_specs=(
@@ -380,8 +379,9 @@ def dist_mttkrp_overlapped(
 ) -> Array:
     """Mode-``n`` MTTKRP with the completing psum hidden behind the GEMMs.
 
-    Identical placement and *bitwise-identical per-element reduction* to
-    :func:`dist_mttkrp`, but the local block is split into ``n_chunks``
+    Identical placement and per-element reduction to :func:`dist_mttkrp`
+    (equal up to fp32 rounding: XLA may block a row slab's local GEMM
+    differently from the whole block's), but the local block is split into ``n_chunks``
     row slabs along mode ``n`` and each slab's psum is issued as soon as
     its local MTTKRP finishes -- a double-buffered pipeline: the collective
     of chunk ``k`` has no data dependency on the GEMM of chunk ``k+1``, so
@@ -439,7 +439,7 @@ def dist_mttkrp_overlapped(
         ]
         return jnp.concatenate(reduced, axis=lead)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -560,7 +560,7 @@ def dist_mttkrp_compressed(
         total, new_e = compressed_psum(m, gather_axes, err_blk.reshape(m.shape))
         return total, new_e.reshape(err_blk.shape)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -710,7 +710,7 @@ def _dist_contract(
 
     contracted_factors = [factors[m] for m in contracted]
     if err is None:
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             local_exact,
             mesh=mesh,
             in_specs=(src_spec, *f_specs),
@@ -718,7 +718,7 @@ def _dist_contract(
             check_vma=False,
         )
         return fn(src, *contracted_factors)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_compressed,
         mesh=mesh,
         in_specs=(src_spec, err_spec, *f_specs),

@@ -40,6 +40,28 @@ def pad_axis(x: Array, axis: int, mult: int) -> Array:
     return jnp.pad(x, widths)
 
 
-def block(dim: int, target: int) -> int:
-    """Largest block <= target; dims smaller than target use the dim itself."""
-    return min(dim, target)
+# Mosaic (the TPU Pallas compiler) tiles the last two dims of every block
+# in (8, 128) vreg tiles: each must be a multiple of its tile or the
+# array's full extent.  Leading dims are free.
+SUBLANE = 8
+LANE = 128
+
+# Cap on the elements of one kernel's largest VMEM tile (2 MB at f32):
+# double-buffered input tiles plus in-kernel temporaries of the same size
+# stay well inside the 16 MB of VMEM a kernel may use by default.
+TILE_ELEM_BUDGET = 512 * 1024
+
+
+def block(dim: int, target: int, align: int = 1) -> int:
+    """Block size for one array axis: the whole ``dim`` when it is at most
+    ``target``, else ``target`` rounded down to a multiple of ``align``
+    (never below ``align``; the whole dim again if that covers it).
+
+    ``align`` is :data:`LANE` for a block's minor dim, :data:`SUBLANE` for
+    its second-minor dim and 1 for leading dims, so interpret mode tiles
+    exactly as the chip must.
+    """
+    if dim <= target:
+        return dim
+    b = max(align, target // align * align)
+    return dim if b >= dim else b

@@ -26,16 +26,20 @@ innermost, so each output block stays resident in VMEM across its whole
 reduction (revisited-output accumulation pattern).  The output is zeroed at
 the first reduction step via ``pl.when``.
 
-TPU tiling notes (the BlockSpec shapes define the VMEM working set):
-  * block_i x block_b is the MXU matmul tile -> multiples of 128 when the
-    dims allow (hardware-aligned); C (CP rank, typically 10-50) is padded to
-    the 128-lane boundary by the wrapper.
-  * VMEM footprint per step = T-tile (bi*bb) + A-row (C) + B-tile (bb*C)
-    + out (bi*C) floats -- e.g. bi=bb=256, C=128: ~0.5 MB, far under ~16 MB,
-    leaving headroom for double buffering of the streamed T tiles.
-  * ``a`` advances fastest among reduction steps with block size 1: the A row
-    is a (1, C) VMEM vector; K-tiles are (bb, C) -- formed and consumed, never
-    stored.
+TPU tiling notes (the BlockSpec shapes define the VMEM working set; Mosaic
+requires the last two dims of every block to be multiples of (8, 128) or
+the array's full extent):
+  * block_i x block_b is the MXU matmul tile; C (CP rank, typically 10-50)
+    is padded to the 128-lane boundary by the wrapper on TPU.
+  * ``A`` rides as an ``(A_dim, 1, C)`` array, so its ``(block_a, 1, C)``
+    block keeps a full ``(1, C)`` minor tile whatever ``block_a`` is.
+  * Each grid step walks ``block_a`` rows of ``A`` in an unrolled loop:
+    one (1, C) * (bb, C) VPU multiply forms the KRP tile, one MXU dot
+    consumes it.  At pos=0 the ``a`` axis is the tensor block's
+    second-minor dim (multiple of 8 or full); elsewhere it is a leading
+    dim and any size.
+  * VMEM per step = T-tile (ba*bi*bb) + A rows (ba*C) + B-tile (bb*C)
+    + out (bi*C) floats, double-buffered for the streamed tiles.
 """
 
 from __future__ import annotations
@@ -46,66 +50,69 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._tiling import SUBLANE
+
 Array = jax.Array
 
 
-def _kernel(t_ref, a_ref, b_ref, o_ref, *, pos: int):
-    """One grid step: o += T_tile @ (A_row * B_tile)."""
-    a_idx = pl.program_id(1)
-    b_idx = pl.program_id(2)
+def _x_tile(t_ref, j: int, pos: int, batched: bool):
+    """Row ``j`` of the block's ``a`` axis as the (..., rows, cols) matmul
+    operand: (bi, bb) at pos 0/1, (bb, bi) at pos 2 (contracted on dim 0)."""
+    if batched:
+        return t_ref[:, :, j, :] if pos == 0 else t_ref[:, j]
+    return t_ref[:, j, :] if pos == 0 else t_ref[j]
+
+
+def _kernel(t_ref, a_ref, b_ref, o_ref, *, pos: int, batched: bool):
+    """One grid step: o += sum_j T[.., a_j, ..] @ (A[a_j] * B_tile)."""
+    lead = 1 if batched else 0
+    a_idx = pl.program_id(lead + 1)
+    b_idx = pl.program_id(lead + 2)
 
     @pl.when(jnp.logical_and(a_idx == 0, b_idx == 0))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # KRP tile, formed on the fly in VMEM (row-wise Hadamard definition):
-    # (1, C) * (bb, C) -> (bb, C).  This is the object the paper materializes
-    # in HBM (Alg. 2 line 2 / Alg. 3 line 15); here it lives only in VMEM.
-    k_tile = a_ref[0, :] * b_ref[...]  # (bb, C)
+    hi = jax.lax.Precision.HIGHEST
+    # contract the tile's bb axis: dim 1 of (bi, bb) at pos 0/1, dim 0 of
+    # (bb, bi) at pos 2 -- shifted by one under a leading batch axis
+    x_dim = lead + (0 if pos == 2 else 1)
+    batch_dims = ((0,), (0,)) if batched else ((), ())
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
+    for j in range(a_ref.shape[lead]):
+        # KRP tile, formed on the fly in VMEM (row-wise Hadamard
+        # definition): (.., 1, C) * (.., bb, C) -> (.., bb, C).  This is the
+        # object the paper materializes in HBM (Alg. 2 line 2 / Alg. 3
+        # line 15); here it lives only in VMEM.
+        a_row = a_ref[:, j] if batched else a_ref[j]
+        k_tile = a_row * b_ref[...]
+        x_tile = _x_tile(t_ref, j, pos, batched).astype(k_tile.dtype)
+        acc += jax.lax.dot_general(
+            x_tile, k_tile, (((x_dim,), (lead,)), batch_dims), precision=hi,
+            preferred_element_type=jnp.float32,
+        )
+    o_ref[...] += acc.astype(o_ref.dtype)
 
-    t = t_ref[...]
-    if pos == 0:  # T block (bi, 1, bb)
-        x_tile = t[:, 0, :]
-    elif pos == 1:  # T block (1, bi, bb)
-        x_tile = t[0, :, :]
-    else:  # pos == 2: T block (1, bb, bi) -> contract over bb
-        x_tile = t[0].T
-    # MXU contraction of the streamed tensor tile with the in-VMEM KRP tile.
-    o_ref[...] += jax.lax.dot(
-        x_tile.astype(k_tile.dtype), k_tile, precision=jax.lax.Precision.HIGHEST
-    ).astype(o_ref.dtype)
 
+def _t_spec(pos: int, block_i: int, block_a: int, block_b: int, block_batch=None):
+    """Tensor BlockSpec for the (i, a, b) grid at each ``pos`` layout:
+    pos0 (I, A, B), pos1 (A, I, B), pos2 (A, B, I), plus a leading batch
+    block when ``block_batch`` is given."""
+    shape = {
+        0: (block_i, block_a, block_b),
+        1: (block_a, block_i, block_b),
+        2: (block_a, block_b, block_i),
+    }[pos]
+    order = {0: (0, 1, 2), 1: (1, 0, 2), 2: (1, 2, 0)}[pos]  # (i, a, b) slots
 
-def _kernel_batched(t_ref, a_ref, b_ref, o_ref, *, pos: int):
-    """One grid step of the batched kernel: per batch slab, o += T @ (A*B).
+    def index(*g):
+        ids = g[-3:]
+        idx = tuple(ids[o] for o in order)
+        return idx if block_batch is None else (g[0],) + idx
 
-    Identical algebra to :func:`_kernel` with a leading batch axis on every
-    ref; the MXU contraction becomes a batched ``dot_general`` (batch dim 0,
-    contracting the KRP-tile rows)."""
-    a_idx = pl.program_id(2)
-    b_idx = pl.program_id(3)
-
-    @pl.when(jnp.logical_and(a_idx == 0, b_idx == 0))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    # per-batch KRP tiles: (bt, 1, C) * (bt, bb, C) -> (bt, bb, C) -- each
-    # batch entry has its own factors, so the Hadamard is per-slab
-    k_tile = a_ref[:, 0, :][:, None, :] * b_ref[...]
-
-    t = t_ref[...]
-    if pos == 0:  # T block (bt, bi, 1, bb)
-        x_tile = t[:, :, 0, :]
-    elif pos == 1:  # T block (bt, 1, bi, bb)
-        x_tile = t[:, 0, :, :]
-    else:  # pos == 2: T block (bt, 1, bb, bi) -> contract over bb
-        x_tile = jnp.swapaxes(t[:, 0, :, :], 1, 2)
-    o_ref[...] += jax.lax.dot_general(
-        x_tile.astype(k_tile.dtype),
-        k_tile,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        precision=jax.lax.Precision.HIGHEST,
-    ).astype(o_ref.dtype)
+    if block_batch is not None:
+        shape = (block_batch,) + shape
+    return pl.BlockSpec(shape, index)
 
 
 def fused_mttkrp_bilinear_batched(
@@ -143,30 +150,19 @@ def fused_mttkrp_bilinear_batched(
     if dim_i % block_i or dim_b % block_b or n_batch % block_batch:
         raise ValueError("dims must be padded to block multiples")
 
-    grid = (n_batch // block_batch, dim_i // block_i, dim_a, dim_b // block_b)
-
-    if pos == 0:
-        t_spec = pl.BlockSpec(
-            (block_batch, block_i, 1, block_b),
-            lambda s, i, al, bl: (s, i, al, bl),
-        )
-    elif pos == 1:
-        t_spec = pl.BlockSpec(
-            (block_batch, 1, block_i, block_b),
-            lambda s, i, al, bl: (s, al, i, bl),
-        )
-    else:
-        t_spec = pl.BlockSpec(
-            (block_batch, 1, block_b, block_i),
-            lambda s, i, al, bl: (s, al, bl, i),
-        )
-
+    block_a = _block_a(dim_a, pos)
+    grid = (
+        n_batch // block_batch, dim_i // block_i, dim_a // block_a,
+        dim_b // block_b,
+    )
     return pl.pallas_call(
-        functools.partial(_kernel_batched, pos=pos),
+        functools.partial(_kernel, pos=pos, batched=True),
         grid=grid,
         in_specs=[
-            t_spec,
-            pl.BlockSpec((block_batch, 1, c), lambda s, i, al, bl: (s, al, 0)),
+            _t_spec(pos, block_i, block_a, block_b, block_batch),
+            pl.BlockSpec(
+                (block_batch, block_a, 1, c), lambda s, i, al, bl: (s, al, 0, 0)
+            ),
             pl.BlockSpec(
                 (block_batch, block_b, c), lambda s, i, al, bl: (s, bl, 0)
             ),
@@ -176,7 +172,17 @@ def fused_mttkrp_bilinear_batched(
         ),
         out_shape=jax.ShapeDtypeStruct((n_batch, dim_i, c), jnp.float32),
         interpret=interpret,
-    )(t, a, b)
+    )(t, a.reshape(n_batch, dim_a, 1, c), b)
+
+
+def _block_a(dim_a: int, pos: int) -> int:
+    """Rows of ``A`` walked per grid step.  At pos 0 the ``a`` axis is the
+    tensor block's second-minor dim: 8 (the wrapper pads ``dim_a`` to a
+    multiple) or the whole dim.  Elsewhere it is a leading dim: the largest
+    divisor of ``dim_a`` up to 8, so no padding is needed."""
+    if pos == 0:
+        return dim_a if dim_a <= SUBLANE else SUBLANE
+    return max(d for d in range(1, SUBLANE + 1) if dim_a % d == 0)
 
 
 def fused_mttkrp_bilinear(
@@ -205,24 +211,17 @@ def fused_mttkrp_bilinear(
     if dim_i % block_i or dim_b % block_b:
         raise ValueError("dims must be padded to block multiples")
 
-    grid = (dim_i // block_i, dim_a, dim_b // block_b)
-
-    if pos == 0:
-        t_spec = pl.BlockSpec((block_i, 1, block_b), lambda i, al, bl: (i, al, bl))
-    elif pos == 1:
-        t_spec = pl.BlockSpec((1, block_i, block_b), lambda i, al, bl: (al, i, bl))
-    else:
-        t_spec = pl.BlockSpec((1, block_b, block_i), lambda i, al, bl: (al, bl, i))
-
+    block_a = _block_a(dim_a, pos)
+    grid = (dim_i // block_i, dim_a // block_a, dim_b // block_b)
     return pl.pallas_call(
-        functools.partial(_kernel, pos=pos),
+        functools.partial(_kernel, pos=pos, batched=False),
         grid=grid,
         in_specs=[
-            t_spec,
-            pl.BlockSpec((1, c), lambda i, al, bl: (al, 0)),
+            _t_spec(pos, block_i, block_a, block_b),
+            pl.BlockSpec((block_a, 1, c), lambda i, al, bl: (al, 0, 0)),
             pl.BlockSpec((block_b, c), lambda i, al, bl: (bl, 0)),
         ],
         out_specs=pl.BlockSpec((block_i, c), lambda i, al, bl: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((dim_i, c), jnp.float32),
         interpret=interpret,
-    )(t, a, b)
+    )(t, a.reshape(dim_a, 1, c), b)

@@ -26,7 +26,7 @@ Array = jax.Array
 
 def _kernel(a_ref, b_ref, o_ref):
     # (1, C) * (bb, C) -> (bb, C): the row-wise KRP definition on the VPU.
-    o_ref[0, :, :] = (a_ref[0, :] * b_ref[...]).astype(o_ref.dtype)
+    o_ref[0, :, :] = (a_ref[0] * b_ref[...]).astype(o_ref.dtype)
 
 
 def krp_pair(
@@ -44,11 +44,12 @@ def krp_pair(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, c), lambda al, bl: (al, 0)),
+            # a rides as (J_A, 1, C): a full (1, C) minor tile per block
+            pl.BlockSpec((1, 1, c), lambda al, bl: (al, 0, 0)),
             pl.BlockSpec((block_b, c), lambda al, bl: (bl, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_b, c), lambda al, bl: (al, bl, 0)),
         out_shape=jax.ShapeDtypeStruct((ja, jb, c), a.dtype),
         interpret=interpret,
-    )(a, b)
+    )(a.reshape(ja, 1, c), b)
     return out.reshape(ja * jb, c)

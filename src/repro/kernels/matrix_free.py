@@ -37,16 +37,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._tiling import LANE, SUBLANE, TILE_ELEM_BUDGET
 from ._tiling import block as _block
 from ._tiling import interpret_default as _interpret
 from ._tiling import on_tpu as _on_tpu
 from ._tiling import pad_axis as _pad_axis
 
 Array = jax.Array
-
-# Cap on tensor-tile elements held in VMEM per grid step (2 MB at f32).
-_TILE_ELEM_BUDGET = 512 * 1024
-
 
 def _fold_tile(t, us_by_mode, live, n, batched):
     """Contract every non-target mode out of one VMEM tile.
@@ -76,9 +73,21 @@ def _fold_tile(t, us_by_mode, live, n, batched):
             shape[0] = u.shape[0]
         shape[pos] = u.shape[-2]
         shape[-1] = u.shape[-1]
-        t = (t * u.reshape(shape)).sum(axis=pos)
+        t = _sum_axis(t * u.reshape(shape), pos)
         live.remove(a)
     return t
+
+
+def _sum_axis(t, axis: int):
+    """``t.sum(axis)``; over a leading (untiled) axis as a chain of
+    elementwise adds, since Mosaic cannot lower a leading-axis reduction
+    of an intermediate that an earlier sublane reduction left behind."""
+    if axis >= t.ndim - 2:
+        return t.sum(axis=axis)
+    return reduce(
+        jnp.add,
+        [jax.lax.index_in_dim(t, j, axis, keepdims=False) for j in range(t.shape[axis])],
+    )
 
 
 def matrix_free_kernel(
@@ -228,21 +237,58 @@ def matrix_free_batched_kernel(
     )(x, *us)
 
 
-def _reduction_blocks(
-    mode_shape: Sequence[int], n: int, lead_elems: int, block_r: int
-) -> dict[int, int]:
-    """Per-non-target-mode block sizes, shrunk to fit the VMEM tile budget.
+def _align(k: int, big_n: int) -> int:
+    """Mosaic alignment of mode ``k``'s block: the tensor's minor dim takes
+    whole 128-lane tiles; every other mode's block is also the
+    second-minor dim of its factor's ``(rows, C)`` block, so 8 sublanes."""
+    return LANE if k == big_n - 1 else SUBLANE
 
-    ``lead_elems`` is the number of tile elements already committed to the
-    non-reduction axes (``block_i``, times ``block_batch`` when batched).
+
+def _sublanes(b: int) -> int:
+    """Round a block up to whole 8-row sublane tiles: each mode's axis
+    becomes the sublane dim of some fold intermediate, where Mosaic cannot
+    reduce over a ragged tile (the wrapper zero-pads the extra rows)."""
+    return -(-b // SUBLANE) * SUBLANE
+
+
+def _padded(c: int, mult: int | None) -> int:
+    return -(-c // mult) * mult if mult else c
+
+
+def _tile_blocks(
+    mode_shape: Sequence[int], n: int, lead_elems: int, c: int,
+    block_i: int, block_r: int,
+) -> tuple[int, dict[int, int]]:
+    """Target-mode block and per-non-target-mode blocks for one VMEM tile.
+
+    Blocks start at ``block_i`` / ``block_r`` (aligned, whole sublane
+    tiles) and the largest is halved, staying aligned, until both the
+    tensor tile and the first fold contraction's ``(..., C)`` result fit
+    the budget.  ``lead_elems`` is the batch-slab extent (1 unbatched).
     """
-    rb = {k: _block(d, block_r) for k, d in enumerate(mode_shape) if k != n}
-    while lead_elems * math.prod(rb.values()) > _TILE_ELEM_BUDGET:
-        k = max(rb, key=lambda kk: rb[kk])
-        if rb[k] == 1:
+    big_n = len(mode_shape)
+    blocks = {
+        k: _sublanes(_block(d, block_i if k == n else block_r, _align(k, big_n)))
+        for k, d in enumerate(mode_shape)
+    }
+    first = max(k for k in range(big_n) if k != n)  # see _fold_tile
+
+    def footprint() -> int:
+        tile = lead_elems * math.prod(blocks.values())
+        return max(tile, tile // blocks[first] * c)
+
+    while footprint() > TILE_ELEM_BUDGET:
+        half = {
+            k: b // 2 // _align(k, big_n) * _align(k, big_n)
+            for k, b in blocks.items()
+        }
+        cands = [k for k, b in blocks.items() if 0 < half[k] < b]
+        if not cands:
             break
-        rb[k] = rb[k] // 2
-    return rb
+        k = max(cands, key=lambda kk: blocks[kk])
+        blocks[k] = half[k]
+    bi = blocks.pop(n)
+    return bi, blocks
 
 
 @partial(
@@ -281,8 +327,7 @@ def matrix_free_mttkrp(
 
     in_dim = x.shape[n]
     others = [k for k in range(big_n) if k != n]
-    bi = _block(in_dim, block_i)
-    rb = _reduction_blocks(x.shape, n, bi, block_r)
+    bi, rb = _tile_blocks(x.shape, n, 1, _padded(c, pad_rank_to), block_i, block_r)
 
     x_pad = _pad_axis(x, n, bi)
     us = []
@@ -337,9 +382,10 @@ def matrix_free_mttkrp_batched(
 
     in_dim = mode_shape[n]
     others = [k for k in range(big_n) if k != n]
-    bi = _block(in_dim, block_i)
     bs = _block(s_batch, block_batch)
-    rb = _reduction_blocks(mode_shape, n, bi * bs, block_r)
+    bi, rb = _tile_blocks(
+        mode_shape, n, bs, _padded(c, pad_rank_to), block_i, block_r
+    )
 
     x_pad = _pad_axis(_pad_axis(x, 1 + n, bi), 0, bs)
     us = []

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._tiling import SUBLANE
 from ._tiling import block as _block
 from ._tiling import interpret_default as _interpret
 from ._tiling import pad_axis as _pad_axis
@@ -40,7 +41,7 @@ def _kernel(t_ref, w_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += (t_ref[0, :, :] * w_ref[0, :]).astype(o_ref.dtype)
+    o_ref[...] += (t_ref[0, :, :] * w_ref[0]).astype(o_ref.dtype)
 
 
 def multi_ttv_kernel(
@@ -58,12 +59,13 @@ def multi_ttv_kernel(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_i, c), lambda i, l: (l, i, 0)),
-            pl.BlockSpec((1, c), lambda i, l: (l, 0)),
+            # w rides as (L, 1, C): a full (1, C) minor tile per block
+            pl.BlockSpec((1, 1, c), lambda i, l: (l, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_i, c), lambda i, l: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((dim_i, c), jnp.float32),
         interpret=interpret,
-    )(t, w)
+    )(t, w.reshape(big_l, 1, c))
 
 
 def _kernel_batched(t_ref, w_ref, o_ref):
@@ -74,9 +76,7 @@ def _kernel_batched(t_ref, w_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     # per-batch broadcast MAC: (bt, bi, C) * (bt, 1, C)
-    o_ref[...] += (t_ref[:, 0, :, :] * w_ref[:, 0, :][:, None, :]).astype(
-        o_ref.dtype
-    )
+    o_ref[...] += (t_ref[:, 0, :, :] * w_ref[:, 0]).astype(o_ref.dtype)
 
 
 def multi_ttv_batched_kernel(
@@ -106,14 +106,16 @@ def multi_ttv_batched_kernel(
             pl.BlockSpec(
                 (block_batch, 1, block_i, c), lambda s, i, l: (s, l, i, 0)
             ),
-            pl.BlockSpec((block_batch, 1, c), lambda s, i, l: (s, l, 0)),
+            pl.BlockSpec(
+                (block_batch, 1, 1, c), lambda s, i, l: (s, l, 0, 0)
+            ),
         ],
         out_specs=pl.BlockSpec(
             (block_batch, block_i, c), lambda s, i, l: (s, i, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((n_batch, dim_i, c), jnp.float32),
         interpret=interpret,
-    )(t, w)
+    )(t, w.reshape(n_batch, big_l, 1, c))
 
 
 @partial(jax.jit, static_argnames=("block_i", "interpret"))
@@ -123,7 +125,7 @@ def multi_ttv(
     """Kernelized multi-TTV:  M[i,c] = sum_l t[l,i,c] * w[l,c]."""
     interp = _interpret(interpret)
     dim_i = t.shape[1]
-    bi = _block(dim_i, block_i)
+    bi = _block(dim_i, block_i, SUBLANE)
     t_pad = _pad_axis(t, 1, bi)
     out = multi_ttv_kernel(t_pad, w, block_i=bi, interpret=interp)
     return out[:dim_i].astype(t.dtype)
@@ -145,7 +147,7 @@ def multi_ttv_batched(
     """
     interp = _interpret(interpret)
     s_batch, dim_i = t.shape[0], t.shape[2]
-    bi = _block(dim_i, block_i)
+    bi = _block(dim_i, block_i, SUBLANE)
     bs = _block(s_batch, block_batch)
     t_pad = _pad_axis(_pad_axis(t, 2, bi), 0, bs)
     w_pad = _pad_axis(w, 0, bs)

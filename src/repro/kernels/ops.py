@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from repro.core.krp import krp_or_ones, krp_or_ones_batched
 from repro.core.tensor_ops import dims_split
 
+from ._tiling import LANE, SUBLANE, TILE_ELEM_BUDGET
 from ._tiling import block as _block
 from ._tiling import interpret_default as _interpret
 from ._tiling import on_tpu as _on_tpu
@@ -72,6 +73,18 @@ def balanced_split(dims: Sequence[int]) -> int:
 
 
 _balanced_split = balanced_split
+
+
+def _fused_blocks(
+    dim_i: int, dim_b: int, pos: int, block_i: int, block_b: int
+) -> tuple[int, int]:
+    """Legal (block_i, block_b) for the fused kernel's tensor view: the
+    i axis is the block's minor dim at pos 2 and its second-minor dim
+    otherwise (the output block keeps it second-minor too); the b axis
+    takes the other of the two."""
+    if pos == 2:
+        return _block(dim_i, block_i, LANE), _block(dim_b, block_b, SUBLANE)
+    return _block(dim_i, block_i, SUBLANE), _block(dim_b, block_b, LANE)
 
 
 @partial(jax.jit, static_argnames=("n", "block_i", "block_b", "interpret", "pad_rank_to"))
@@ -136,11 +149,13 @@ def fused_mttkrp(
         a = _pad_axis(a, 1, pad_rank_to)
         b = _pad_axis(b, 1, pad_rank_to)
 
-    bi = _block(in_dim, block_i)
-    bb = _block(b.shape[0], block_b)
+    bi, bb = _fused_blocks(in_dim, b.shape[0], pos, block_i, block_b)
     b_axis = 1 if pos == 2 else 2  # t layout: pos0 (i,a,b), pos1 (a,i,b), pos2 (a,b,i)
     t = _pad_axis(_pad_axis(t, pos, bi), b_axis, bb)
     b = _pad_axis(b, 0, bb)
+    if pos == 0 and a.shape[0] > SUBLANE:  # a is the block's second-minor dim
+        t = _pad_axis(t, 1, SUBLANE)
+        a = _pad_axis(a, 0, SUBLANE)
     out = fused_mttkrp_bilinear(
         t, a, b, pos=pos, block_i=bi, block_b=bb, interpret=interp
     )
@@ -217,13 +232,17 @@ def fused_mttkrp_batched(
         a = _pad_axis(a, 2, pad_rank_to)
         b = _pad_axis(b, 2, pad_rank_to)
 
-    bi = _block(in_dim, block_i)
-    bb = _block(b.shape[1], block_b)
+    bi, bb = _fused_blocks(in_dim, b.shape[1], pos, block_i, block_b)
     bs = _block(s_batch, block_batch)
+    while bs > 1 and bs * bi * SUBLANE * bb > TILE_ELEM_BUDGET:
+        bs //= 2  # a grid step walks up to SUBLANE rows of A per slab
     b_axis = 2 if pos == 2 else 3  # unbatched layout axes, shifted by one
     t = _pad_axis(_pad_axis(_pad_axis(t, pos + 1, bi), b_axis, bb), 0, bs)
     a = _pad_axis(a, 0, bs)
     b = _pad_axis(_pad_axis(b, 1, bb), 0, bs)
+    if pos == 0 and a.shape[1] > SUBLANE:  # a is the block's second-minor dim
+        t = _pad_axis(t, 2, SUBLANE)
+        a = _pad_axis(a, 1, SUBLANE)
     out = fused_mttkrp_bilinear_batched(
         t, a, b, pos=pos, block_i=bi, block_b=bb, block_batch=bs,
         interpret=interp,
@@ -242,7 +261,7 @@ def krp_materialize(
     out = mats[0]
     for u in mats[1:]:
         jb = u.shape[0]
-        bb = _block(jb, block_b)
+        bb = _block(jb, block_b, SUBLANE)
         u_pad = _pad_axis(u, 0, bb)
         ja = out.shape[0]
         prod = krp_pair(out, u_pad, block_b=bb, interpret=interp)

@@ -21,12 +21,11 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import math
 from typing import Any, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro import compat
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _MESH: contextvars.ContextVar[Mesh | None] = contextvars.ContextVar(
     "repro_mesh", default=None
@@ -51,34 +50,42 @@ def in_manual_mode() -> bool:
     return _MANUAL.get()
 
 
-def _auto(n: int):
-    return compat.auto_axis_types(n)
+def make_mesh(
+    axis_shapes: Sequence[int], axis_names: Sequence[str], *, devices=None
+) -> Mesh:
+    """THE mesh constructor: every axis is ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to ``AxisType.Explicit`` axes, under which
+    sharding-in-types rejects the contractions over sharded dims that the
+    ``shard_map`` + ``psum`` placements and GSPMD propagation rely on.  Uses
+    the first ``prod(axis_shapes)`` of ``devices`` (default: all local
+    devices), so a mesh may cover fewer devices than the process sees.
+    """
+    shape = tuple(int(s) for s in axis_shapes)
+    devices = list(jax.devices() if devices is None else devices)
+    n = math.prod(shape)
+    if len(devices) < n:
+        raise RuntimeError(
+            f"need {n} devices for mesh {shape}, have {len(devices)} -- set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count before importing jax"
+        )
+    return jax.make_mesh(
+        shape,
+        tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(shape),
+        devices=devices[:n],
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()
-    if len(devices) == n:
-        return compat.make_mesh(shape, axes, axis_types=_auto(len(axes)))
-    if len(devices) > n:  # e.g. dry-run process exposes 512; single pod uses 256
-        import numpy as np
-
-        return compat.mesh_from_devices(
-            np.asarray(devices[:n]).reshape(shape), axes, axis_types=_auto(len(axes))
-        )
-    raise RuntimeError(
-        f"need {n} devices for mesh {shape}, have {len(devices)} -- set "
-        "XLA_FLAGS=--xla_force_host_platform_device_count before importing jax"
-    )
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over the locally available devices (tests / examples)."""
-    return compat.make_mesh((data, model), ("data", "model"), axis_types=_auto(2))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_node_mesh(
@@ -97,9 +104,7 @@ def make_node_mesh(
     Declare the intra level to the planner via
     ``Problem(intra_axes=(axis_names[1],))``.
     """
-    return compat.make_mesh(
-        (int(nodes), int(devices_per_node)), tuple(axis_names), axis_types=_auto(2)
-    )
+    return make_mesh((nodes, devices_per_node), axis_names)
 
 
 @contextlib.contextmanager

@@ -10,7 +10,8 @@ them all, drains the service, and logs problems/sec plus the serving
 counters.  ``--mesh`` shards every dispatch's batch axis over all attached
 devices (batch-parallel: zero collective traffic); ``--tuning-cache`` names
 a persistent :class:`repro.plan.autotune.TuningCache` file to use as the
-warm-plan store.
+warm-plan store.  Compiles persist in ``<checkout>/.jax_cache`` unless
+``JAX_COMPILATION_CACHE_DIR`` names another directory.
 """
 
 from __future__ import annotations
@@ -38,7 +39,12 @@ def main():
                     help="persistent TuningCache file (the warm-plan store)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
+
     from repro.core.tensor_ops import random_tensor
+    from repro.launch.mesh import make_mesh
     from repro.plan.autotune import TuningCache
     from repro.serve import CPService
 
@@ -48,7 +54,7 @@ def main():
 
         # batch-parallel sharding needs the device count to divide the batch
         n_dev = math.gcd(jax.device_count(), args.batch_size)
-        mesh = jax.make_mesh((n_dev,), ("b",))
+        mesh = make_mesh((n_dev,), ("b",))
         log.info("batch-parallel over %d of %d devices", n_dev, jax.device_count())
     cache = TuningCache(args.tuning_cache) if args.tuning_cache else None
     svc = CPService(

@@ -34,8 +34,6 @@ from repro.launch import mesh as meshlib
 
 from .common import ParamDef, act_fn
 
-from repro.compat import shard_map as _shard_map
-
 Array = jax.Array
 
 EXPERT_PAD_MULTIPLE = 16
@@ -112,7 +110,7 @@ def moe_apply(p: dict, cfg: ModelConfig, x: Array) -> tuple[Array, Array]:
         if shared is None
         else dict(gate=P(dspec, "model"), up=P(dspec, "model"), down=P("model", dspec))
     )
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

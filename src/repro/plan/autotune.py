@@ -708,6 +708,7 @@ def tune(
     seed: int = 0,
     pp_tol: float = 0.0,
     intra_axes: Sequence[str] = (),
+    batch: int = 1,
 ) -> dict:
     """Measure tiles + candidate plans for ``x``'s problem; persist winners.
 
@@ -734,19 +735,48 @@ def tune(
     mesh, exactly as on :class:`Problem`: nodes whose reductions span both
     levels are then measured under flat AND hierarchical collectives, and
     the resulting entry keys include the node-topology field so two-level
-    measurements never collide with single-level ones.
+    measurements never collide with single-level ones.  ``batch=B > 1``
+    tunes the batched problem a :class:`repro.serve.CPService` of
+    ``batch_size=B`` dispatches: ``x`` is ``(B, *shape)``, the entry lands
+    under that batched signature (the service's warm-plan key), nodes are
+    timed on the whole batch, and kernel tiles on its first problem.
     Returns the stored entry dict.
     """
     cache = cache or default_tuning_cache()
     problem = Problem.from_tensor(
         x, rank, mode_axes=mode_axes, mesh=mesh, pp_tol=pp_tol,
-        intra_axes=intra_axes,
+        intra_axes=intra_axes, batch=batch,
     )
     if factors is None:
-        factors = random_factors(jax.random.PRNGKey(seed), x.shape, rank, x.dtype)
+        factors = random_factors(
+            jax.random.PRNGKey(seed), problem.shape, rank, x.dtype,
+            batch=problem.batch,
+        )
+    # kernel tiles key on the mode dims: tune them on one problem
+    x1 = x[0] if problem.batched else x
+    f1 = [u[0] for u in factors] if problem.batched else factors
+    from .sweep import SWEEP_PRECISION  # lazy: sweep imports the planner
+
     budget = _Budget(budget_ms)
-    fused = _tune_fused_tiles(x, factors, reps=reps, budget=budget)
-    mfree = _tune_matrix_free_tiles(x, factors, reps=reps, budget=budget)
+    # measure at the precision the sweep executes at
+    with jax.default_matmul_precision(SWEEP_PRECISION):
+        entry = _measure(
+            problem, x, factors, x1, f1, mesh=mesh, mode_axes=mode_axes,
+            reps=reps, budget=budget,
+        )
+    entry.update(budget_ms=budget_ms, reps=reps)
+    cache.put(problem_key(problem), entry)
+    return entry
+
+
+def _measure(
+    problem: Problem, x, factors, x1, f1, *, mesh, mode_axes, reps: int,
+    budget: _Budget,
+) -> dict:
+    """The measuring body of :func:`tune`: tiles on one problem (``x1``,
+    ``f1``), nodes and PP rows on the whole (possibly batched) problem."""
+    fused = _tune_fused_tiles(x1, f1, reps=reps, budget=budget)
+    mfree = _tune_matrix_free_tiles(x1, f1, reps=reps, budget=budget)
     rows = _tune_nodes(
         problem, x, factors, mesh=mesh, mode_axes=mode_axes, reps=reps,
         budget=budget,
@@ -764,7 +794,7 @@ def tune(
     tiles = {
         "fused_mttkrp": fused,
         "matrix_free": mfree,
-        "multi_ttv": _tune_ttv_tiles(x, factors, reps=reps, budget=budget),
+        "multi_ttv": _tune_ttv_tiles(x1, f1, reps=reps, budget=budget),
     }
     pp_rows = (
         _tune_pp(
@@ -774,18 +804,14 @@ def tune(
         if problem.pp_tol > 0.0
         else {}
     )
-    entry = {
+    return {
         "backend": backend_name(),
         "n_devices": (
             math.prod(problem.axis_sizes.values()) if problem.axis_sizes else 1
         ),
-        "budget_ms": budget_ms,
-        "reps": reps,
         "elapsed_ms": (time.perf_counter() - budget.t0) * 1e3,
         "tiles": tiles,
         "nodes": rows,
         "serial_fractions": _recalibrate_serial_fractions(problem, rows),
         "pp": pp_rows,
     }
-    cache.put(problem_key(problem), entry)
-    return entry

@@ -41,6 +41,14 @@ from .schedule import ROOT, pp_pairs as pp_pair_meta
 
 Array = jax.Array
 
+# THE precision policy of the solver: every contraction of a sweep (MTTKRP,
+# partial contractions, Grams, solve, fit) at Precision.HIGHEST, as the
+# Pallas kernels already contract.  On a TPU an fp32 matmul at default
+# precision takes one bf16 pass; on the paper's 225x59x200x200 tensor that
+# stalled ALS at fit 0.738 where the HIGHEST reference reached 0.950.  At
+# the paper's ranks MTTKRP stays memory-bound even at HIGHEST's passes.
+SWEEP_PRECISION = "highest"
+
 # THE host-synchronization point of the cp_als driver: exactly one call per
 # dispatched chunk of sweeps.  Module-level so tests can count syncs.
 _block_until_ready = jax.block_until_ready
@@ -359,7 +367,17 @@ def als_sweep(
     iteration.  The drift/n_exact bookkeeping is recomputed outside the
     gate from the same quantities the branches used, bitwise identical to
     the nested-cond formulation (``test_property.py`` pins this).
+
+    Every contraction of the sweep runs at :data:`SWEEP_PRECISION`.
     """
+    with jax.default_matmul_precision(SWEEP_PRECISION):
+        return _gated_sweep(problem, plan, executor, state)
+
+
+def _gated_sweep(
+    problem: Problem, plan: SweepPlan, executor: Executor, state: SweepState
+) -> SweepState:
+    """The body of :func:`als_sweep`: the exact walk, or the PP gate."""
     if state.pp is None:
         return _exact_sweep(problem, plan, executor, state)
 

@@ -1,14 +1,14 @@
-"""Tests for the jax-version compat shim and the single-device degenerate
-paths of the distributed MTTKRP subsystem (runs on the default 1-device
-CPU backend -- the multi-device paths live in test_dist.py)."""
+"""Tests for the one mesh constructor (every axis ``AxisType.Auto``) and
+the single-device degenerate paths of the distributed MTTKRP subsystem
+(runs on the default 1-device CPU backend -- the multi-device paths live
+in test_dist.py)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import mttkrp, mttkrp_einsum, random_factors, random_tensor
 from repro.core.cpals import als_sweep
 from repro.core.tensor_ops import tensor_norm
@@ -27,52 +27,54 @@ def mesh1():
     return meshlib.make_host_mesh(1, 1)
 
 
-# ------------------------------------------------------------------ compat
-def test_auto_axis_types_matches_installed_jax():
-    types = compat.auto_axis_types(3)
-    if compat.HAS_AXIS_TYPE:
-        assert types == (jax.sharding.AxisType.Auto,) * 3
-    else:
-        assert types is None  # pre-0.6: kwarg must be dropped entirely
+# ------------------------------------------------------- mesh constructor
+@pytest.mark.parametrize("names", [("data",), ("data", "model"), ("pod", "data", "model")])
+def test_make_mesh_axes_are_auto(names):
+    m = meshlib.make_mesh((1,) * len(names), names)
+    assert m.axis_names == names
+    assert m.axis_types == (AxisType.Auto,) * len(names)
 
 
-def test_make_mesh_accepts_axis_types_on_any_jax():
-    m = compat.make_mesh((1, 1), ("data", "model"), axis_types=compat.auto_axis_types(2))
+def test_make_mesh_takes_the_given_devices():
+    m = meshlib.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     assert dict(m.shape) == {"data": 1, "model": 1}
+    assert list(m.devices.flat) == jax.devices()[:1]
 
 
-def test_mesh_from_devices():
-    m = compat.mesh_from_devices(
-        np.asarray(jax.devices()[:1]).reshape(1, 1),
-        ("data", "model"),
-        axis_types=compat.auto_axis_types(2),
-    )
-    assert m.axis_names == ("data", "model")
+def test_make_mesh_rejects_too_few_devices():
+    with pytest.raises(RuntimeError, match="need"):
+        meshlib.make_mesh((len(jax.devices()) + 1,), ("data",))
 
 
-def test_public_shard_map_alias_installed():
-    # importing repro.compat guarantees the >= 0.6 surface exists
-    assert hasattr(jax, "shard_map")
+def test_contraction_over_a_sharded_dim_compiles_on_our_mesh():
+    """The regression behind every ShardingTypeError: jax.make_mesh's
+    default Explicit axes reject a Gram over a sharded row dim (what
+    ``grams`` computes inside every sweep); the Auto mesh leaves it to
+    GSPMD."""
+    def gram(u):
+        return u.T @ u
+
+    for mesh, ok in ((jax.make_mesh((1,), ("data",)), False),
+                     (meshlib.make_mesh((1,), ("data",)), True)):
+        u = jax.device_put(jnp.ones((4, 3)), NamedSharding(mesh, P("data", None)))
+        if ok:
+            np.testing.assert_array_equal(np.asarray(jax.jit(gram)(u)), 4.0)
+        else:
+            with pytest.raises(Exception, match="Contracting dimensions are sharded"):
+                jax.jit(gram)(u)
 
 
-@pytest.mark.parametrize("flag_name", ["check_vma", "check_rep"])
-def test_shard_map_accepts_both_flag_spellings(mesh1, flag_name):
-    def f(x):
-        return jax.lax.psum(x, "data")
-
-    out = compat.shard_map(
-        f,
-        mesh=mesh1,
-        in_specs=P("data"),
-        out_specs=P("data"),
-        **{flag_name: False},
+def test_shard_map_psum_on_auto_mesh(mesh1):
+    out = jax.shard_map(
+        lambda x: jax.lax.psum(x, "data"),
+        mesh=mesh1, in_specs=P("data"), out_specs=P("data"), check_vma=False,
     )(jnp.arange(4.0))
     np.testing.assert_array_equal(np.asarray(out), np.arange(4.0))
 
 
-def test_host_mesh_routes_through_compat(mesh1):
-    # regression for jax.sharding.AxisType usage on jax < 0.6
+def test_host_mesh_is_auto(mesh1):
     assert dict(mesh1.shape) == {"data": 1, "model": 1}
+    assert mesh1.axis_types == (AxisType.Auto, AxisType.Auto)
 
 
 # ------------------------------------- dist degenerate paths (1-device mesh)
@@ -144,14 +146,14 @@ def test_compressed_psum_size1_axis_and_error_bound(mesh1):
     def f(x_blk, e_blk):
         return compressed_psum(x_blk, "data", e_blk)
 
-    s, ne = compat.shard_map(
+    s, ne = jax.shard_map(
         f, mesh=mesh1, in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False
     )(x, err0)
     step = float(jnp.max(jnp.abs(x))) / 127.0
     np.testing.assert_allclose(np.asarray(s), np.asarray(x), atol=step / 2 + 1e-6)
     assert float(jnp.max(jnp.abs(ne))) <= step / 2 + 1e-6
     # second round with carried residual stays bounded (error feedback)
-    s2, ne2 = compat.shard_map(
+    s2, ne2 = jax.shard_map(
         f, mesh=mesh1, in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False
     )(x, ne)
     assert float(jnp.max(jnp.abs(ne2))) <= float(jnp.max(jnp.abs(x + ne))) / 254.0 + 1e-6

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.core.tensor_ops import random_factors, random_tensor
+from repro.launch.mesh import make_mesh
 from repro.plan import Problem, cp_als, plan_sweep
 from repro.plan.autotune import TuningCache, problem_key
 from repro.serve import CPService, QueueFull, RequestQueue
@@ -214,6 +215,27 @@ def test_warm_plan_hits_from_tuning_cache(tmp_path):
     assert stats["signatures"] == 2 and stats["warm_plan_hits"] == 1
 
 
+def test_batched_tune_makes_the_service_signature_warm():
+    """``tune(..., batch=B)`` measures the batched problem a
+    ``CPService(batch_size=B)`` dispatches and stores it under that
+    signature, so the service plans it warm; results still match the
+    direct per-tensor run."""
+    from repro.plan.autotune import tune
+
+    shape, B = (6, 5, 4), 2
+    reqs = [_request(shape, seed=80 + i) for i in range(B)]
+    cache = TuningCache()
+    tune(jax.numpy.stack([x for x, _ in reqs]), RANK, batch=B, cache=cache,
+         budget_ms=1, reps=1)
+    assert cache.get(problem_key(Problem(shape=shape, rank=RANK, batch=B)))
+    svc = CPService(batch_size=B, n_iters=N_ITERS, tuning_cache=cache)
+    futs = [svc.submit(x, RANK, init_factors=init) for x, init in reqs]
+    svc.flush()
+    assert svc.stats()["warm_plan_hits"] == 1
+    for fut, (x, init) in zip(futs, reqs):
+        _assert_matches_direct(fut, x, init)
+
+
 def test_service_signature_is_the_canonical_problem_signature():
     """The batch bucket key extends Problem.signature()/problem_key (the
     tuning-cache key) with the update options -- one key construction."""
@@ -230,7 +252,7 @@ def test_service_signature_is_the_canonical_problem_signature():
 def test_batch_parallel_service_matches_local():
     """A mesh-backed service (batch axis sharded over every device, zero
     collective traffic) returns the local service's results."""
-    mesh = jax.make_mesh((N_DEV,), ("b",))
+    mesh = make_mesh((N_DEV,), ("b",))
     svc_sh = CPService(batch_size=N_DEV, n_iters=N_ITERS, mesh=mesh)
     svc_lo = CPService(batch_size=N_DEV, n_iters=N_ITERS)
     reqs = [_request((8, 8, 6), seed=80 + i) for i in range(N_DEV)]
@@ -266,4 +288,4 @@ def test_submit_validation_and_future_protocol():
         CPService(batch_size=0)
     if N_DEV > 1:
         with pytest.raises(ValueError, match="divisible"):
-            CPService(batch_size=N_DEV + 1, mesh=jax.make_mesh((N_DEV,), ("b",)))
+            CPService(batch_size=N_DEV + 1, mesh=make_mesh((N_DEV,), ("b",)))

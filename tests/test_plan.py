@@ -2,6 +2,7 @@
 numerics for every algorithm, and sharded == local on a size-1 mesh."""
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -498,3 +499,28 @@ def test_auto_plan_invariants(shape, rank):
 def test_forced_strategy_is_verbatim(shape, strategy):
     plan = plan_sweep(Problem(shape=tuple(shape), rank=4), strategy=strategy)
     assert all(m.algorithm == strategy for m in plan.modes)
+
+
+def test_sweep_contracts_at_the_solver_precision():
+    """Every dot_general a traced sweep emits -- MTTKRPs, partial
+    contractions, Grams, solve, fit -- carries SWEEP_PRECISION: on a TPU a
+    default-precision fp32 matmul takes one bf16 pass, which stalls ALS on
+    the paper's fMRI tensor."""
+    from repro.plan.sweep import SWEEP_PRECISION
+
+    shape, rank = (6, 5, 4, 3), 3
+    x = random_tensor(jax.random.PRNGKey(0), shape)
+    fs = random_factors(jax.random.PRNGKey(1), shape, rank)
+    want = f"Precision.{SWEEP_PRECISION.upper()}"
+    for strategy in ("auto", "1step", "2step", "dimtree"):
+        plan = plan_sweep(Problem.from_tensor(x, rank), strategy=strategy)
+        state = SweepState(
+            x=x, factors=fs, weights=jnp.ones((rank,)), norm_x=jnp.float32(1.0),
+            it=jnp.asarray(0),
+        )
+        text = str(jax.make_jaxpr(
+            lambda s, plan=plan: als_sweep(plan.problem, plan, LocalExecutor(), s)
+        )(state))
+        precisions = re.findall(r"precision=\(?([^,\)\]]*)", text)
+        assert precisions, strategy
+        assert set(p.strip() for p in precisions) == {want}, (strategy, set(precisions))

@@ -290,12 +290,20 @@ def test_pp_gate_restricted_carry_bitwise_iterates():
 def test_pp_final_fit_matches_exact():
     """On a planted low-rank tensor a PP run (mostly approximated sweeps)
     converges to the same fit as exact ALS, while actually skipping exact
-    re-materializations."""
+    re-materializations.  The planted factors have orthonormal columns, so
+    ALS leaves its early large-step phase within a few sweeps instead of
+    crawling through a swamp of nearly collinear components (where every
+    sweep's drift exceeds pp_tol and PP never engages)."""
+    import jax.numpy as jnp
+
     from repro.core import cp_full, random_factors, random_tensor
     from repro.plan import Problem, cp_als, plan_sweep
 
     shape, rank, n_iters = (12, 10, 8), 4, 40
-    true = random_factors(jax.random.PRNGKey(30), shape, rank)
+    true = [
+        jnp.linalg.qr(u)[0]
+        for u in random_factors(jax.random.PRNGKey(30), shape, rank)
+    ]
     x = cp_full(None, true)
     x = x + 1e-3 * random_tensor(jax.random.PRNGKey(31), shape)
     init = random_factors(jax.random.PRNGKey(32), shape, rank)
