@@ -41,6 +41,9 @@ class CPState:
     # Exact (re-materializing) sweeps executed when the run used pairwise
     # perturbation (Problem.pp_tol > 0); None for classic exact-only runs.
     pp_exact_sweeps: int | None = None
+    # Times cp_als blocked the host on the device: each wait for a
+    # chunk and each read of a device value into Python (float/bool/int).
+    host_syncs: int = 0
 
 
 @dataclass

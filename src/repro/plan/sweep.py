@@ -212,13 +212,14 @@ def _update_factor(
     C x C Gram-Hadamard, optionally column-normalize into the lambdas, and
     refresh exactly the changed factor's Gram.  Mutates ``factors``/``gs``
     in place; returns the (possibly updated) weights."""
-    h = hadamard_except(gs, n)
-    u = m_n @ jnp.linalg.pinv(h)
-    if plan.normalize:
-        u, norms = normalize_columns(u, it)
-        weights = norms
-    factors[n] = u
-    gs[n] = jnp.swapaxes(u, -1, -2) @ u
+    with jax.named_scope(f"update.mode{n}"):
+        h = hadamard_except(gs, n)
+        u = m_n @ jnp.linalg.pinv(h)
+        if plan.normalize:
+            u, norms = normalize_columns(u, it)
+            weights = norms
+        factors[n] = u
+        gs[n] = jnp.swapaxes(u, -1, -2) @ u
     return weights
 
 
@@ -245,14 +246,15 @@ def _exact_sweep(
             alg, tiles, coll = np_.algorithm, np_.tiles, np_.collective
         else:
             alg, tiles, coll = "auto", None, "flat"
-        if use_carry:
-            out, carry = executor.contract_carry(
-                node, src, factors, alg, carry, tiles=tiles, collective=coll
-            )
-        else:
-            out = executor.contract(
-                node, src, factors, alg, tiles=tiles, collective=coll
-            )
+        with jax.named_scope(f"mttkrp.node{node.id}"):
+            if use_carry:
+                out, carry = executor.contract_carry(
+                    node, src, factors, alg, carry, tiles=tiles, collective=coll
+                )
+            else:
+                out = executor.contract(
+                    node, src, factors, alg, tiles=tiles, collective=coll
+                )
         if node.is_leaf:
             m_last = out
             weights = _update_factor(plan, factors, gs, weights, node.mode, m_last, it)
@@ -260,7 +262,8 @@ def _exact_sweep(
             cache[node.id] = out
 
     # Fit from the last MTTKRP (standard trick; avoids forming the model).
-    fit = fit_from_last_mttkrp(gs, weights, m_last, factors[-1], state.norm_x)
+    with jax.named_scope("fit"):
+        fit = fit_from_last_mttkrp(gs, weights, m_last, factors[-1], state.norm_x)
     return SweepState(
         x=x, factors=factors, weights=weights, norm_x=state.norm_x, it=it, fit=fit,
         carry=carry, grams=gs, pp=state.pp,
@@ -298,7 +301,8 @@ def _pp_sweep(
                 m_n = m_n + _pp_contract_first(pp.pairs[(m, n)], du)
         m_last = m_n
         weights = _update_factor(plan, factors, gs, weights, n, m_n, it)
-    fit = fit_from_last_mttkrp(gs, weights, m_last, factors[-1], state.norm_x)
+    with jax.named_scope("fit"):
+        fit = fit_from_last_mttkrp(gs, weights, m_last, factors[-1], state.norm_x)
     new_pp = PPState(
         ref=pp.ref, pairs=pp.pairs, base=pp.base,
         drift=_pp_drift(factors, pp.ref), n_exact=pp.n_exact,
@@ -541,6 +545,17 @@ def cp_als(
     plans/executors (the service keys on the problem signature and memoizes
     plan + executor under the same key).  A cache hit makes the call
     compile-free for shapes already traced.
+
+    Observability costs nothing unless a profiler trace is being taken.
+    Device ops carry the named scopes ``init`` (this set-up),
+    ``mttkrp.node<id>`` (one schedule node's contraction), ``update.mode<n>``
+    and ``fit`` in their op names.  The host loop opens the
+    ``jax.profiler.TraceAnnotation`` spans ``cp_als.init`` (entry to the
+    first dispatch), ``cp_als.dispatch`` (argument preparation and enqueue),
+    ``cp_als.wait`` (the chunk's block) and ``cp_als.check`` (the fit reads,
+    the convergence test and the callback).  ``CPState.host_syncs`` counts
+    every time the host blocked on the device: each chunk's wait and each
+    device value read into Python.
     """
     problem = plan.problem
     if executor is None:
@@ -554,75 +569,77 @@ def cp_als(
     k = int(sweeps_per_sync)
     if k < 1:
         raise ValueError(f"sweeps_per_sync must be >= 1, got {sweeps_per_sync}")
-    key = jax.random.PRNGKey(seed)
-    if problem.batched:
-        expected = (problem.batch,) + problem.shape
-        if tuple(x.shape) != expected:
-            raise ValueError(
-                f"batched problem expects x.shape {expected}, got {tuple(x.shape)}"
+    with jax.profiler.TraceAnnotation("cp_als.init"), jax.named_scope("init"):
+        key = jax.random.PRNGKey(seed)
+        if problem.batched:
+            expected = (problem.batch,) + problem.shape
+            if tuple(x.shape) != expected:
+                raise ValueError(
+                    f"batched problem expects x.shape {expected}, got {tuple(x.shape)}"
+                )
+            factors = init_factors or random_factors(
+                key, problem.shape, problem.rank, x.dtype, batch=problem.batch
             )
-        factors = init_factors or random_factors(
-            key, problem.shape, problem.rank, x.dtype, batch=problem.batch
+        else:
+            factors = init_factors or random_factors(key, x.shape, problem.rank, x.dtype)
+        x, factors = executor.prepare(problem, x, factors)
+        # donated buffers are deleted after the first dispatch; prepare() may
+        # pass caller arrays through unchanged (LocalExecutor), so donation is
+        # keyed off the backend (a no-op-with-warning on CPU) and caller-owned
+        # init_factors are copied once rather than invalidated under the caller.
+        donate = (3, 4, 5, 6, 7) if jax.default_backend() != "cpu" else ()
+        if donate and init_factors is not None:
+            factors = [jnp.array(u, copy=True) for u in factors]
+        if problem.batched:
+            weights = jnp.ones((problem.batch, problem.rank), x.dtype)
+            norm_x = tensor_norm(x, batched=True).astype(x.dtype)
+        else:
+            weights = jnp.ones((problem.rank,), x.dtype)
+            norm_x = tensor_norm(x).astype(x.dtype)
+        carry = (
+            executor.init_carry(plan, x, factors)
+            if hasattr(executor, "init_carry")
+            else None
         )
-    else:
-        factors = init_factors or random_factors(key, x.shape, problem.rank, x.dtype)
-    x, factors = executor.prepare(problem, x, factors)
-    # donated buffers are deleted after the first dispatch; prepare() may
-    # pass caller arrays through unchanged (LocalExecutor), so donation is
-    # keyed off the backend (a no-op-with-warning on CPU) and caller-owned
-    # init_factors are copied once rather than invalidated under the caller.
-    donate = (3, 4, 5, 6, 7) if jax.default_backend() != "cpu" else ()
-    if donate and init_factors is not None:
-        factors = [jnp.array(u, copy=True) for u in factors]
-    if problem.batched:
-        weights = jnp.ones((problem.batch, problem.rank), x.dtype)
-        norm_x = tensor_norm(x, batched=True).astype(x.dtype)
-    else:
-        weights = jnp.ones((problem.rank,), x.dtype)
-        norm_x = tensor_norm(x).astype(x.dtype)
-    carry = (
-        executor.init_carry(plan, x, factors)
-        if hasattr(executor, "init_carry")
-        else None
-    )
-    # Grams are computed once here and carried across sweeps (each update
-    # refreshes exactly the changed factor's Gram inside the sweep).
-    gs = grams(factors)
-    # PP plans carry the cache through the same scan (zeros + inf drift, so
-    # the first sweep is exact); pp stays None otherwise and the chunk
-    # graph is the classic exact one, bitwise.
-    pp = _pp_init(problem, x, factors) if plan.pp else None
+        # Grams are computed once here and carried across sweeps (each update
+        # refreshes exactly the changed factor's Gram inside the sweep).
+        gs = grams(factors)
+        # PP plans carry the cache through the same scan (zeros + inf drift,
+        # so the first sweep is exact); pp stays None otherwise and the chunk
+        # graph is the classic exact one, bitwise.
+        pp = _pp_init(problem, x, factors) if plan.pp else None
 
-    # One dispatch = `length` sweeps under lax.scan.  jit only the evolving
-    # buffers out (returning x from the compiled fn would make XLA emit a
-    # full-tensor copy every chunk); donate them in so off-CPU backends
-    # update factors/Grams/carry/PP-cache in place.
-    def _chunk(x, norm_x, it0, factors, weights, gs, carry, pp, length):
-        def body(c, _):
-            factors, weights, gs, carry, pp, it = c
-            state = SweepState(
-                x=x, factors=factors, weights=weights, norm_x=norm_x,
-                it=it, carry=carry, grams=gs, pp=pp,
+        # One dispatch = `length` sweeps under lax.scan.  jit only the
+        # evolving buffers out (returning x from the compiled fn would make
+        # XLA emit a full-tensor copy every chunk); donate them in so off-CPU
+        # backends update factors/Grams/carry/PP-cache in place.
+        def _chunk(x, norm_x, it0, factors, weights, gs, carry, pp, length):
+            def body(c, _):
+                factors, weights, gs, carry, pp, it = c
+                state = SweepState(
+                    x=x, factors=factors, weights=weights, norm_x=norm_x,
+                    it=it, carry=carry, grams=gs, pp=pp,
+                )
+                out = als_sweep(problem, plan, executor, state)
+                return (
+                    (out.factors, out.weights, out.grams, out.carry, out.pp, it + 1),
+                    out.fit,
+                )
+
+            init = (factors, weights, gs, carry, pp, it0)
+            (factors, weights, gs, carry, pp, _), fits = jax.lax.scan(
+                body, init, None, length=length
             )
-            out = als_sweep(problem, plan, executor, state)
-            return (
-                (out.factors, out.weights, out.grams, out.carry, out.pp, it + 1),
-                out.fit,
-            )
+            return factors, weights, gs, carry, pp, fits
 
-        init = (factors, weights, gs, carry, pp, it0)
-        (factors, weights, gs, carry, pp, _), fits = jax.lax.scan(
-            body, init, None, length=length
-        )
-        return factors, weights, gs, carry, pp, fits
+        if dispatch_cache is not None and dispatch_key in dispatch_cache:
+            chunk = dispatch_cache[dispatch_key]
+        else:
+            chunk = jax.jit(_chunk, static_argnames=("length",), donate_argnums=donate)
+            if dispatch_cache is not None:
+                dispatch_cache[dispatch_key] = chunk
 
-    if dispatch_cache is not None and dispatch_key in dispatch_cache:
-        chunk = dispatch_cache[dispatch_key]
-    else:
-        chunk = jax.jit(_chunk, static_argnames=("length",), donate_argnums=donate)
-        if dispatch_cache is not None:
-            dispatch_cache[dispatch_key] = chunk
-
+    syncs = 0
     fit_prev = -math.inf
     fit = jnp.asarray(0.0, x.dtype)
     it = 0
@@ -630,32 +647,44 @@ def cp_als(
     while it < n_iters and not done:
         length = min(k, n_iters - it)
         t0 = time.perf_counter()
-        factors, weights, gs, carry, pp, fits = chunk(
-            x, norm_x, jnp.asarray(it), factors, weights, gs, carry, pp,
-            length=length,
-        )
-        fits = _block_until_ready(fits)  # the chunk's single host sync
+        with jax.profiler.TraceAnnotation("cp_als.dispatch"):
+            factors, weights, gs, carry, pp, fits = chunk(
+                x, norm_x, jnp.asarray(it), factors, weights, gs, carry, pp,
+                length=length,
+            )
+        with jax.profiler.TraceAnnotation("cp_als.wait"):
+            fits = _block_until_ready(fits)  # the chunk's one wait
+        syncs += 1
         dt = time.perf_counter() - t0
-        for j in range(length):
-            if problem.batched:
-                # per-problem fits (B,); stop only when EVERY problem's
-                # fit delta clears tol (one fused dispatch, shared stop).
-                f = fits[j]
-                if callback is not None:
-                    callback(it + j, float(jnp.mean(f)), dt / length)
-                if track_fit and bool(jnp.max(jnp.abs(f - fit_prev)) < tol):
-                    done = True
-                fit_prev = f
-            else:
-                f = float(fits[j])
-                if callback is not None:
-                    callback(it + j, f, dt / length)
-                if track_fit and abs(f - fit_prev) < tol:
-                    done = True
-                fit_prev = f
-        it += length
-        fit = fits[length - 1]
+        with jax.profiler.TraceAnnotation("cp_als.check"):
+            for j in range(length):
+                if problem.batched:
+                    # per-problem fits (B,); stop only when EVERY problem's
+                    # fit delta clears tol (one fused dispatch, shared stop).
+                    f = fits[j]
+                    if callback is not None:
+                        syncs += 1
+                        callback(it + j, float(jnp.mean(f)), dt / length)
+                    if track_fit:
+                        syncs += 1
+                        if bool(jnp.max(jnp.abs(f - fit_prev)) < tol):
+                            done = True
+                    fit_prev = f
+                else:
+                    f = float(fits[j])
+                    syncs += 1
+                    if callback is not None:
+                        callback(it + j, f, dt / length)
+                    if track_fit and abs(f - fit_prev) < tol:
+                        done = True
+                    fit_prev = f
+            it += length
+            fit = fits[length - 1]
+    pp_exact_sweeps = None
+    if pp is not None:
+        pp_exact_sweeps = int(pp.n_exact)
+        syncs += 1
     return CPState(
         factors=factors, weights=weights, fit=fit, it=it,
-        pp_exact_sweeps=int(pp.n_exact) if pp is not None else None,
+        pp_exact_sweeps=pp_exact_sweeps, host_syncs=syncs,
     )

@@ -1,0 +1,127 @@
+"""What cp_als leaves for a profiler and a reader: named scopes on the
+sweep's device ops, host spans around each step of the host loop, and
+CPState.host_syncs."""
+
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import repro.plan.sweep as sweeplib
+from repro.core import cp_full, random_factors, random_tensor
+from repro.core.cpals import CPState, grams
+from repro.plan import Problem, cp_als, plan_sweep
+
+SPANS = ("cp_als.init", "cp_als.dispatch", "cp_als.wait", "cp_als.check")
+
+
+def _planted(shape=(8, 7, 6, 5), rank=3, seed=4):
+    return cp_full(None, random_factors(jax.random.PRNGKey(seed), shape, rank)), rank
+
+
+def _chunk_op_names(schedule):
+    """The op names in the compiled chunk of a cp_als run on ``schedule``."""
+    x, rank = _planted()
+    plan = plan_sweep(Problem.from_tensor(x, rank), schedule=schedule)
+    cache = {}
+    st = cp_als(x, plan, n_iters=1, dispatch_cache=cache, dispatch_key=0)
+    fs = list(st.factors)
+    lowered = cache[0].lower(x, jnp.float32(1.0), jnp.asarray(0), fs, st.weights, grams(fs),
+                             None, None, length=1)
+    return plan, re.findall(r'op_name="([^"]+)"', lowered.compile().as_text())
+
+
+@pytest.mark.parametrize("schedule", [None, "flat"])
+def test_chunk_ops_carry_the_sweep_scopes(schedule):
+    """Every schedule node's contraction runs under ``mttkrp.node<id>``,
+    each mode's update under ``update.mode<n>`` and the fit under ``fit``;
+    nothing of the sweep carries ``init``."""
+    plan, names = _chunk_op_names(schedule)
+    for node in plan.resolved_schedule.walk():
+        assert any(re.search(rf"/mttkrp\.node{node.id}/(.*/)?dot_general", n) for n in names), node
+    for n in range(plan.problem.ndim):
+        assert any(f"/update.mode{n}/" in name for name in names), n
+    assert any("/fit/" in name for name in names)
+    assert not any("/init/" in name for name in names)
+    # a scope is a whole path component: node ids never run together
+    scopes = {p for n in names for p in n.split("/") if re.fullmatch(r"mttkrp\.node\d+", p)}
+    assert scopes == {f"mttkrp.node{node.id}" for node in plan.resolved_schedule.walk()}
+
+
+def test_cp_als_spans_in_the_host_trace(tmp_path):
+    """Under jax.profiler the host plane holds one ``cp_als.init`` span per
+    solve and one dispatch, wait and check span per chunk, in that order."""
+    from jax.profiler import ProfileData
+
+    x, rank = _planted()
+    plan = plan_sweep(Problem.from_tensor(x, rank))
+    cache = {}
+    cp_als(x, plan, n_iters=2, dispatch_cache=cache, dispatch_key=0)
+    with jax.profiler.trace(str(tmp_path)):
+        st = cp_als(x, plan, n_iters=5, tol=0.0, sweeps_per_sync=2,
+                    dispatch_cache=cache, dispatch_key=0)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = sorted(
+        (e.start_ns, e.end_ns, e.name)
+        for p in ProfileData.from_file(path).planes if p.name == "/host:CPU"
+        for line in p.lines for e in line.events if e.name in SPANS
+    )
+    names = [n for _, _, n in events]
+    assert st.it == 5
+    assert names == ["cp_als.init"] + ["cp_als.dispatch", "cp_als.wait", "cp_als.check"] * 3
+    assert all(a[1] <= b[0] for a, b in zip(events, events[1:]))  # one after another
+
+
+@pytest.fixture
+def waits(monkeypatch):
+    """Counts cp_als's waits at its one wait point."""
+    counts = {"n": 0}
+    real = jax.block_until_ready
+
+    def counting(tree):
+        counts["n"] += 1
+        return real(tree)
+
+    monkeypatch.setattr(sweeplib, "_block_until_ready", counting)
+    return counts
+
+
+@pytest.mark.parametrize("k, n_waits", [(1, 6), (3, 2), (4, 2)])
+def test_host_syncs_are_waits_plus_scalar_reads(waits, k, n_waits):
+    """Unbatched: one wait per chunk and one ``float`` of each sweep's fit."""
+    x, rank = _planted()
+    plan = plan_sweep(Problem.from_tensor(x, rank))
+    st = cp_als(x, plan, n_iters=6, track_fit=False, seed=7, sweeps_per_sync=k)
+    assert waits["n"] == n_waits
+    assert st.host_syncs == n_waits + 6
+
+
+def test_host_syncs_batched_count_each_read_made(waits):
+    """Batched: a read for the callback's mean and one for the convergence
+    test, each only where it is made."""
+    B, shape, rank = 4, (6, 5, 4), 2
+    x = random_tensor(jax.random.PRNGKey(0), (B,) + shape)
+    init = random_factors(jax.random.PRNGKey(1), shape, rank, batch=B)
+    plan = plan_sweep(Problem.from_tensor(x, rank, batch=B))
+    st = cp_als(x, plan, n_iters=6, track_fit=False, init_factors=init, sweeps_per_sync=3)
+    assert (waits["n"], st.host_syncs) == (2, 2)
+    waits["n"] = 0
+    st = cp_als(x, plan, n_iters=6, tol=0.0, init_factors=init, sweeps_per_sync=3,
+                callback=lambda it, fit, dt: None)
+    assert (waits["n"], st.host_syncs) == (2, 2 + 2 * 6)
+
+
+def test_host_syncs_count_the_pp_read(waits):
+    """A pairwise-perturbation run reads its exact-sweep count once more."""
+    x, rank = _planted()
+    plan = plan_sweep(Problem.from_tensor(x, rank, pp_tol=0.05))
+    st = cp_als(x, plan, n_iters=4, track_fit=False, seed=3)
+    assert st.pp_exact_sweeps is not None
+    assert st.host_syncs == waits["n"] + 4 + 1
+
+
+def test_cp_state_host_syncs_defaults_to_zero():
+    st = CPState(factors=[], weights=jnp.ones(2), fit=jnp.float32(0.5))
+    assert st.host_syncs == 0 and st.pp_exact_sweeps is None
