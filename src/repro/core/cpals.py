@@ -44,6 +44,9 @@ class CPState:
     # Times cp_als blocked the host on the device: each wait for a
     # chunk and each read of a device value into Python (float/bool/int).
     host_syncs: int = 0
+    # Tensor-sized matrix views of the tensor cp_als built for the solve
+    # (once, before the first sweep); 0 where the sweeps read the tensor.
+    prepared_views: int = 0
 
 
 @dataclass

@@ -44,18 +44,91 @@ from .tensor_ops import mode_letters
 Array = jax.Array
 
 
+def view_split(lo: int, hi: int, n: int) -> int:
+    """The split point of the matrix view a root partial over ``[lo, hi)``
+    of an order-``n`` tensor reads: ``hi``, unless the range runs to the
+    last mode, then ``lo`` (see :func:`partial_from_view`)."""
+    return lo if hi == n else hi
+
+
+def matrix_view(x: Array, m: int, *, batched: bool = False) -> Array:
+    """``X_(m)``: the tensor as a ``prod(shape[:m]) x prod(shape[m:])``
+    matrix, in the tensor's own row-major order (no entry moves).
+
+    The paper's "free reshape": free in a row-major layout, a relayout
+    under a TPU's (8, 128) tiles whenever a minor dim is not a whole tile.
+    ``batched`` keeps a leading batch axis in front (``m`` counts the
+    tensor's modes, not the batch).
+    """
+    lead = 1 if batched else 0
+    left = math.prod(x.shape[lead:lead + m])
+    return x.reshape(x.shape[:lead] + (left, -1))
+
+
+def view_times_krp(xm: Array, right_factors: Sequence[Array]) -> Array:
+    """``X_(m) @ K_R``: the view contracted with the KRP of the trailing
+    modes' factors, shape ``(rows, C)``."""
+    c = right_factors[0].shape[1]
+    return xm @ krp_or_ones(list(right_factors), c, xm.dtype)
+
+
+def krp_times_view(left_factors: Sequence[Array], xm: Array) -> Array:
+    """``K_L^T @ X_(m)``: the KRP of the leading modes' factors contracted
+    with the view, shape ``(C, cols)``."""
+    c = left_factors[0].shape[1]
+    return krp_or_ones(list(left_factors), c, xm.dtype).T @ xm
+
+
+def _root_partial(
+    xm: Array, left: Sequence[Array], right: Sequence[Array],
+    kept: Sequence[int], *, fence: bool,
+) -> Array:
+    """The partial of shape ``kept + (C,)`` left when the leading modes'
+    factors ``left`` and the trailing modes' ``right`` are contracted out
+    of the view ``xm``.  The trailing modes go first through ``X_(m) @
+    K_R``; with no trailing modes it is ``K_L^T @ X_(m)`` instead; a middle
+    range then contracts its leading modes against their KRP along the
+    shared rank axis.  ``fence`` ends the GEMM in an optimization barrier
+    on its small output (see :func:`partial_from_view`)."""
+    done = jax.lax.optimization_barrier if fence else (lambda t: t)
+    kept = tuple(kept)
+    if not right:
+        t = done(krp_times_view(left, xm))  # (C, R)
+        return jnp.moveaxis(t.reshape((t.shape[0],) + kept), 0, -1)
+    t = done(view_times_krp(xm, right))  # (L, C)
+    c = t.shape[-1]
+    if not left:
+        return t.reshape(kept + (c,))
+    k_l = krp_or_ones(list(left), c, xm.dtype)  # (L', C)
+    t3 = t.reshape(k_l.shape[0], -1, c)
+    return jnp.einsum("lmc,lc->mc", t3, k_l).reshape(kept + (c,))
+
+
+def partial_from_view(
+    xm: Array, factors: Sequence[Array], lo: int, hi: int, kept: Sequence[int]
+) -> Array:
+    """The root partial over ``[lo, hi)`` from the view ``X_(m)``, ``m =
+    view_split(lo, hi, len(factors))``, built once for many sweeps;
+    ``kept`` are the dims of the kept modes.  Returns the partial tensor
+    of shape ``kept + (C,)``, by the GEMMs of :func:`partial_mttkrp_range`.
+    ``factors`` is the full mode-ordered list; entries inside ``[lo, hi)``
+    are ignored.
+
+    The GEMM ends in an optimization barrier on its small output: without
+    it XLA folds the partial's reshape (and the leaves that read it) into
+    the GEMM and reshapes or transposes the view instead, which on a TPU
+    relays out the whole view in every sweep.
+    """
+    return _root_partial(xm, factors[:lo], factors[hi:], kept, fence=True)
+
+
 def partial_mttkrp_right(x: Array, right_factors: Sequence[Array]) -> Array:
     """T_L = X contracted with the KRP of the trailing ``len(right)`` modes.
 
     Returns a tensor of shape  x.shape[:m] + (C,).
     """
-    n_right = len(right_factors)
-    c = right_factors[0].shape[1]
-    m = x.ndim - n_right
-    left_size = math.prod(x.shape[:m])
-    k_r = krp_or_ones(list(right_factors), c, x.dtype)  # (R, C)
-    t = x.reshape(left_size, -1) @ k_r
-    return t.reshape(x.shape[:m] + (c,))
+    m = x.ndim - len(right_factors)
+    return _root_partial(matrix_view(x, m), (), right_factors, x.shape[:m], fence=False)
 
 
 def partial_mttkrp_left(x: Array, left_factors: Sequence[Array]) -> Array:
@@ -64,40 +137,27 @@ def partial_mttkrp_left(x: Array, left_factors: Sequence[Array]) -> Array:
     Returns a tensor of shape  x.shape[m:] + (C,).
     """
     m = len(left_factors)
-    c = left_factors[0].shape[1]
-    right_size = math.prod(x.shape[m:])
-    k_l = krp_or_ones(list(left_factors), c, x.dtype)  # (L, C)
-    t = k_l.T @ x.reshape(-1, right_size)  # (C, R)
-    return jnp.moveaxis(t.reshape((c,) + x.shape[m:]), 0, -1)
+    return _root_partial(matrix_view(x, m), left_factors, (), x.shape[m:], fence=False)
 
 
 def partial_mttkrp_range(x: Array, factors: Sequence[Array], lo: int, hi: int) -> Array:
     """Contract every mode of ``x`` outside ``[lo, hi)`` with its factor.
 
     Returns the partial tensor of shape ``x.shape[lo:hi] + (C,)`` -- the
-    root-level contraction of a general dimension-tree node.  The trailing
-    modes ``[hi, N)`` go first through the same GEMM as
-    :func:`partial_mttkrp_right` (so ``lo == 0`` reproduces it exactly, and
-    ``hi == N`` reproduces :func:`partial_mttkrp_left`); a leading range is
-    then contracted against its KRP along the shared rank axis.  ``factors``
-    is the full mode-ordered list; entries inside ``[lo, hi)`` are ignored.
+    root-level contraction of a general dimension-tree node, through the
+    GEMMs on the view ``X_(m)``, ``m = view_split(lo, hi, N)``, taken
+    inside the call (so ``lo == 0`` reproduces :func:`partial_mttkrp_right`
+    exactly, and ``hi == N`` reproduces :func:`partial_mttkrp_left`).
+    ``factors`` is the full mode-ordered list; entries inside ``[lo, hi)``
+    are ignored.
     """
     n = x.ndim
     if not 0 <= lo < hi <= n:
         raise ValueError(f"range [{lo}, {hi}) invalid for order-{n} tensor")
     if lo == 0 and hi == n:
         raise ValueError("range [0, N) contracts nothing")
-    if lo == 0:
-        return partial_mttkrp_right(x, list(factors[hi:]))
-    if hi == n:
-        return partial_mttkrp_left(x, list(factors[:lo]))
-    t = partial_mttkrp_right(x, list(factors[hi:]))  # x.shape[:hi] + (C,)
-    c = factors[0].shape[1]
-    left_size = math.prod(x.shape[:lo])
-    k_l = krp_or_ones(list(factors[:lo]), c, x.dtype)  # (L, C)
-    t3 = t.reshape(left_size, -1, c)
-    out = jnp.einsum("lmc,lc->mc", t3, k_l)
-    return out.reshape(x.shape[lo:hi] + (c,))
+    xm = matrix_view(x, view_split(lo, hi, n))
+    return _root_partial(xm, factors[:lo], factors[hi:], x.shape[lo:hi], fence=False)
 
 
 def contract_from_partial(

@@ -480,14 +480,21 @@ def _tune_nodes(
     (``Problem.intra_axes``) every node whose reduction spans both levels
     is additionally measured under ``collective="hierarchical"``, so the
     planner's per-node flat-vs-hierarchical pick argmins over measured
-    head-to-head times rather than modeled bandwidths.  Stops
+    head-to-head times rather than modeled bandwidths.  Root partials
+    on an executor with ``contract_view`` are timed as
+    :func:`repro.plan.sweep.cp_als` runs them: on the matrix view its
+    set-up program builds, which is built here too but not timed (a solve
+    pays it once, not once a sweep).  Stops
     cleanly when ``budget`` runs out -- unmeasured nodes simply keep their
     analytic costs at plan time.
     """
+    from repro.core.dimtree import view_split
+
     from .cost import hierarchical_applicable  # lazy: cost imports schedule
     from .executor import make_executor  # lazy: avoids an import cycle
     from .planner import plan_sweep
     from .schedule import enumerate_schedules
+    from .sweep import _reads_view, prepare_operands, view_splits
 
     kinds = (
         ("sharded", "overlapping", "compressed") if problem.sharded else ("local",)
@@ -505,9 +512,18 @@ def _tune_nodes(
             carry = (
                 ex.init_carry(plan, xs, fs) if hasattr(ex, "init_carry") else None
             )
+            splits = view_splits(plan, ex)
+            views = (
+                prepare_operands(xs, splits=splits, batched=problem.batched)[1]
+                if splits
+                else {}
+            )
             cache: dict[int, Array] = {ROOT: xs}
             for node in sched.walk():
                 src = cache[node.parent]
+                reads_view = bool(views) and _reads_view(node)
+                if reads_view:
+                    src = views[view_split(node.lo, node.hi, problem.ndim)]
                 planned = plan.node_plan(node.id).algorithm
                 algs = (
                     _leaf_algorithms(problem, node, kind)
@@ -557,13 +573,18 @@ def _tune_nodes(
                             if alg == planned and coll == "flat":
                                 run_out, carry = fn(src, fs, carry)
                         else:
-                            fn = jax.jit(
-                                lambda s, f, node=node, alg=alg, tl=tl, coll=coll: (
-                                    ex.contract(
-                                        node, s, f, alg, tiles=tl, collective=coll
+                            if reads_view:
+                                fn = jax.jit(
+                                    lambda v, f, node=node: ex.contract_view(node, v, f)
+                                )
+                            else:
+                                fn = jax.jit(
+                                    lambda s, f, node=node, alg=alg, tl=tl, coll=coll: (
+                                        ex.contract(
+                                            node, s, f, alg, tiles=tl, collective=coll
+                                        )
                                     )
                                 )
-                            )
                             if key not in seen and not budget.exhausted():
                                 seen.add(key)
                                 rows.append(

@@ -30,7 +30,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.dimtree import contract_from_partial, partial_mttkrp_range
+from repro.core.dimtree import (
+    contract_from_partial,
+    partial_from_view,
+    partial_mttkrp_range,
+)
 from repro.core.mttkrp import mttkrp, mttkrp_batched
 from repro.core.tensor_ops import mode_letters
 from repro.dist.dist_mttkrp import (
@@ -75,7 +79,11 @@ class Executor(Protocol):
     factors)`` and ``contract_carry(node, src, factors, algorithm, carry)
     -> (out, carry)`` -- which the engine threads through
     ``SweepState.carry`` when present (``hasattr`` duck typing; stateless
-    executors skip both).
+    executors skip both).  Executors that can contract a root partial from
+    the tensor's matrix view implement ``contract_view(node, view,
+    factors)``; :func:`repro.plan.sweep.cp_als` then builds each view once
+    a solve and hands it to the node (:class:`LocalExecutor` only; the
+    others read the tensor as before).
     """
 
     def prepare(self, problem, x: Array, factors: Sequence[Array]):
@@ -97,6 +105,14 @@ class Executor(Protocol):
         within the node axis first so only shards cross the slow level
         (ignored by executors without collectives)."""
         ...
+
+
+def _over_batch(fn, batched: bool, src: Array, factors: Sequence[Array]) -> Array:
+    """``fn(src, factors)``; with ``batched``, vmapped over the leading
+    batch axis of ``src`` and of every factor."""
+    if batched:
+        return jax.vmap(lambda t, *fs: fn(t, list(fs)))(src, *factors)
+    return fn(src, list(factors))
 
 
 class LocalExecutor:
@@ -127,19 +143,31 @@ class LocalExecutor:
                         src, list(factors), node.mode, method=algorithm, tiles=tiles
                     )
                 return mttkrp(src, list(factors), node.mode, method=algorithm, tiles=tiles)
-            if batched:
-                return jax.vmap(
-                    lambda t, *fs: partial_mttkrp_range(t, list(fs), node.lo, node.hi)
-                )(src, *factors)
-            return partial_mttkrp_range(src, list(factors), node.lo, node.hi)
-        if batched:
-            return jax.vmap(
-                lambda t, *fs: contract_from_partial(
-                    t, dict(zip(node.contracted, fs)), node.lo, node.hi, node.parent_lo
-                )
-            )(src, *[factors[m] for m in node.contracted])
-        sibs = {m: factors[m] for m in node.contracted}
-        return contract_from_partial(src, sibs, node.lo, node.hi, node.parent_lo)
+            return _over_batch(
+                lambda t, fs: partial_mttkrp_range(t, fs, node.lo, node.hi),
+                batched, src, factors,
+            )
+        return _over_batch(
+            lambda t, fs: contract_from_partial(
+                t, dict(zip(node.contracted, fs)), node.lo, node.hi, node.parent_lo
+            ),
+            batched, src, [factors[m] for m in node.contracted],
+        )
+
+    def contract_view(
+        self, node: ContractionNode, view: Array, factors: Sequence[Array]
+    ) -> Array:
+        """One root partial node from the tensor's matrix view ``X_(m)``
+        (``m = view_split(node.lo, node.hi, N)``): the same GEMMs as
+        :meth:`contract` on the raw tensor, on an operand laid out once a
+        solve, each fenced so that the view stays as laid out
+        (:func:`repro.core.dimtree.partial_from_view`).  A 3-D view carries
+        a leading batch axis, vmapped over with the factors."""
+        kept = node.shape[:-1]
+        return _over_batch(
+            lambda v, fs: partial_from_view(v, fs, node.lo, node.hi, kept),
+            view.ndim == 3, view, factors,
+        )
 
     def pp_pairs(
         self, problem, x: Array, factors: Sequence[Array]

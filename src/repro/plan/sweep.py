@@ -17,6 +17,7 @@ Gram/Hadamard/pinv/normalize/fit algebra exists ONLY here.
 
 from __future__ import annotations
 
+import functools
 import math
 import time
 from dataclasses import dataclass
@@ -32,12 +33,13 @@ from repro.core.cpals import (
     hadamard_except,
     normalize_columns,
 )
+from repro.core.dimtree import matrix_view, view_split
 from repro.core.tensor_ops import random_factors, tensor_norm
 
 from .executor import Executor, LocalExecutor, ShardedExecutor
 from .planner import SweepPlan, plan_sweep
 from .problem import Problem
-from .schedule import ROOT, pp_pairs as pp_pair_meta
+from .schedule import ROOT, ContractionNode, pp_pairs as pp_pair_meta
 
 Array = jax.Array
 
@@ -71,9 +73,15 @@ class SweepState:
     plan enabled PP sweeps, ``None`` otherwise -- and ``None`` keeps the
     sweep graph literally the classic exact one (the ``pp_tol=0`` bitwise
     guarantee is *by construction*, not by tolerance).
+
+    ``views`` maps a split point ``m`` to the tensor's matrix view
+    ``X_(m)`` (:func:`repro.core.dimtree.matrix_view`): root partial nodes
+    then read their view through the executor's ``contract_view`` instead
+    of ``x``, and ``x`` may be ``None`` when nothing else reads it.
+    ``None`` (every direct caller) reads ``x``.
     """
 
-    x: Array
+    x: Array | None
     factors: list[Array]
     weights: Array
     norm_x: Array
@@ -82,12 +90,14 @@ class SweepState:
     carry: Any = None
     grams: list[Array] | None = None
     pp: Any = None
+    views: dict[int, Array] | None = None
 
 
 jax.tree_util.register_pytree_node(
     SweepState,
     lambda s: (
-        (s.x, s.factors, s.weights, s.norm_x, s.it, s.fit, s.carry, s.grams, s.pp),
+        (s.x, s.factors, s.weights, s.norm_x, s.it, s.fit, s.carry, s.grams, s.pp,
+         s.views),
         None,
     ),
     lambda _, c: SweepState(*c),
@@ -233,6 +243,7 @@ def _exact_sweep(
     weights = state.weights
     it = state.it
     carry = state.carry
+    views = state.views or {}
     use_carry = hasattr(executor, "contract_carry")
     gs = list(state.grams) if state.grams is not None else grams(factors)
     m_last = None
@@ -247,7 +258,11 @@ def _exact_sweep(
         else:
             alg, tiles, coll = "auto", None, "flat"
         with jax.named_scope(f"mttkrp.node{node.id}"):
-            if use_carry:
+            if views and _reads_view(node):
+                out = executor.contract_view(
+                    node, views[view_split(node.lo, node.hi, problem.ndim)], factors
+                )
+            elif use_carry:
                 out, carry = executor.contract_carry(
                     node, src, factors, alg, carry, tiles=tiles, collective=coll
                 )
@@ -266,7 +281,7 @@ def _exact_sweep(
         fit = fit_from_last_mttkrp(gs, weights, m_last, factors[-1], state.norm_x)
     return SweepState(
         x=x, factors=factors, weights=weights, norm_x=state.norm_x, it=it, fit=fit,
-        carry=carry, grams=gs, pp=state.pp,
+        carry=carry, grams=gs, pp=state.pp, views=state.views,
     )
 
 
@@ -309,7 +324,7 @@ def _pp_sweep(
     )
     return SweepState(
         x=state.x, factors=factors, weights=weights, norm_x=state.norm_x,
-        it=it, fit=fit, carry=state.carry, grams=gs, pp=new_pp,
+        it=it, fit=fit, carry=state.carry, grams=gs, pp=new_pp, views=state.views,
     )
 
 
@@ -320,7 +335,7 @@ def _with_payload(state: SweepState, payload) -> SweepState:
     factors, weights, fit, carry, gs = payload
     return SweepState(
         x=state.x, factors=list(factors), weights=weights, norm_x=state.norm_x,
-        it=state.it, fit=fit, carry=carry, grams=gs, pp=state.pp,
+        it=state.it, fit=fit, carry=carry, grams=gs, pp=state.pp, views=state.views,
     )
 
 
@@ -438,6 +453,7 @@ def _gated_sweep(
         x=out.x, factors=out.factors, weights=out.weights, norm_x=out.norm_x,
         it=out.it, fit=out.fit, carry=out.carry, grams=out.grams,
         pp=PPState(ref=ref, pairs=pairs, base=base, drift=drift, n_exact=n_exact),
+        views=out.views,
     )
 
 
@@ -479,6 +495,77 @@ def legacy_sweep(
     )
     out = als_sweep(problem, plan, executor, state)
     return out.factors, out.weights, out.fit
+
+
+def _reads_view(node: ContractionNode) -> bool:
+    """Whether ``node`` reads a matrix view of the tensor where views are
+    built: a root partial, contracted from the tensor and not a leaf."""
+    return node.from_root and not node.is_leaf
+
+
+def view_splits(plan: SweepPlan, executor: Executor) -> tuple[int, ...]:
+    """The split points of the matrix views :func:`cp_als` builds for
+    ``plan`` on ``executor``: one per distinct view the schedule's root
+    partials read (:func:`repro.core.dimtree.view_split`).  Empty where the
+    executor has no ``contract_view`` or the schedule no root partial."""
+    if not hasattr(executor, "contract_view"):
+        return ()
+    n = plan.problem.ndim
+    return tuple(sorted({
+        view_split(node.lo, node.hi, n)
+        for node in plan.resolved_schedule.walk()
+        if _reads_view(node)
+    }))
+
+
+@functools.partial(jax.jit, static_argnames=("splits", "batched"))
+def prepare_operands(x: Array, *, splits: tuple[int, ...], batched: bool):
+    """The set-up program :func:`cp_als` runs once a solve, before its
+    first dispatch: the tensor's norm and its matrix views ``{m: X_(m)}``
+    for each split point in ``splits``, from one read of ``x`` under the
+    scope ``prepare``.  On a TPU each view is a relayout of the whole
+    tensor (:func:`repro.core.dimtree.matrix_view`), paid here once a
+    solve instead of inside every sweep."""
+    with jax.named_scope("prepare"):
+        norm_x = tensor_norm(x, batched=batched).astype(x.dtype)
+        views = {m: matrix_view(x, m, batched=batched) for m in splits}
+    return norm_x, views
+
+
+def sweep_chunk(plan: SweepPlan, executor: Executor, donate: tuple[int, ...] = ()):
+    """The jitted program one :func:`cp_als` dispatch runs:
+    ``chunk(x, norm_x, it0, factors, weights, gs, carry, pp, views=None, *,
+    length)`` runs ``length`` sweeps under ``lax.scan`` and returns
+    ``(factors, weights, gs, carry, pp, fits)``.
+
+    Only the evolving buffers go out (returning ``x`` would make XLA emit a
+    full-tensor copy every chunk); ``donate`` names the arguments donated
+    in, so off-CPU backends update factors/Grams/carry/PP-cache in place.
+    With ``views`` the root partials read them, and ``x`` is ``None``
+    unless a root leaf or the PP cache reads it.
+    """
+    problem = plan.problem
+
+    def _chunk(x, norm_x, it0, factors, weights, gs, carry, pp, views=None, *, length):
+        def body(c, _):
+            factors, weights, gs, carry, pp, it = c
+            state = SweepState(
+                x=x, factors=factors, weights=weights, norm_x=norm_x,
+                it=it, carry=carry, grams=gs, pp=pp, views=views,
+            )
+            out = als_sweep(problem, plan, executor, state)
+            return (
+                (out.factors, out.weights, out.grams, out.carry, out.pp, it + 1),
+                out.fit,
+            )
+
+        init = (factors, weights, gs, carry, pp, it0)
+        (factors, weights, gs, carry, pp, _), fits = jax.lax.scan(
+            body, init, None, length=length
+        )
+        return factors, weights, gs, carry, pp, fits
+
+    return jax.jit(_chunk, static_argnames=("length",), donate_argnums=donate)
 
 
 def cp_als(
@@ -546,8 +633,14 @@ def cp_als(
     plan + executor under the same key).  A cache hit makes the call
     compile-free for shapes already traced.
 
+    Executors with the ``contract_view`` hook (:class:`LocalExecutor`)
+    have the tensor's matrix views built once a call, in the set-up
+    program :func:`prepare_operands` that also takes the norm, and each
+    root partial of the schedule reads its view; ``CPState.prepared_views``
+    counts them.  Views are not kept across calls.
+
     Observability costs nothing unless a profiler trace is being taken.
-    Device ops carry the named scopes ``init`` (this set-up),
+    Device ops carry the named scopes ``prepare`` (the set-up program),
     ``mttkrp.node<id>`` (one schedule node's contraction), ``update.mode<n>``
     and ``fit`` in their op names.  The host loop opens the
     ``jax.profiler.TraceAnnotation`` spans ``cp_als.init`` (entry to the
@@ -590,12 +683,10 @@ def cp_als(
         donate = (3, 4, 5, 6, 7) if jax.default_backend() != "cpu" else ()
         if donate and init_factors is not None:
             factors = [jnp.array(u, copy=True) for u in factors]
-        if problem.batched:
-            weights = jnp.ones((problem.batch, problem.rank), x.dtype)
-            norm_x = tensor_norm(x, batched=True).astype(x.dtype)
-        else:
-            weights = jnp.ones((problem.rank,), x.dtype)
-            norm_x = tensor_norm(x).astype(x.dtype)
+        lead = (problem.batch,) if problem.batched else ()
+        weights = jnp.ones(lead + (problem.rank,), x.dtype)
+        splits = view_splits(plan, executor)
+        norm_x, views = prepare_operands(x, splits=splits, batched=problem.batched)
         carry = (
             executor.init_carry(plan, x, factors)
             if hasattr(executor, "init_carry")
@@ -608,34 +699,18 @@ def cp_als(
         # so the first sweep is exact); pp stays None otherwise and the chunk
         # graph is the classic exact one, bitwise.
         pp = _pp_init(problem, x, factors) if plan.pp else None
-
-        # One dispatch = `length` sweeps under lax.scan.  jit only the
-        # evolving buffers out (returning x from the compiled fn would make
-        # XLA emit a full-tensor copy every chunk); donate them in so off-CPU
-        # backends update factors/Grams/carry/PP-cache in place.
-        def _chunk(x, norm_x, it0, factors, weights, gs, carry, pp, length):
-            def body(c, _):
-                factors, weights, gs, carry, pp, it = c
-                state = SweepState(
-                    x=x, factors=factors, weights=weights, norm_x=norm_x,
-                    it=it, carry=carry, grams=gs, pp=pp,
-                )
-                out = als_sweep(problem, plan, executor, state)
-                return (
-                    (out.factors, out.weights, out.grams, out.carry, out.pp, it + 1),
-                    out.fit,
-                )
-
-            init = (factors, weights, gs, carry, pp, it0)
-            (factors, weights, gs, carry, pp, _), fits = jax.lax.scan(
-                body, init, None, length=length
-            )
-            return factors, weights, gs, carry, pp, fits
+        # the chunk takes x only where something still reads it: a node
+        # off the root that gets no view, or the PP cache's pairwise build
+        reads_x = plan.pp or any(
+            node.from_root and not (views and _reads_view(node))
+            for node in plan.resolved_schedule.walk()
+        )
+        x_arg = x if reads_x else None
 
         if dispatch_cache is not None and dispatch_key in dispatch_cache:
             chunk = dispatch_cache[dispatch_key]
         else:
-            chunk = jax.jit(_chunk, static_argnames=("length",), donate_argnums=donate)
+            chunk = sweep_chunk(plan, executor, donate)
             if dispatch_cache is not None:
                 dispatch_cache[dispatch_key] = chunk
 
@@ -649,8 +724,8 @@ def cp_als(
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("cp_als.dispatch"):
             factors, weights, gs, carry, pp, fits = chunk(
-                x, norm_x, jnp.asarray(it), factors, weights, gs, carry, pp,
-                length=length,
+                x_arg, norm_x, jnp.asarray(it), factors, weights, gs, carry, pp,
+                views, length=length,
             )
         with jax.profiler.TraceAnnotation("cp_als.wait"):
             fits = _block_until_ready(fits)  # the chunk's one wait
@@ -687,4 +762,5 @@ def cp_als(
     return CPState(
         factors=factors, weights=weights, fit=fit, it=it,
         pp_exact_sweeps=pp_exact_sweeps, host_syncs=syncs,
+        prepared_views=len(views),
     )

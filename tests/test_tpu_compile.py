@@ -18,7 +18,9 @@ the TPU compiler.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,3 +127,50 @@ def test_krp_compiles_for_v5e(one_chip):
         _spec((FMRI[2], RANK), one_chip),
         _spec((FMRI[3], RANK), one_chip),
     )
+
+
+def _tensor_sized_relayouts(hlo: str, size: int) -> list[str]:
+    """Every copy, transpose or (non-bitcast) reshape in an optimized HLO
+    text, fused computations included, whose output holds at least
+    ``size`` elements."""
+    found = []
+    for m in re.finditer(r"%(\S+) = f32\[([0-9,]*)\]\S* (copy|transpose|reshape)\(", hlo):
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        if math.prod(dims) >= size:
+            found.append(f"{m.group(3)} {m.group(1)} f32[{m.group(2)}]")
+    return found
+
+
+def test_cp_als_sweep_reads_its_view_without_a_relayout(one_chip):
+    """At the paper's fMRI shape, the chunk cp_als dispatches (binary@2,
+    the tree the planner picks) reads the matrix view X_(2) as laid out
+    by the set-up program: its optimized HLO holds no tensor-sized copy,
+    transpose or reshape, and no tensor-sized temporary.  The set-up
+    program holds the view's reshape and the norm as one fused
+    multiply-reduce over the tensor."""
+    from repro.plan import LocalExecutor, Problem, plan_sweep
+    from repro.plan.sweep import prepare_operands, sweep_chunk, view_splits
+
+    size = math.prod(FMRI)
+    x = _spec(FMRI, one_chip)
+    plan = plan_sweep(Problem.from_tensor(x, RANK))
+    ex = LocalExecutor()
+    splits = view_splits(plan, ex)
+    assert plan.resolved_schedule.name == "binary@2" and splits == (2,)
+
+    prep = prepare_operands.lower(x, splits=splits, batched=False).compile().as_text()
+    assert re.search(r"= f32\[13275,40000\]\S* (reshape|copy|fusion)\(.*prepare/", prep)
+    assert re.search(r"= f32\[\]\S* fusion\(%x[.\d]*\).*prepare/reduce_sum", prep)
+    entry = prep[prep.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}\n")]
+    assert not re.search(r"= f32\[225,59,200,200\]\S* (multiply|square)\(", entry)
+
+    views = {2: _spec((225 * 59, 200 * 200), one_chip)}
+    chunk = sweep_chunk(plan, ex, donate=(3, 4, 5, 6, 7))
+    compiled = chunk.lower(
+        None, _spec((), one_chip), jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        _factors(FMRI, one_chip), _spec((RANK,), one_chip),
+        [_spec((RANK, RANK), one_chip)] * len(FMRI), None, None, views, length=1,
+    ).compile()
+    assert _tensor_sized_relayouts(compiled.as_text(), size) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * size / 100
