@@ -9,7 +9,9 @@ from dataclasses import dataclass, field
 
 import jax
 
-from bench import data, reference
+from bench import data, program_trace, reference
+
+SPANS = ("solve", "plan") + program_trace.PROGRAM_SPANS
 
 
 @dataclass

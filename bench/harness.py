@@ -6,11 +6,17 @@ Everything a cell is made of is found by name: its configuration in
 each metric's reader in ``metrics/<metric>.py``.  A run sets up, measures
 one window of ``--seconds``, reads the metrics, and then checks the
 window's answers against the plain reference (``reference.py``).
+
+A driver module holds ``SPANS``, the host spans its set-up and window
+open and the program's spans it wants read, and four functions:
+``setup(config, mix, seed, span) -> state``, ``describe(state) -> lines``,
+``window(state, seconds, span) -> {"window_s", "units", "attempted",
+"counters"}`` (each unit as :class:`Run` describes it) and
+``check(state, win, control) -> (numbers, failed)``.
 """
 
 from __future__ import annotations
 
-import importlib
 import importlib.util
 import json
 import shutil
@@ -20,9 +26,11 @@ import time
 from dataclasses import dataclass, field
 from pathlib import Path
 
+from bench import trace as trace_mod
+
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
-SPANS = ("solve", "plan")
+SPANS = (trace_mod.WINDOW,)  # the harness's own; each driver adds its own
 
 
 class NoAccelerator(RuntimeError):
@@ -31,7 +39,16 @@ class NoAccelerator(RuntimeError):
 
 @dataclass
 class Run:
-    """What a metric's reader reads: one run of one cell."""
+    """What a metric's reader reads: one run of one cell.
+
+    Each of ``units`` is one CP solution the window delivered, a dict with
+    ``done``, its seconds from the window's start, and ``sweeps``, the
+    sweeps that made it; optionally ``state``, the ``CPState`` it came
+    from.  A reader reads these and returns ``None`` where what it needs
+    is missing, whichever driver delivered them.  ``trace`` is the traced
+    window on the host's clock; ``program`` splits it by the program's own
+    scopes and spans on the aligned clock, and is ``None`` where the trace
+    has no device or its clocks cannot be aligned."""
 
     cell: str
     config: dict
@@ -39,9 +56,17 @@ class Run:
     peak: dict
     setup_s: float
     window_s: float
-    units: list  # one record per unit of work, times from the window's start
+    units: list
     counters: dict = field(default_factory=dict)
     trace: object = None  # trace.Summary of a traced run
+    program: object = None  # program_trace.ProgramSummary of a traced run
+
+
+def sweeps_of(units) -> int | None:
+    """The sweeps of ``units`` summed: ``None`` where there are no units,
+    or a unit does not say how many sweeps made it."""
+    sweeps = [u.get("sweeps") for u in units]
+    return None if not sweeps or None in sweeps else sum(sweeps)
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -60,15 +85,40 @@ def load_part(kind: str, name: str, base: Path = BENCH) -> dict:
     return json.loads((Path(base) / kind / f"{name}.json").read_text())
 
 
+def _load(path: Path, what: str):
+    """The module at ``path``, loaded by path (so that a part added under
+    another ``base`` is found alike)."""
+    spec = importlib.util.spec_from_file_location(f"bench_part_{len(sys.modules)}", path)
+    if spec is None or not path.exists():
+        raise FileNotFoundError(f"no {what} at {path}")
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # dataclasses look their module up here
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str, base: Path = BENCH):
     """The ``read(run)`` function of ``metrics/<metric>.py``."""
-    path = Path(base) / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{len(sys.modules)}", path)
-    if spec is None or not path.exists():
-        raise FileNotFoundError(f"no reader for metric {metric!r} at {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(Path(base) / "metrics" / f"{metric}.py", f"reader for metric {metric!r}").read
+
+
+def load_driver(kind: str, base: Path = BENCH):
+    """The driver module ``drivers/<kind>.py``."""
+    return _load(Path(base) / "drivers" / f"{kind}.py", f"driver for kind {kind!r}")
+
+
+def spans_of(driver) -> tuple:
+    """The host spans a run of ``driver`` reads: the harness's and the driver's."""
+    return tuple(dict.fromkeys(SPANS + tuple(driver.SPANS)))
+
+
+def read_program(path: str, spans):
+    """The program's split of the trace at ``path``, or ``None`` where it
+    has no device or the clocks cannot be aligned."""
+    from bench import program_trace
+
+    program = program_trace.summarize(path, spans)
+    return program if program.devices and program.clock_offset_ns is not None else None
 
 
 def metrics_for(bench: dict, cell: str, traced: bool) -> list[dict]:
@@ -132,13 +182,13 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float, trace: bool,
     import jax
 
     from bench import roofline
-    from bench import trace as trace_mod
 
     config = config or load_part("configs", cell["config"], base)
     mix = mix or load_part("traffic", cell["traffic"], base)
     device = jax.devices()[0]
     peak = peak or roofline.peaks(device.device_kind)
-    driver = importlib.import_module(f"bench.drivers.{config['kind']}")
+    driver = load_driver(config["kind"], base)
+    spans = spans_of(driver)
     counter = CompileCounter()
 
     state = driver.setup(config, mix, seed, span)
@@ -146,18 +196,25 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float, trace: bool,
         log(line)
     setup_s = time.perf_counter() - t_start
     log_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
+    summary = program = None
     try:
         if trace:
-            jax.profiler.start_trace(log_dir)
+            # spans are TraceMe events; the Python tracer would slow the host loop
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(log_dir, profiler_options=options)
         counter.active = True
         try:
-            with span("window"):
+            with span(trace_mod.WINDOW):
                 win = driver.window(state, seconds, span)
         finally:
             counter.active = False
             if trace:
                 jax.profiler.stop_trace()
-        summary = trace_mod.summarize(trace_mod.find_xplane(log_dir), SPANS) if trace else None
+        if trace:
+            path = trace_mod.find_xplane(log_dir)
+            summary = trace_mod.summarize(path, spans)
+            program = read_program(path, spans)
     finally:
         if log_dir:
             shutil.rmtree(log_dir, ignore_errors=True)
@@ -169,10 +226,13 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float, trace: bool,
         log(f"trace: window_s={summary.window_s} busy_s={summary.busy_s} "
             f"devices={summary.devices} idle_share={summary.idle_share} "
             f"busy_s_in_spans={summary.span_busy}")
+    if program is not None:
+        log(f"program: clock={program.clock} busy_s_by_scope={program.busy_s_by_scope} "
+            f"idle_s_by_span={program.idle_s_by_span}")
 
     run = Run(cell=cell["name"], config=config, mix=mix, peak=peak, setup_s=setup_s,
               window_s=win["window_s"], units=win["units"], counters=win["counters"],
-              trace=summary)
+              trace=summary, program=program)
     metrics = {}
     for m in metrics_for(bench, cell["name"], trace):
         value = reader(m["name"], base)(run)
@@ -197,7 +257,9 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float, trace: bool,
     }
     if summary is not None:
         result["device"].update(busy_s=summary.busy_s, window_s=summary.window_s)
-        result["breakdown"] = {"device_ops": summary.top_ops, "idle_gaps": summary.idle_gaps}
+        # on the aligned clock where there is one, so a gap falls in the span it lies in
+        gaps = (program or summary).idle_gaps
+        result["breakdown"] = {"device_ops": summary.top_ops, "idle_gaps": gaps}
     result["checks"] = checks
     return result
 

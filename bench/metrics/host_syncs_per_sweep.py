@@ -3,12 +3,12 @@
 Python) over the sweeps, summed over the window's solves.  None where the
 program does not count them."""
 
+from bench.harness import sweeps_of
+
 
 def read(run):
-    if run.config["kind"] != "solve" or not run.units:
-        return None
+    sweeps = sweeps_of(run.units)
     syncs = [getattr(u.get("state"), "host_syncs", None) for u in run.units]
-    sweeps = sum(u["sweeps"] for u in run.units)
-    if None in syncs or not sweeps:
+    if not sweeps or None in syncs:
         return None
     return sum(syncs) / sweeps

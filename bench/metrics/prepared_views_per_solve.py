@@ -5,9 +5,7 @@ count them."""
 
 
 def read(run):
-    if run.config["kind"] != "solve" or not run.units:
-        return None
     views = [getattr(u.get("state"), "prepared_views", None) for u in run.units]
-    if None in views:
+    if not views or None in views:
         return None
     return sum(views) / len(views)

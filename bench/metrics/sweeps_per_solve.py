@@ -1,8 +1,9 @@
 """sweeps_per_solve: sweeps to converge (CPState.it), averaged over the
 window's solves."""
 
+from bench.harness import sweeps_of
+
 
 def read(run):
-    if run.config["kind"] != "solve" or not run.units:
-        return None
-    return sum(u["sweeps"] for u in run.units) / len(run.units)
+    sweeps = sweeps_of(run.units)
+    return None if sweeps is None else sweeps / len(run.units)

@@ -1,10 +1,10 @@
 """The program's own scopes and spans in a profiler trace.
 
 ``cp_als`` names its device work with ``jax.named_scope`` (``init``,
-``mttkrp.node<id>``, ``update.mode<n>``, ``fit``) and its host loop with
-``jax.profiler.TraceAnnotation`` spans (``cp_als.init``, ``cp_als.dispatch``,
-``cp_als.wait``, ``cp_als.check``).  This module reduces a trace of it, on
-top of :mod:`bench.trace`:
+``prepare``, ``mttkrp.node<id>``, ``update.mode<n>``, ``fit``) and its host
+loop with ``jax.profiler.TraceAnnotation`` spans (``cp_als.init``,
+``cp_als.dispatch``, ``cp_als.wait``, ``cp_als.check``).  This module
+reduces a trace of it, on top of :mod:`bench.trace`:
 
 - **Scopes.**  Each device op's ``tf_op`` stat holds its path of scopes.
   ``jax.profiler.ProfileData`` does not expose it, so :func:`read_devices`
@@ -16,7 +16,10 @@ top of :mod:`bench.trace`:
   its host launch (``PJRT_LoadedExecutable_Execute``, in order) and its
   host ``CompleteCallbacks`` (by ``run_id``).  The offset added to device
   times is the least one that puts no module start before its launch; it
-  must also put no callback before its module's end, or there is none.
+  must also put no callback before its module's end.  Where the device's
+  clock was set again within the trace, no one offset does both: each run
+  of a device's modules that one offset fits gets its own, within stated
+  limits (:func:`clock_segments`).
 - **Idle gaps.**  On the aligned clock, the device's idle time inside each
   span, and the longest gaps named by the innermost span open over them.
 
@@ -33,6 +36,7 @@ Importing this module loads no accelerator library.
 from __future__ import annotations
 
 import argparse
+import bisect
 import heapq
 import json
 import re
@@ -44,7 +48,7 @@ from bench import trace
 
 PROGRAM_SPANS = ("cp_als.init", "cp_als.dispatch", "cp_als.wait", "cp_als.check")
 SYNC_SPANS = ("cp_als.dispatch", "cp_als.wait", "cp_als.check")
-SCOPE = re.compile(r"mttkrp\.node\d+|update\.mode\d+|fit|init")
+SCOPE = re.compile(r"mttkrp\.node\d+|update\.mode\d+|fit|init|prepare")
 NO_SCOPE = "none"
 MODULES_LINE = "XLA Modules"
 LAUNCH = "PJRT_LoadedExecutable_Execute"
@@ -254,6 +258,13 @@ def read_host(path: str, span_names) -> Host:
     return Host(spans=spans, window=window, launches=sorted(launches), callbacks=callbacks)
 
 
+# The device's clock may be set again within a trace: one step of about
+# 0.2 ms, one to four seconds in, in most full-size traces on one TPU v5e.
+# Steps beyond these limits leave the trace unaligned (clock_segments).
+MAX_CLOCK_STEPS = 2  # per device
+MAX_CLOCK_STEP_NS = 500_000
+
+
 def clock_offset(devices: dict, launches, callbacks) -> tuple[float | None, str]:
     """Nanoseconds to add to device times to put them on the host clock,
     and how it was found.
@@ -276,6 +287,69 @@ def clock_offset(devices: dict, launches, callbacks) -> tuple[float | None, str]
     if lo > hi:
         return None, "bounds cross: " + how
     return lo, how
+
+
+def clock_segments(devices: dict, launches, callbacks) -> tuple[dict, str]:
+    """Per device, the offsets that put its times on the host clock,
+    ``{device: [(from_ns, offset_ns), ...]}``, each offset holding from a
+    time on that device's clock on (the first from the trace's start); and
+    how they were found.  ``{}`` where the trace cannot be aligned.
+
+    Where :func:`clock_offset` finds one offset, every device takes it.
+    Otherwise each device's modules are walked in order of ``run_id``,
+    which has to be the order of their starts.  A run of them shares the
+    least offset that puts none of their starts before its launch, while
+    that offset puts no callback of theirs before its module's end; the
+    module that no such offset fits begins the next run: a step in that
+    device's clock.  The steps are taken only where a device has at most
+    ``MAX_CLOCK_STEPS`` of them, each of at most ``MAX_CLOCK_STEP_NS``, and
+    no module's own bounds cross.  An op between a step and the module
+    that shows it keeps the earlier offset: it is off by at most the step."""
+    offset, how = clock_offset(devices, launches, callbacks)
+    if offset is not None:
+        return {name: [(float("-inf"), offset)] for name in devices}, how
+    if not how.startswith("bounds cross"):
+        return {}, how
+    runs = sorted({r for dev in devices.values() for r, _, _ in dev.modules})
+    n = min(len(runs), len(launches))
+    launch_of = dict(zip(runs[len(runs) - n:], launches[len(launches) - n:]))
+    out, told = {}, []
+    for name, dev in devices.items():
+        modules = sorted(m for m in dev.modules if m[0] in launch_of)
+        if not modules:
+            return {}, f"{how}; {name}: no module paired with a launch"
+        if any(b[1] <= a[1] for a, b in zip(modules, modules[1:])):
+            return {}, f"{how}; {name}: module starts out of run order"
+        segments, since, lo, hi = [], float("-inf"), float("-inf"), float("inf")
+        for r, s, e in modules:
+            m_lo = launch_of[r] - s
+            m_hi = callbacks[r] - e if r in callbacks else float("inf")
+            if m_lo > m_hi:
+                return {}, f"{how}; {name}: run {r} alone needs [{m_lo:.0f}, {m_hi:.0f}] ns"
+            if max(lo, m_lo) > min(hi, m_hi):
+                segments.append((since, lo))
+                since, lo, hi = s, m_lo, m_hi
+            else:
+                lo, hi = max(lo, m_lo), min(hi, m_hi)
+        segments.append((since, lo))
+        steps = [b - a for (_, a), (_, b) in zip(segments, segments[1:])]
+        said = ", ".join(f"{o:.0f} from {f:.0f}" for f, o in segments)
+        if len(steps) > MAX_CLOCK_STEPS or any(abs(d) > MAX_CLOCK_STEP_NS for d in steps):
+            return {}, (f"{how}; {name}: offsets {said}: more than {MAX_CLOCK_STEPS} steps "
+                        f"or one over {MAX_CLOCK_STEP_NS} ns")
+        out[name] = segments
+        told.append(f"{name} offsets {said} (steps {', '.join(f'{d:.0f}' for d in steps)} ns)")
+    return out, f"clock steps: {'; '.join(told)} ({how.removeprefix('bounds cross: ')})"
+
+
+def _shift(ops, segments):
+    """``ops`` ``(start, end, key)`` moved by the offset that holds where each starts."""
+    starts = [f for f, _ in segments]
+    out = []
+    for s, e, k in ops:
+        offset = segments[bisect.bisect_right(starts, s) - 1][1]
+        out.append((s + offset, e + offset, k))
+    return out
 
 
 def scope_of(tf_op: str) -> str:
@@ -316,8 +390,8 @@ class ProgramSummary:
 
     window_s: float
     devices: int
-    clock_offset_ns: float | None
-    clock: str  # how the offset was found, or why there is none
+    clock_offset_ns: float | None  # the first device's first offset (clock_segments)
+    clock: str  # how the offsets were found, or why there are none
     busy_s_by_scope: dict = field(default_factory=dict)  # scope -> busy seconds
     idle_s_by_span: dict = field(default_factory=dict)  # span -> device idle seconds
     idle_gaps: list = field(default_factory=list)  # [[span, seconds], ...], longest first
@@ -330,10 +404,11 @@ def reduce(devices: dict, host: Host, top: int = 10) -> ProgramSummary:
     lo, hi = host.window
     if hi <= lo:
         raise ValueError(f"empty window {host.window}")
-    offset, how = clock_offset(devices, host.launches, host.callbacks)
+    segments, how = clock_segments(devices, host.launches, host.callbacks)
+    first = next(iter(segments.values()), None)
     out = ProgramSummary(window_s=(hi - lo) * 1e-9, devices=len(devices),
-                         clock_offset_ns=offset, clock=how)
-    if offset is None:
+                         clock_offset_ns=first[0][1] if first else None, clock=how)
+    if not segments:
         return out
     by_name = {
         name: trace.union(trace.clip([(s, e) for n, s, e in host.spans if n == name], lo, hi))
@@ -342,21 +417,22 @@ def reduce(devices: dict, host: Host, top: int = 10) -> ProgramSummary:
     scope_busy: dict[str, float] = defaultdict(float)
     span_idle: dict[str, float] = defaultdict(float)
     all_gaps = []
-    for dev in devices.values():
-        ops = [(s + offset, e + offset, scope_of(op)) for s, e, op in dev.ops]
+    for name, dev in devices.items():
+        scope = {op: scope_of(op) for op in {op for _, _, op in dev.ops}}
+        ops = _shift([(s, e, scope[op]) for s, e, op in dev.ops], segments[name])
         for key, t in split_busy(ops, lo, hi).items():
             scope_busy[key] += t
         busy = trace.union(trace.clip([(s, e) for s, e, _ in ops], lo, hi))
         idle = trace.gaps(busy, lo, hi)
         all_gaps += idle
-        for name, intervals in by_name.items():
-            span_idle[name] += trace.overlap(idle, intervals)
+        for span, intervals in by_name.items():
+            span_idle[span] += trace.overlap(idle, intervals)
     n_dev = max(1, len(devices))
-    named = sorted(((trace.span_at(host.spans, (s + e) / 2), (e - s) * 1e-9) for s, e in all_gaps),
-                   key=lambda g: -g[1])
+    longest = sorted(all_gaps, key=lambda g: g[0] - g[1])[:top]
+    named = [(trace.span_at(host.spans, (s + e) / 2), (e - s) * 1e-9) for s, e in longest]
     out.busy_s_by_scope = {k: v * 1e-9 / n_dev for k, v in sorted(scope_busy.items())}
     out.idle_s_by_span = {k: v * 1e-9 / n_dev for k, v in sorted(span_idle.items())}
-    out.idle_gaps = [[n, s] for n, s in named[:top]]
+    out.idle_gaps = [[n, s] for n, s in named]
     return out
 
 

@@ -68,8 +68,9 @@ def test_throwaway_cell_from_files_alone(tmp_path):
     """A cell added as files under the benchmark's directories and an
     entry in BENCHMARK.json is found by name; no harness code changes."""
     base = tmp_path / "bench"
-    for d in ("configs", "traffic", "metrics"):
+    for d in ("configs", "traffic", "metrics", "drivers"):
         (base / d).mkdir(parents=True)
+    shutil.copy(harness.BENCH / "drivers" / "solve.py", base / "drivers")
     _, cfg, _ = tiny("fmri4.solve")
     (base / "configs" / "throwaway.json").write_text(json.dumps(cfg))
     (base / "traffic" / "once_more.json").write_text(json.dumps({"loop": "repeat"}))

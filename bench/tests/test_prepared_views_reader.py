@@ -29,7 +29,8 @@ def test_prepared_views_reader_is_none_without_the_counter():
     assert read(_run([SimpleNamespace()])) is None  # a program that does not count
     assert read(_run([SimpleNamespace(prepared_views=1), SimpleNamespace()])) is None
     assert read(_run([])) is None
-    assert read(_run([SimpleNamespace(prepared_views=1)], {"kind": "other"})) is None
+    # read from what the window delivered, whichever driver delivered it
+    assert read(_run([SimpleNamespace(prepared_views=1)], {"kind": "other"})) == 1.0
 
 
 def test_prepared_views_reader_reads_what_cp_als_counts():

@@ -258,3 +258,113 @@ def test_importing_loads_no_accelerator_library():
     )
     out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, check=True)
     assert out.stdout.strip() == "False"
+
+
+def test_prepare_is_a_scope():
+    assert pt.scope_of("jit(prepare_operands)/prepare/reshape:") == "prepare"
+    assert pt.scope_of("jit(prepare_operands)/prepare/multiply_reduce") == "prepare"
+    dev = pt.Device(ops=[(0, 30, "jit(prepare_operands)/prepare/copy:"),
+                         (30, 50, "jit(c)/mttkrp.node1/dot_general:")],
+                    modules=[(1, 0, 30), (2, 30, 50)])
+    host = pt.Host(spans=[("solve", 0, 100)], window=(0, 100), launches=[0, 30],
+                   callbacks={1: 40, 2: 60})
+    s = pt.reduce({"/device:TPU:0": dev}, host)
+    assert s.busy_s_by_scope == {"prepare": pytest.approx(30e-9),
+                                 "mttkrp.node1": pytest.approx(20e-9)}
+
+
+def test_readers_equal_the_command_line_on_the_cp_als_recording(cp_als_trace, tmp_path, capsys):
+    """The three readers of the program's split, on the recording as a
+    traced run of the ``solve`` driver hands it to them, print what the
+    command line prints for it."""
+    recorded = json.loads(CP_ALS_RUN.read_text())
+    cfg = {"shape": recorded["shape"], "rank": recorded["rank"], "dtype": "float32"}
+    units = [{"sweeps": u["sweeps"]} for u in recorded["units"]]
+    path = tmp_path / "cfg.json"
+    path.write_text(json.dumps(cfg))
+    assert pt.main([cp_als_trace, "--sweeps", str(sum(u["sweeps"] for u in units)),
+                    "--config", str(path)]) == 0
+    line = json.loads(capsys.readouterr().out)
+    spans = harness.spans_of(harness.load_driver("solve"))
+    run = SimpleNamespace(config=cfg, peak=roofline.peaks(recorded["device_kind"]), units=units,
+                          program=harness.read_program(cp_als_trace, spans))
+    for metric in ("mttkrp_roofline", "update_ms_per_sweep", "sync_idle_ms_per_sweep"):
+        assert harness.reader(metric)(run) == line[metric] > 0, metric
+    # the gaps fall in the host loop's spans, as a traced run's breakdown names them
+    gaps = [n for n, _ in run.program.idle_gaps]
+    assert all(n.startswith("cp_als.") for n in gaps[:5]), gaps
+
+
+def _step_device():
+    """Two modules that no one offset orders: the device's clock runs 10
+    behind the host's until it is set again, then 30 behind."""
+    return pt.Device(ops=[(100, 150, "jit(c)/mttkrp.node1/dot_general:"),
+                          (300, 320, "jit(c)/fit/mul:")],
+                     modules=[(1, 100, 150), (2, 300, 320)])
+
+
+def test_clock_segments_follow_a_step_in_the_device_clock():
+    """Each run of modules gets the least offset of its own, and the ops
+    after the step move by the second."""
+    dev = _step_device()
+    assert pt.clock_offset({"d": dev}, [110, 330], {1: 170, 2: 400})[0] is None
+    segments, how = pt.clock_segments({"d": dev}, [110, 330], {1: 170, 2: 400})
+    assert segments == {"d": [(float("-inf"), 10), (300, 30)]}
+    assert how.startswith("clock steps: d offsets 10 from -inf, 30 from 300 (steps 20 ns)")
+    host = pt.Host(spans=[("cp_als.wait", 0, 500)], window=(0, 500), launches=[110, 330],
+                   callbacks={1: 170, 2: 400})
+    s = pt.reduce({"/device:TPU:0": dev}, host)
+    assert s.clock_offset_ns == 10
+    assert s.busy_s_by_scope == {"mttkrp.node1": pytest.approx(50e-9), "fit": pytest.approx(20e-9)}
+    # idle: [0, 110], [160, 330], [350, 500]
+    assert s.idle_s_by_span == {"cp_als.wait": pytest.approx((110 + 170 + 150) * 1e-9)}
+    # where one offset orders every module, every device takes it
+    assert pt.clock_segments({"d": dev}, [110, 330], {1: 200, 2: 400})[0] == {
+        "d": [(float("-inf"), 30)]}
+
+
+def test_each_device_keeps_its_own_clock():
+    """Two devices whose clocks differ run the same programs: each is
+    aligned by its own modules, never by the other's times."""
+    a = pt.Device(ops=[(100, 150, "jit(c)/fit/mul:"), (300, 320, "jit(c)/fit/mul:")],
+                  modules=[(1, 100, 150), (2, 300, 320)])
+    b = pt.Device(ops=[(70, 120, "jit(c)/fit/mul:"), (270, 290, "jit(c)/fit/mul:")],
+                  modules=[(1, 70, 120), (2, 270, 290)])
+    launches, callbacks = [110, 310], {1: 170, 2: 370}
+    assert pt.clock_offset({"a": a, "b": b}, launches, callbacks)[0] is None
+    segments, _ = pt.clock_segments({"a": a, "b": b}, launches, callbacks)
+    assert segments == {"a": [(float("-inf"), 10)], "b": [(float("-inf"), 40)]}
+    host = pt.Host(spans=[("cp_als.wait", 0, 500)], window=(0, 500), launches=launches,
+                   callbacks=callbacks)
+    s = pt.reduce({"a": a, "b": b}, host)
+    # both devices busy in [110, 160] and [310, 330] on the host clock
+    assert s.busy_s_by_scope == {"fit": pytest.approx(70e-9)}
+    assert s.idle_s_by_span == {"cp_als.wait": pytest.approx(430e-9)}
+
+
+@pytest.mark.parametrize("case", ["alternating", "large_step", "out_of_order", "module_crosses"])
+def test_clock_segments_refuse_what_no_step_explains(case):
+    """Bounds that steps within the stated limits cannot reconcile leave
+    the trace unaligned, as a single offset that crosses did before."""
+    if case == "alternating":  # the clock back and forth, module by module
+        modules = [(r, 100 * r, 100 * r + 10) for r in range(1, 6)]
+        launches = [100 * r + (10 if r % 2 else 30) for r in range(1, 6)]
+        callbacks = {r: 100 * r + 10 + (15 if r % 2 else 35) for r in range(1, 6)}
+    elif case == "large_step":
+        step = pt.MAX_CLOCK_STEP_NS + 1
+        modules = [(1, 100, 150), (2, 10**7, 10**7 + 20)]
+        launches, callbacks = [110, 10**7 + 10 + step], {1: 170, 2: 10**7 + 40 + step}
+    elif case == "out_of_order":  # run ids that do not follow the device's starts
+        modules = [(1, 300, 320), (2, 100, 150)]
+        launches, callbacks = [310, 130], {1: 320 + 15, 2: 150 + 5}
+    else:
+        modules = [(1, 100, 150), (2, 300, 320)]
+        launches, callbacks = [110, 330], {1: 170, 2: 330}
+    dev = pt.Device(ops=[(s, e, "jit(c)/fit/mul:") for _, s, e in modules], modules=modules)
+    assert pt.clock_offset({"d": dev}, launches, callbacks)[0] is None
+    segments, how = pt.clock_segments({"d": dev}, launches, callbacks)
+    assert segments == {} and how.startswith("bounds cross"), how
+    host = pt.Host(spans=[("cp_als.wait", 0, 2 * 10**7)], window=(0, 2 * 10**7),
+                   launches=launches, callbacks=callbacks)
+    s = pt.reduce({"d": dev}, host)
+    assert s.clock_offset_ns is None and s.busy_s_by_scope == {} and s.idle_gaps == []

@@ -119,17 +119,15 @@ def reduce(device_ops, host_spans, window, top: int = 10) -> Summary:
         for name, intervals in by_name.items():
             span_busy[name] += overlap(busy, intervals)
     n_dev = max(1, len(device_ops))
-    named = sorted(
-        ((span_at(spans, (s + e) / 2), (e - s) * 1e-9) for s, e in all_gaps),
-        key=lambda g: -g[1],
-    )
+    longest = sorted(all_gaps, key=lambda g: g[0] - g[1])[:top]
+    named = [(span_at(spans, (s + e) / 2), (e - s) * 1e-9) for s, e in longest]
     ops = sorted(per_op.items(), key=lambda kv: -kv[1])
     return Summary(
         window_s=(hi - lo) * 1e-9,
         busy_s=busy_total * 1e-9 / n_dev,
         devices=len(device_ops),
         top_ops=[[n, s] for n, s in ops[:top]],
-        idle_gaps=[[n, s] for n, s in named[:top]],
+        idle_gaps=[[n, s] for n, s in named],
         span_busy={n: b * 1e-9 / n_dev for n, b in sorted(span_busy.items())},
     )
 
