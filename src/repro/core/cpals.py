@@ -3,7 +3,7 @@
 Per mode-n update (alternating least squares):
     M   = MTTKRP(X, {U_k}, n)                      (the bottleneck; Algs. 2-4)
     H   = *_{k != n} (U_k^T U_k)                   (Hadamard of Gram matrices)
-    U_n = M @ pinv(H);  column-normalize -> lambda
+    U_n = M @ inv(H) (by LU);  column-normalize -> lambda
 
 Fit is tracked with the standard factored identity (no residual tensor):
     ||X - Y||^2 = ||X||^2 - 2 <X, Y> + ||Y||^2

@@ -30,8 +30,16 @@ signatures degrade cleanly to the analytic model.
 The queue is the bounded FIFO+priority :class:`repro.serve.queue.RequestQueue`
 (submission raises :class:`repro.serve.queue.QueueFull` at capacity --
 client-visible backpressure), and ``stats()`` exposes the serving counters
-(queue depth, batch occupancy, compiles, warm-plan hits, problems/sec) the
-throughput benchmark reports.
+(queue depth, batch occupancy, compiles, warm-plan hits, host reads,
+problems/sec) the throughput benchmark reports.
+
+Observability costs nothing unless a profiler trace is being taken: each
+:meth:`CPService.step` opens the ``jax.profiler.TraceAnnotation`` spans
+``serve.take`` (bucket choice, dequeue, the signature's plan lookup),
+``serve.pack`` (the starts and the stacked batch), ``serve.dispatch`` (the
+``cp_als`` call, with its own ``cp_als.*`` spans inside) and
+``serve.unpack`` (each slot's answer sliced out and its fit read), and
+``stats()["host_reads"]`` counts the device values it reads into Python.
 """
 
 from __future__ import annotations
@@ -44,8 +52,9 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+import repro.plan
 from repro.core.tensor_ops import random_factors
-from repro.plan import Problem, cp_als, make_executor, plan_sweep
+from repro.plan import Problem, make_executor, plan_sweep
 from repro.plan.autotune import lookup_measurements, problem_key
 
 from .queue import QueueFull, RequestQueue
@@ -148,6 +157,10 @@ class CPService:
     pairwise-perturbation sweeps the service default (overridable per
     request); PP requests bucket under their own signature, so exact and PP
     traffic never share a compiled dispatch.
+
+    Each :meth:`step` opens the profiler spans ``serve.take``,
+    ``serve.pack``, ``serve.dispatch`` and ``serve.unpack`` (see the module
+    docstring), and :meth:`stats` counts ``host_reads``.
     """
 
     def __init__(
@@ -192,6 +205,7 @@ class CPService:
             "compiles": 0,
             "warm_plan_hits": 0,
             "padded_slots": 0,
+            "host_reads": 0,
         }
         self._execute_s = 0.0
 
@@ -317,6 +331,13 @@ class CPService:
         self._states[sig] = state
         return state
 
+    def plan_of(self, signature: str):
+        """The :class:`repro.plan.SweepPlan` the service dispatches for
+        ``signature`` (see :meth:`signature_of`), or ``None`` before the
+        first batch of that signature has run."""
+        state = self._states.get(signature)
+        return None if state is None else state.plan
+
     def _init_for(self, payload: _CPRequest) -> list[Array]:
         """One request's initial factors (pinned or drawn from its seed)."""
         if payload.init_factors is not None:
@@ -337,64 +358,71 @@ class CPService:
         resolves exactly the real requests' futures -- returned in slot
         order.  Returns ``[]`` when nothing is pending.
         """
-        sig = self._queue.next_key()
-        if sig is None:
-            return []
-        chunk = self._queue.take(self.batch_size, sig)
-        payloads = [r.payload for r in chunk]
-        state = self._state_for(sig, payloads[0])
+        with jax.profiler.TraceAnnotation("serve.take"):
+            sig = self._queue.next_key()
+            if sig is None:
+                return []
+            chunk = self._queue.take(self.batch_size, sig)
+            payloads = [r.payload for r in chunk]
+            state = self._state_for(sig, payloads[0])
         B = self.batch_size
         n_iters, tol = payloads[0].n_iters, payloads[0].tol
-        # pad by cycling the real requests: slot i >= len(chunk) duplicates a
-        # real problem, so the shared convergence stop is unchanged and no
-        # dummy can perturb anything (problems are independent per slice)
-        slots = [payloads[i % len(payloads)] for i in range(B)]
-        inits = [self._init_for(p) for p in slots]
-        if B > 1:
-            x = jnp.stack([p.tensor for p in slots])
-            init = [
-                jnp.stack([inits[b][m] for b in range(B)])
-                for m in range(len(state.problem.shape))
-            ]
-        else:
-            x = slots[0].tensor
-            init = inits[0]
+        with jax.profiler.TraceAnnotation("serve.pack"):
+            # pad by cycling the real requests: slot i >= len(chunk) duplicates
+            # a real problem, so the shared convergence stop is unchanged and
+            # no dummy can perturb anything (problems are independent per slice)
+            slots = [payloads[i % len(payloads)] for i in range(B)]
+            inits = [self._init_for(p) for p in slots]
+            if B > 1:
+                x = jnp.stack([p.tensor for p in slots])
+                init = [
+                    jnp.stack([inits[b][m] for b in range(B)])
+                    for m in range(len(state.problem.shape))
+                ]
+            else:
+                x = slots[0].tensor
+                init = inits[0]
         if 0 not in state.dispatch:
             self._counters["compiles"] += 1  # the dispatch-cache miss compiles
         t0 = time.monotonic()
-        st = cp_als(
-            x,
-            state.plan,
-            executor=state.executor,
-            n_iters=n_iters,
-            tol=tol,
-            init_factors=init,
-            sweeps_per_sync=self.sweeps_per_sync or n_iters,
-            dispatch_cache=state.dispatch,
-            dispatch_key=0,
-        )
+        with jax.profiler.TraceAnnotation("serve.dispatch"):
+            # looked up on the package at call time: repro.plan.cp_als is the
+            # one front door, and whatever wraps it there wraps the service too
+            st = repro.plan.cp_als(
+                x,
+                state.plan,
+                executor=state.executor,
+                n_iters=n_iters,
+                tol=tol,
+                init_factors=init,
+                sweeps_per_sync=self.sweeps_per_sync or n_iters,
+                dispatch_cache=state.dispatch,
+                dispatch_key=0,
+            )
         now = time.monotonic()
         self._execute_s += now - t0
         self._counters["batches"] += 1
         self._counters["padded_slots"] += B - len(chunk)
         self._counters["completed"] += len(chunk)
         futures = []
-        for i, req in enumerate(chunk):
-            if B > 1:
-                factors = [u[i] for u in st.factors]
-                weights, fit = st.weights[i], float(st.fit[i])
-            else:
-                factors, weights, fit = list(st.factors), st.weights, float(st.fit)
-            req.payload.future._result = CPResult(
-                rid=req.rid,
-                factors=factors,
-                weights=weights,
-                fit=fit,
-                sweeps=int(st.it),
-                signature=sig,
-                latency_s=now - req.submitted_at,
-            )
-            futures.append(req.payload.future)
+        with jax.profiler.TraceAnnotation("serve.unpack"):
+            for i, req in enumerate(chunk):
+                if B > 1:
+                    factors = [u[i] for u in st.factors]
+                    weights, fit = st.weights[i], float(st.fit[i])
+                else:
+                    factors, weights, fit = list(st.factors), st.weights, float(st.fit)
+                self._counters["host_reads"] += 1  # the fit; st.it is a host int
+                req.payload.future._result = CPResult(
+                    rid=req.rid,
+                    factors=factors,
+                    weights=weights,
+                    fit=fit,
+                    sweeps=int(st.it),
+                    signature=sig,
+                    latency_s=now - req.submitted_at,
+                )
+                futures.append(req.payload.future)
         return futures
 
     def flush(self) -> list[CPFuture]:
@@ -417,8 +445,13 @@ class CPService:
         executed batches), ``signatures`` (distinct buckets seen),
         ``compiles`` (jitted dispatches built -- one per signature),
         ``warm_plan_hits`` (signatures planned from tuning-cache
-        measurements), ``execute_s`` and ``problems_per_s`` (completed real
-        problems over in-dispatch seconds).
+        measurements), ``host_reads`` (device values :meth:`step` read into
+        Python: one fit per real request, since the sweep count comes back
+        as a host integer), ``execute_s`` and ``problems_per_s`` (completed
+        real problems over in-dispatch seconds: the seconds inside the
+        ``cp_als`` calls alone, so queueing, packing and unpacking are not in
+        it; the served figure is the benchmark's ``solve_s``, a window's
+        seconds over the requests completed in it, host path included).
         """
         c = dict(self._counters)
         served_slots = c["completed"] + c["padded_slots"]

@@ -132,6 +132,63 @@ def test_pp_requests_bucket_separately():
     assert svc.stats()["compiles"] == 2
 
 
+def test_service_answers_equal_the_plain_reference():
+    """Each answer of a served stream (6 tensors, batches of 4: one full,
+    one padded) equals the benchmark's plain reference (einsum MTTKRP, LU
+    solve, no batching, HIGHEST) run on that tensor alone from the same
+    pinned start for the same 20 sweeps.  Tolerance 1e-5 in model
+    distance and fit: float32 rounding of two different contraction
+    orders, carried through 20 sweeps, reads at most 5.3e-7 and 1.8e-7
+    here (with the update solved by pinv instead: 1.3e-6)."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench import reference
+
+    svc = CPService(batch_size=4, n_iters=20)
+    shape, rank = (7, 6, 5), 3
+    reqs = [_request(shape, seed=90 + i) for i in range(6)]
+    futs = [svc.submit(x, rank, init_factors=init) for x, init in reqs]
+    svc.flush()
+    for (x, init), fut in zip(reqs, futs):
+        res = fut.result()
+        ref, _ = reference.als(x, init, n_iters=20, tol=0.0)
+        assert res.sweeps == ref[3] == 20
+        diff = reference.model_diff(res.weights, res.factors, ref[1], ref[0])
+        assert diff < 1e-5, diff
+        assert abs(res.fit - ref[2]) < 1e-5
+
+
+def test_host_reads_count_each_fit_read():
+    """``stats()["host_reads"]``: one device value read a real request (its
+    fit), none for a padded slot; the sweep count is a host integer."""
+    svc = CPService(batch_size=4, n_iters=2)
+    for i in range(6):
+        x, init = _request((6, 5, 4), seed=100 + i)
+        svc.submit(x, RANK, init_factors=init)
+    assert len(svc.step()) == 4
+    assert svc.stats()["host_reads"] == 4
+    assert len(svc.step()) == 2
+    stats = svc.stats()
+    assert (stats["host_reads"], stats["batches"], stats["padded_slots"]) == (6, 2, 2)
+    svc1 = CPService(batch_size=1, n_iters=2)
+    svc1.submit(x, RANK)
+    svc1.flush()
+    assert svc1.stats()["host_reads"] == 1
+
+
+def test_plan_of_names_the_dispatched_plan():
+    svc = CPService(batch_size=2, n_iters=2)
+    x, init = _request((6, 5, 4), seed=110)
+    sig = svc.signature_of(x, RANK)
+    assert svc.plan_of(sig) is None
+    svc.submit(x, RANK, init_factors=init)
+    svc.flush()
+    plan = svc.plan_of(sig)
+    assert plan.problem.batch == 2 and plan.problem.shape == (6, 5, 4)
+
+
 # ---------------------------------------------------------------- scheduling
 def test_fifo_within_signature_and_priority_across():
     """step() serves the bucket owning the most urgent request; within a

@@ -125,3 +125,55 @@ def test_host_syncs_count_the_pp_read(waits):
 def test_cp_state_host_syncs_defaults_to_zero():
     st = CPState(factors=[], weights=jnp.ones(2), fit=jnp.float32(0.5))
     assert st.host_syncs == 0 and st.pp_exact_sweeps is None
+
+
+SERVE_SPANS = ("serve.take", "serve.pack", "serve.dispatch", "serve.unpack")
+
+
+def test_serve_spans_in_the_host_trace(tmp_path):
+    """Under jax.profiler each CPService.step opens take, pack, dispatch
+    and unpack one after another, with cp_als's own spans inside dispatch."""
+    from jax.profiler import ProfileData
+
+    from repro.serve import CPService
+
+    svc = CPService(batch_size=2, n_iters=3)
+    xs = [random_tensor(jax.random.PRNGKey(s), (6, 5, 4)) for s in range(2)]
+    for x in xs:
+        svc.submit(x, 2)
+    svc.flush()  # compiles the signature's dispatch outside the trace
+    for x in xs:
+        svc.submit(x, 2)
+    with jax.profiler.trace(str(tmp_path)):
+        done = svc.step()
+    assert len(done) == 2
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = sorted(
+        (e.start_ns, e.end_ns, e.name)
+        for p in ProfileData.from_file(path).planes if p.name == "/host:CPU"
+        for line in p.lines for e in line.events if e.name in SERVE_SPANS + SPANS
+    )
+    serve = [ev for ev in events if ev[2] in SERVE_SPANS]
+    assert [n for _, _, n in serve] == list(SERVE_SPANS)
+    assert all(a[1] <= b[0] for a, b in zip(serve, serve[1:]))  # one after another
+    (_, d0, d1), = [(n, s, e) for s, e, n in serve if n == "serve.dispatch"]
+    inner = [n for s, e, n in events if n in SPANS]
+    assert inner[0] == "cp_als.init" and "cp_als.wait" in inner
+    assert all(d0 <= s and e <= d1 for s, e, n in events if n in SPANS)
+
+
+def test_cp_als_takes_its_first_grams_at_the_sweep_precision(monkeypatch):
+    """The Grams cp_als computes before its first sweep, which that sweep's
+    updates solve with, are contracted at SWEEP_PRECISION like every
+    contraction inside the sweep, not at the backend's default."""
+    seen = []
+    real = sweeplib.grams
+
+    def recording(factors):
+        seen.append(jax.config.jax_default_matmul_precision)
+        return real(factors)
+
+    monkeypatch.setattr(sweeplib, "grams", recording)
+    x, rank = _planted()
+    cp_als(x, plan_sweep(Problem.from_tensor(x, rank)), n_iters=2, seed=1)
+    assert seen == [sweeplib.SWEEP_PRECISION]
