@@ -41,8 +41,8 @@ class CPState:
     # Exact (re-materializing) sweeps executed when the run used pairwise
     # perturbation (Problem.pp_tol > 0); None for classic exact-only runs.
     pp_exact_sweeps: int | None = None
-    # Times cp_als blocked the host on the device: each wait for a
-    # chunk and each read of a device value into Python (float/bool/int).
+    # Times cp_als blocked the host on the device: the one read of each
+    # chunk's fits, which is its wait, and the read of pp_exact_sweeps.
     host_syncs: int = 0
     # Tensor-sized matrix views of the tensor cp_als built for the solve
     # (once, before the first sweep); 0 where the sweeps read the tensor.

@@ -25,6 +25,7 @@ from typing import Any, Callable, MutableMapping, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.cpals import (
     CPState,
@@ -52,8 +53,9 @@ Array = jax.Array
 SWEEP_PRECISION = "highest"
 
 # THE host-synchronization point of the cp_als driver: exactly one call per
-# dispatched chunk of sweeps.  Module-level so tests can count syncs.
-_block_until_ready = jax.block_until_ready
+# dispatched chunk of sweeps, reading the chunk's per-sweep fits into host
+# memory.  Module-level so tests can count syncs.
+_read_fits = np.asarray
 
 
 @dataclass
@@ -541,7 +543,10 @@ def sweep_chunk(plan: SweepPlan, executor: Executor, donate: tuple[int, ...] = (
     """The jitted program one :func:`cp_als` dispatch runs:
     ``chunk(x, norm_x, it0, factors, weights, gs, carry, pp, views=None, *,
     length)`` runs ``length`` sweeps under ``lax.scan`` and returns
-    ``(factors, weights, gs, carry, pp, fits)``.
+    ``(factors, weights, gs, carry, pp, it, fits, fit)``: ``it`` is
+    ``it0 + length``, the next chunk's ``it0``, and ``fit`` the last
+    sweep's entry of the per-sweep ``fits``, so the host loop takes no
+    slice of ``fits`` and sends no counter to the device.
 
     Only the evolving buffers go out (returning ``x`` would make XLA emit a
     full-tensor copy every chunk); ``donate`` names the arguments donated
@@ -565,10 +570,10 @@ def sweep_chunk(plan: SweepPlan, executor: Executor, donate: tuple[int, ...] = (
             )
 
         init = (factors, weights, gs, carry, pp, it0)
-        (factors, weights, gs, carry, pp, _), fits = jax.lax.scan(
+        (factors, weights, gs, carry, pp, it), fits = jax.lax.scan(
             body, init, None, length=length
         )
-        return factors, weights, gs, carry, pp, fits
+        return factors, weights, gs, carry, pp, it, fits, fits[-1]
 
     return jax.jit(_chunk, static_argnames=("length",), donate_argnums=donate)
 
@@ -604,20 +609,24 @@ def cp_als(
     weight and carry buffers donated off-CPU) and the host blocks exactly
     once per chunk -- the per-sweep iterates are bitwise identical to
     ``sweeps_per_sync=1``, only the host round-trips change (one per chunk
-    instead of one per sweep).  Convergence is checked against the chunk's
-    per-sweep fits at each sync point, so a run may execute up to
-    ``sweeps_per_sync - 1`` sweeps past the first converged one; the
-    callback still fires once per executed sweep (with the chunk's mean
-    per-sweep seconds).
+    instead of one per sweep).  That one block is the chunk's sync: the read
+    of its per-sweep fits into host memory, started as the chunk is
+    enqueued.  Convergence is checked against that host copy, so a run may
+    execute up to ``sweeps_per_sync - 1`` sweeps past the first converged
+    one; the callback still fires once per executed sweep (with the chunk's
+    mean per-sweep seconds).  The chunk itself returns the last sweep's fit
+    and the advanced sweep counter, so between dispatches the host makes
+    no other device call.
 
     Batched problems (``plan.problem.batched``) expect ``x`` of shape
     ``(batch, *problem.shape)`` and run ALL problems through the same
     compiled dispatches: factors/weights/Grams gain a leading batch axis,
     the fit is per-problem (``CPState.fit`` has shape ``(batch,)``), the
     callback receives the batch-mean fit, and convergence requires every
-    problem's fit delta below ``tol`` (problems are independent, so the
-    shared stop is the price of one fused dispatch -- at most a few extra
-    sweeps for the fastest converger).
+    problem's fit delta below ``tol``, taken in the fits' dtype as the
+    device computes it (problems are independent, so the shared stop is
+    the price of one fused dispatch -- at most a few extra sweeps for the
+    fastest converger).
 
     Plans with ``plan.pp`` (built from a ``Problem(pp_tol > 0)``) run the
     pairwise-perturbation loop: the scan carries the PP cache next to the
@@ -650,10 +659,11 @@ def cp_als(
     and ``fit`` in their op names.  The host loop opens the
     ``jax.profiler.TraceAnnotation`` spans ``cp_als.init`` (entry to the
     first dispatch), ``cp_als.dispatch`` (argument preparation and enqueue),
-    ``cp_als.wait`` (the chunk's block) and ``cp_als.check`` (the fit reads,
-    the convergence test and the callback).  ``CPState.host_syncs`` counts
-    every time the host blocked on the device: each chunk's wait and each
-    device value read into Python.
+    ``cp_als.wait`` (the chunk's block: the read of its fits) and
+    ``cp_als.check`` (the convergence test and the callback, on the host
+    copy).  ``CPState.host_syncs`` counts every time the host blocked on
+    the device: one read a chunk, plus one read of the exact-sweep count on
+    a pairwise-perturbation plan.
     """
     problem = plan.problem
     if executor is None:
@@ -722,48 +732,50 @@ def cp_als(
             chunk = sweep_chunk(plan, executor, donate)
             if dispatch_cache is not None:
                 dispatch_cache[dispatch_key] = chunk
+        # the sweep counter's one transfer a solve: each chunk returns its
+        # advanced counter, which the next chunk takes as it is
+        it_dev = jnp.asarray(0)
 
     syncs = 0
     fit_prev = -math.inf
-    fit = jnp.asarray(0.0, x.dtype)
+    fit = None
     it = 0
     done = False
     while it < n_iters and not done:
         length = min(k, n_iters - it)
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("cp_als.dispatch"):
-            factors, weights, gs, carry, pp, fits = chunk(
-                x_arg, norm_x, jnp.asarray(it), factors, weights, gs, carry, pp,
+            factors, weights, gs, carry, pp, it_dev, fits, fit = chunk(
+                x_arg, norm_x, it_dev, factors, weights, gs, carry, pp,
                 views, length=length,
             )
+            fits.copy_to_host_async()
         with jax.profiler.TraceAnnotation("cp_als.wait"):
-            fits = _block_until_ready(fits)  # the chunk's one wait
+            fits = _read_fits(fits)  # the chunk's one wait
         syncs += 1
         dt = time.perf_counter() - t0
         with jax.profiler.TraceAnnotation("cp_als.check"):
-            for j in range(length):
-                if problem.batched:
-                    # per-problem fits (B,); stop only when EVERY problem's
-                    # fit delta clears tol (one fused dispatch, shared stop).
-                    f = fits[j]
+            if problem.batched:
+                # per-problem fits (B,), in the device's dtype; stop only
+                # when EVERY problem's fit delta clears tol (one fused
+                # dispatch, shared stop).
+                tol_f = fits.dtype.type(tol)
+                for j, f in enumerate(fits):
                     if callback is not None:
-                        syncs += 1
-                        callback(it + j, float(jnp.mean(f)), dt / length)
-                    if track_fit:
-                        syncs += 1
-                        if bool(jnp.max(jnp.abs(f - fit_prev)) < tol):
-                            done = True
+                        callback(it + j, float(f.mean()), dt / length)
+                    if track_fit and np.max(np.abs(f - fit_prev)) < tol_f:
+                        done = True
                     fit_prev = f
-                else:
-                    f = float(fits[j])
-                    syncs += 1
+            else:
+                for j, f in enumerate(fits.tolist()):
                     if callback is not None:
                         callback(it + j, f, dt / length)
                     if track_fit and abs(f - fit_prev) < tol:
                         done = True
                     fit_prev = f
             it += length
-            fit = fits[length - 1]
+    if fit is None:
+        fit = jnp.asarray(0.0, x.dtype)
     pp_exact_sweeps = None
     if pp is not None:
         pp_exact_sweeps = int(pp.n_exact)

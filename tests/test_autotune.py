@@ -253,13 +253,13 @@ def test_sweeps_per_sync_one_host_sync_per_chunk(monkeypatch):
     x, rank = _planted()
     plan = plan_sweep(Problem.from_tensor(x, rank))
     counts = {"n": 0}
-    real = jax.block_until_ready
+    real = sweeplib._read_fits
 
-    def counting(tree):
+    def counting(fits):
         counts["n"] += 1
-        return real(tree)
+        return real(fits)
 
-    monkeypatch.setattr(sweeplib, "_block_until_ready", counting)
+    monkeypatch.setattr(sweeplib, "_read_fits", counting)
     cp_als(x, plan, n_iters=6, track_fit=False, seed=7)
     assert counts["n"] == 6  # k=1: one sync per sweep
     counts["n"] = 0

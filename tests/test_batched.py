@@ -219,13 +219,13 @@ def test_batched_one_dispatch_per_chunk(monkeypatch):
     x, init = _fleet(B, shape, rank, seed=13)
     plan = plan_sweep(Problem.from_tensor(x, rank, batch=B))
     counts = {"n": 0}
-    real = jax.block_until_ready
+    real = sweeplib._read_fits
 
-    def counting(tree):
+    def counting(fits):
         counts["n"] += 1
-        return real(tree)
+        return real(fits)
 
-    monkeypatch.setattr(sweeplib, "_block_until_ready", counting)
+    monkeypatch.setattr(sweeplib, "_read_fits", counting)
     cp_als(x, plan, n_iters=6, track_fit=False, init_factors=init,
            sweeps_per_sync=3)
     assert counts["n"] == 2  # two chunks of 3 sweeps, B=64 notwithstanding

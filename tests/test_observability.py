@@ -78,29 +78,30 @@ def test_cp_als_spans_in_the_host_trace(tmp_path):
 def waits(monkeypatch):
     """Counts cp_als's waits at its one wait point."""
     counts = {"n": 0}
-    real = jax.block_until_ready
+    real = sweeplib._read_fits
 
-    def counting(tree):
+    def counting(fits):
         counts["n"] += 1
-        return real(tree)
+        return real(fits)
 
-    monkeypatch.setattr(sweeplib, "_block_until_ready", counting)
+    monkeypatch.setattr(sweeplib, "_read_fits", counting)
     return counts
 
 
 @pytest.mark.parametrize("k, n_waits", [(1, 6), (3, 2), (4, 2)])
 def test_host_syncs_are_waits_plus_scalar_reads(waits, k, n_waits):
-    """Unbatched: one wait per chunk and one ``float`` of each sweep's fit."""
+    """Unbatched: one wait per chunk, the read of its fits, and nothing
+    else: the convergence test runs on that host copy."""
     x, rank = _planted()
     plan = plan_sweep(Problem.from_tensor(x, rank))
     st = cp_als(x, plan, n_iters=6, track_fit=False, seed=7, sweeps_per_sync=k)
     assert waits["n"] == n_waits
-    assert st.host_syncs == n_waits + 6
+    assert st.host_syncs == n_waits
 
 
 def test_host_syncs_batched_count_each_read_made(waits):
-    """Batched: a read for the callback's mean and one for the convergence
-    test, each only where it is made."""
+    """Batched: one read a chunk whether or not the callback's mean and
+    the convergence test are asked for; both run on the host copy."""
     B, shape, rank = 4, (6, 5, 4), 2
     x = random_tensor(jax.random.PRNGKey(0), (B,) + shape)
     init = random_factors(jax.random.PRNGKey(1), shape, rank, batch=B)
@@ -110,16 +111,17 @@ def test_host_syncs_batched_count_each_read_made(waits):
     waits["n"] = 0
     st = cp_als(x, plan, n_iters=6, tol=0.0, init_factors=init, sweeps_per_sync=3,
                 callback=lambda it, fit, dt: None)
-    assert (waits["n"], st.host_syncs) == (2, 2 + 2 * 6)
+    assert (waits["n"], st.host_syncs) == (2, 2)
 
 
 def test_host_syncs_count_the_pp_read(waits):
-    """A pairwise-perturbation run reads its exact-sweep count once more."""
+    """A pairwise-perturbation run reads its exact-sweep count once more,
+    after its chunks' reads."""
     x, rank = _planted()
     plan = plan_sweep(Problem.from_tensor(x, rank, pp_tol=0.05))
     st = cp_als(x, plan, n_iters=4, track_fit=False, seed=3)
     assert st.pp_exact_sweeps is not None
-    assert st.host_syncs == waits["n"] + 4 + 1
+    assert st.host_syncs == waits["n"] + 1
 
 
 def test_cp_state_host_syncs_defaults_to_zero():
