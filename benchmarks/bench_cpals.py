@@ -15,7 +15,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.core import CPConfig, cp_als, random_tensor
+from repro.core import random_tensor
+from repro.plan import Problem, cp_als, plan_sweep
 
 from .util import row
 
@@ -33,9 +34,9 @@ def _tensors(full: bool):
 
 def _per_iter_seconds(x, rank: int, method: str, iters: int = 3) -> float:
     times: list[float] = []
+    plan = plan_sweep(Problem.from_tensor(x, rank), strategy=method)
     cp_als(
-        x,
-        CPConfig(rank=rank, n_iters=iters, tol=0.0, method=method, track_fit=False),
+        x, plan, n_iters=iters, tol=0.0, track_fit=False,
         callback=lambda it, fit, dt: times.append(dt),
     )
     return min(times[1:]) if len(times) > 1 else times[0]  # skip compile iter

@@ -12,12 +12,22 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import random_factors, random_tensor, tensor_norm
-from repro.core.cpals import als_sweep
-from repro.core.dimtree import dimtree_sweep
+from repro.plan import LocalExecutor, Problem, SweepState, als_sweep, plan_sweep
 
 from .util import row, time_fn
 
 C = 16
+
+
+def _sweep(plan, norm_x, it):
+    """One jitted ``repro.plan.als_sweep`` of ``plan``: ``(x, factors,
+    weights) -> fit``."""
+
+    def sweep(x, factors, weights):
+        state = SweepState(x=x, factors=factors, weights=weights, norm_x=norm_x, it=it)
+        return als_sweep(plan.problem, plan, LocalExecutor(), state).fit
+
+    return jax.jit(sweep)
 
 
 def run(full: bool = False) -> list[str]:
@@ -32,8 +42,9 @@ def run(full: bool = False) -> list[str]:
         norm_x = tensor_norm(x)
         it = jnp.asarray(1)
 
-        std = jax.jit(lambda xx, fs, ww: als_sweep(xx, fs, ww, norm_x, it, "auto", True))
-        dt = jax.jit(lambda xx, fs, ww: dimtree_sweep(xx, fs, ww, norm_x, it))
+        problem = Problem.from_tensor(x, C)
+        std = _sweep(plan_sweep(problem, schedule="flat"), norm_x, it)
+        dt = _sweep(plan_sweep(problem, strategy="dimtree"), norm_x, it)
         t_std = time_fn(std, x, factors, w, reps=3)["median_s"]
         t_dt = time_fn(dt, x, factors, w, reps=3)["median_s"]
         out.append(

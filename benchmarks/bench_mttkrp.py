@@ -143,7 +143,7 @@ def overlap_section(reps: int) -> dict:
     # per-node section below)
     plans = {
         ex: plan_sweep(problem, schedule="flat", executor=ex)
-        for ex in ("sharded", "overlapping", "compressed")
+        for ex in ("sharded", "overlapping")
     }
     rows = []
     for n in range(len(OVERLAP_SHAPE)):
@@ -158,7 +158,6 @@ def overlap_section(reps: int) -> dict:
             "predicted_saving_vs_sharded": (pred_sh - pred_ov) / pred_sh,
             "predicted_s_sharded": pred_sh,
             "predicted_s_overlapping": pred_ov,
-            "predicted_s_compressed": plans["compressed"].modes[n].cost.predicted_s,
             "measured_s_sharded": None,
             "measured_s_overlapping": None,
             "measured_saving_vs_sharded": None,
@@ -235,31 +234,15 @@ def schedule_section(reps: int) -> dict:
         factors = random_factors(jax.random.PRNGKey(5), SCHEDULE_SHAPE, SCHEDULE_RANK)
         xs, fs = shard_problem(x, factors, mode_axes, mesh)
         executor = make_executor(plan.executor, mesh, mode_axes)
-        # carry-bearing executors (compressed) must be measured through their
-        # carry path -- plain contract() would silently time the exact psum
-        carry = (
-            executor.init_carry(plan, xs, fs)
-            if hasattr(executor, "init_carry")
-            else None
-        )
         cache = {sched.root.id: xs}
         for r, node in zip(rows, sched.walk()):
             src = cache[node.parent]
             alg = r["algorithm"]
-            if carry is not None:
-                fn = jax.jit(
-                    lambda s, f, c, node=node, alg=alg: executor.contract_carry(
-                        node, s, f, alg, c
-                    )
-                )
-                r["measured_s"] = time_fn(fn, src, fs, carry, reps=reps)["median_s"]
-                out, carry = fn(src, fs, carry)
-            else:
-                fn = jax.jit(
-                    lambda s, f, node=node, alg=alg: executor.contract(node, s, f, alg)
-                )
-                r["measured_s"] = time_fn(fn, src, fs, reps=reps)["median_s"]
-                out = fn(src, fs)
+            fn = jax.jit(
+                lambda s, f, node=node, alg=alg: executor.contract(node, s, f, alg)
+            )
+            r["measured_s"] = time_fn(fn, src, fs, reps=reps)["median_s"]
+            out = fn(src, fs)
             if not node.is_leaf:
                 cache[node.id] = out
     return {

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import CPConfig, cp_als
+from repro.plan import Problem, cp_als, plan_sweep
 
 
 def synth_fmri(t=120, subjects=30, regions=60, rank=5, seed=0):
@@ -40,9 +40,9 @@ def synth_fmri(t=120, subjects=30, regions=60, rank=5, seed=0):
 
 def run(x, rank, label, method="auto", iters=15):
     times = []
+    plan = plan_sweep(Problem.from_tensor(x, rank), strategy=method)
     st = cp_als(
-        x,
-        CPConfig(rank=rank, n_iters=iters, tol=1e-6, method=method),
+        x, plan, n_iters=iters, tol=1e-6,
         callback=lambda it, fit, dt: times.append(dt),
     )
     per_iter = float(np.min(times[1:])) if len(times) > 1 else times[0]

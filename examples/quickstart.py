@@ -15,15 +15,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (
-    CPConfig,
-    cp_als,
-    cp_full,
-    mttkrp_einsum,
-    random_factors,
-)
+from repro.core import cp_full, mttkrp_einsum, random_factors
 from repro.kernels import ops
-from repro.plan import Problem, plan_sweep
+from repro.plan import Problem, cp_als, plan_sweep
 
 
 def main():
@@ -43,8 +37,7 @@ def main():
 
     history = []
     state = cp_als(
-        x,
-        CPConfig(rank=rank, n_iters=40, tol=1e-7, method="auto"),
+        x, plan, n_iters=40, tol=1e-7,
         callback=lambda it, fit, dt: history.append((it, fit, dt)),
     )
     for it, fit, dt in history[:3] + history[-2:]:

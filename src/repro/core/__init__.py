@@ -1,6 +1,7 @@
-"""Core library: the paper's contribution (KRP, MTTKRP, CP-ALS) in JAX."""
+"""Core library: the paper's contribution (KRP, MTTKRP and the tensor
+operations they need) in JAX.  CP-ALS itself is :mod:`repro.plan`, which
+builds on this package; nothing here imports it."""
 
-from .cpals import CPConfig, CPState, cp_als, normalize_columns
 from .krp import (
     krp,
     krp_batched,
@@ -36,12 +37,8 @@ from .tensor_ops import (
 )
 
 __all__ = [
-    "CPConfig",
-    "CPState",
     "EINSUM_LETTERS",
-    "cp_als",
     "mode_letters",
-    "normalize_columns",
     "krp",
     "krp_batched",
     "krp_naive",

@@ -11,8 +11,8 @@ Every mode-n MTTKRP then reads only the small T tensor of its half (a
 multi-TTV over the sibling modes).  Updating the left modes first (from T_L,
 which depends only on the *right* factors) and then recomputing T_R from the
 fresh left factors reproduces the EXACT standard-ALS iterates -- verified in
-tests against cpals.als_sweep -- while reading X twice per sweep instead of
-N times.  The paper predicts ~2x per-iteration gain for 4-way tensors.
+tests against the flat schedule's sweep -- while reading X twice per sweep
+instead of N times.  The paper predicts ~2x per-iteration gain for 4-way tensors.
 
 This module holds the *numeric primitives* of that idea, generalized so the
 binary two-partial split is just one point in a family: any tree over
@@ -26,8 +26,9 @@ expressible with two operations --
   multi-TTV when a single mode survives).
 
 The tree *shapes* themselves live in :mod:`repro.plan.schedule` (the
-contraction-schedule IR); :func:`dimtree_sweep` stays as the frozen
-back-compat wrapper for the original binary-split sweep.
+contraction-schedule IR), and the sweep that walks them in
+:mod:`repro.plan.sweep` (``plan_sweep(strategy="dimtree")`` is the binary
+split).  This module imports nothing from :mod:`repro.plan`.
 """
 
 from __future__ import annotations
@@ -202,26 +203,3 @@ def mttkrp_from_partial(t: Array, siblings: Sequence[Array], pos: int) -> Array:
         si += 1
     return jnp.einsum(",".join(terms) + f"->{letters[pos]}c", *args)
 
-
-def dimtree_sweep(
-    x: Array,
-    factors: list[Array],
-    weights: Array,
-    norm_x: Array,
-    it: Array,
-    *,
-    normalize: bool = True,
-    split: int | None = None,
-):
-    """One full ALS sweep via the dimension tree; same signature contract as
-    cpals.als_sweep (returns (factors, weights, fit)) and identical iterates.
-
-    Back-compat wrapper: builds the ``strategy='dimtree'`` plan and runs the
-    single shared sweep engine on a LocalExecutor.
-    """
-    from repro import plan as planlib
-
-    return planlib.legacy_sweep(
-        x, factors, weights, norm_x, it,
-        strategy="dimtree", normalize=normalize, split=split,
-    )

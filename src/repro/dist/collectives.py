@@ -1,44 +1,18 @@
-"""Bandwidth-reducing collectives: int8 all-reduce with error feedback.
+"""Two-level collectives for the sharded MTTKRP's node reductions.
 
-``compressed_psum`` implements the standard EF-SGD compressor (Seide et
-al. 1-bit SGD generalized to int8; Karimireddy et al. error feedback):
-each participant quantizes ``value + residual`` to int8 with a private
-per-tensor scale, the quantized tensors are summed across the axis, and
-the local quantization error is carried into the next round.  The carried
-residual keeps the *accumulated* compression error bounded by one
-quantization step instead of growing with the step count, which is what
-lets a compressed data-parallel trainer track the exact run.
-
-Two call sites consume the compressor: the data-parallel gradient
-exchange below, and the compressed factor all-reduce of
-``dist_mttkrp.dist_mttkrp_compressed`` (the
-``repro.plan.CompressedShardedExecutor`` path, which threads the
-residuals through the ALS sweep as carry state).
-
-``make_compressed_dp_step`` builds the data-parallel train step on top:
-per-device grads inside ``shard_map``, compressed (or exact) mean over
-the data axes, then the usual AdamW update on the synchronized grads.
-Error-feedback state is explicitly per-device: leaves carry a leading
-device axis sharded over the whole mesh, so each device round-trips its
-own residual through the step like any other bit of training state (and
-it checkpoints/restores with the same machinery).
+:func:`hierarchical_psum` splits one ``psum`` into a reduce-scatter within
+the fast intra-node level, a psum of the shard across nodes and an
+all-gather back, so only ``1/k`` of each block crosses the slow level.
+:func:`reduce_scatter` and :func:`all_gather` are its two legs.  All three
+are exact: they change the reduction order, never what is summed.
 """
 
 from __future__ import annotations
 
-import math
-from typing import Any, Callable
-
 import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.launch import mesh as meshlib
-from repro.train.optimizer import OptConfig, OptState, adamw_update
+from jax.sharding import Mesh
 
 Array = jax.Array
-
-_EPS = 1e-12  # guards the all-zero-tensor scale
 
 
 def reduce_scatter(x: Array, axis_name, *, scatter_axis: int = 0) -> Array:
@@ -98,114 +72,3 @@ def hierarchical_psum(
     shard = reduce_scatter(x, node_axis, scatter_axis=scatter_axis)
     shard = jax.lax.psum(shard, inter)
     return all_gather(shard, node_axis, gather_axis=scatter_axis)
-
-
-def compressed_psum(
-    x: Array, axis_name, err: Array
-) -> tuple[Array, Array]:
-    """int8-quantized ``psum`` of ``x`` over ``axis_name`` with error feedback.
-
-    Must be called inside ``shard_map``.  ``axis_name`` may be a single mesh
-    axis or a tuple of axes (the gather then spans their product of devices,
-    like ``psum`` over multiple axes).  ``err`` is this device's carried
-    residual from the previous round (zeros initially, same shape as ``x``).
-    Returns ``(sum, new_err)``: the all-reduced dequantized sum (every
-    participant gets the same value) and the new local residual, bounded by
-    half a quantization step (``max|x + err| / 254``).
-
-    The collective itself is an all-gather of the int8 payloads plus one
-    fp32 scale per sender (scales are private, so summation happens on the
-    receiver after dequantization) -- the wire moves 1/4 the bytes of an
-    fp32 all-reduce, at the cost of an ``(n_participants, *x.shape)`` int8
-    gather buffer per tensor on each device.
-    """
-    val = x.astype(jnp.float32) + err
-    scale = jnp.maximum(jnp.max(jnp.abs(val)) / 127.0, _EPS)
-    q = jnp.round(val / scale).astype(jnp.int8)  # |val|/scale <= 127 by scale
-    new_err = val - q.astype(jnp.float32) * scale
-    qs = jax.lax.all_gather(q, axis_name)  # (n, ...) int8 on the wire
-    scales = jax.lax.all_gather(scale, axis_name)  # (n,) fp32
-    total = jnp.einsum("n...,n->...", qs.astype(jnp.float32), scales)
-    return total.astype(x.dtype), new_err
-
-
-def init_error_state(
-    params: Any, mesh: Mesh | None = None, *, n_shards: int | None = None
-) -> Any:
-    """Zero error-feedback residuals: one fp32 copy of ``params`` per device.
-
-    Leaves have shape ``(n, *param.shape)`` with the leading axis sharded
-    over the full mesh inside the compressed step.  ``n`` is the mesh size
-    when ``mesh`` is given, else ``n_shards``, else ``jax.device_count()``
-    (correct when the step's mesh spans every device; pass the mesh for
-    sub-meshes -- the step validates the match either way).
-    """
-    if n_shards is not None:
-        n = int(n_shards)
-    elif mesh is not None:
-        n = math.prod(mesh.shape.values())
-    else:
-        n = jax.device_count()
-    return jax.tree.map(
-        lambda p: jnp.zeros((n,) + tuple(p.shape), jnp.float32), params
-    )
-
-
-def make_compressed_dp_step(
-    model, opt_cfg: OptConfig, mesh: Mesh, *, compress: bool = True
-) -> Callable:
-    """Data-parallel train step with int8+error-feedback gradient exchange.
-
-    Returns ``step(params, opt_state, err, batch) -> (params, opt_state,
-    new_err, metrics)``.  ``compress=False`` swaps the quantized all-reduce
-    for an exact ``pmean`` (same code path otherwise), which is the
-    baseline the compressed run is validated against in tests.
-    """
-    axes = tuple(mesh.axis_names)
-    dp = meshlib.dp_axes(mesh)
-    dspec = meshlib.dp_spec_entry(mesh)
-    dp_size = math.prod(mesh.shape[a] for a in dp)
-
-    mesh_size = math.prod(mesh.shape.values())
-
-    def step(params: Any, opt_state: OptState, err: Any, batch: dict):
-        for e in jax.tree.leaves(err):
-            if e.shape[0] != mesh_size:
-                raise ValueError(
-                    f"error-state leading dim {e.shape[0]} != mesh size "
-                    f"{mesh_size}; build it with init_error_state(params, mesh)"
-                )
-
-        def local_fn(params, err_blk, batch_blk):
-            err_loc = jax.tree.map(lambda e: e[0], err_blk)
-            with meshlib.manual_mode():
-                (loss, metrics), grads = jax.value_and_grad(
-                    model.loss_fn, has_aux=True
-                )(params, batch_blk)
-            if compress:
-                flat_g, tdef = jax.tree.flatten(grads)
-                flat_e = tdef.flatten_up_to(err_loc)
-                summed = [compressed_psum(g, dp, e) for g, e in zip(flat_g, flat_e)]
-                grads = tdef.unflatten([s / dp_size for s, _ in summed])
-                err_loc = tdef.unflatten([e for _, e in summed])
-            else:
-                grads = jax.lax.pmean(grads, dp)
-            loss = jax.lax.pmean(loss, dp)
-            metrics = jax.lax.pmean(metrics, dp)
-            new_err = jax.tree.map(lambda e: e[None], err_loc)
-            return grads, new_err, loss, metrics
-
-        grads, new_err, loss, metrics = jax.shard_map(
-            local_fn,
-            mesh=mesh,
-            in_specs=(P(), P(axes), P(dspec)),
-            out_specs=(P(), P(axes), P(), P()),
-            check_vma=False,
-        )(params, err, batch)
-        params, opt_state, opt_stats = adamw_update(params, grads, opt_state, opt_cfg)
-        metrics = dict(metrics)
-        metrics.update(opt_stats)
-        metrics["loss"] = loss
-        return params, opt_state, new_err, metrics
-
-    return step

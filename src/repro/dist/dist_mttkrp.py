@@ -1,4 +1,4 @@
-"""Block-distributed MTTKRP and CP-ALS over a device mesh.
+"""Block-distributed MTTKRP over a device mesh.
 
 The paper's shared-memory parallelization assigns contiguous row blocks of
 the (never-materialized) matricization to threads; the distributed-memory
@@ -21,12 +21,13 @@ communication lower-bound analysis:
   * no collective touches the axis mapped to mode ``n`` itself: the output
     rows stay distributed over it, exactly like the factor they update.
 
-``dist_cp_als`` / ``dist_dimtree_sweep`` wrap this into sharded ALS
-drivers that match the single-device ``cp_als`` / ``als_sweep`` iterates
-numerically (same update algebra; only the reduction order differs).
-All sweeps route through the single engine in :mod:`repro.plan.sweep`
-(``ShardedExecutor`` wraps the shard_map + psum placement below); this
-module keeps the placement primitives and the back-compat entry points.
+Only the X-sized contractions run inside ``shard_map``; the C x C
+Gram/Hadamard/solve algebra and the (I_k, C) factor updates run at the
+global-array level in :mod:`repro.plan.sweep` (GSPMD inserts the small
+factor collectives), whose ``ShardedExecutor`` wraps the placement
+primitives below.  A sharded solve therefore matches the single-device
+iterates numerically: same update algebra, only the reduction order
+differs.  This module imports nothing from :mod:`repro.plan`.
 """
 
 from __future__ import annotations
@@ -41,7 +42,7 @@ from repro.core.dimtree import contract_from_partial, partial_mttkrp_range
 from repro.core.mttkrp import Method, mttkrp, mttkrp_batched
 from repro.core.tensor_ops import mode_letters
 
-from .collectives import compressed_psum, hierarchical_psum
+from .collectives import hierarchical_psum
 
 Array = jax.Array
 ModeAxes = Mapping[int, str]
@@ -83,10 +84,10 @@ def _node_psum(
         )
     return jax.lax.psum(m, reduce_axes)
 
-# default chunk count of the overlapped psum pipeline; the canonical knob
-# the planner uses is repro.plan.cost.DEFAULT_OVERLAP_CHUNKS (same value --
-# kept as a plain literal here so repro.dist never imports repro.plan at
-# module level).
+# Default chunk count of the overlapped psum pipeline (the planner's cost
+# model prices the same default): the serialization fraction is
+# ~1/n_chunks, so 4 already hides 75% of the hidable term while keeping
+# per-chunk GEMMs large enough to stay compute-efficient.
 DEFAULT_OVERLAP_CHUNKS = 4
 
 
@@ -454,126 +455,6 @@ def dist_mttkrp_overlapped(
     return fn(x, *factors)
 
 
-def init_mttkrp_error_state(
-    shape: Sequence[int], rank: int, mode_axes: ModeAxes, mesh: Mesh
-) -> dict[int, Array]:
-    """Zero error-feedback residuals for the compressed factor all-reduce.
-
-    One fp32 array per mode whose MTTKRP needs a psum (mapped modes other
-    than the mode itself exist).  Every participating device carries its own
-    residual: the global array for mode ``n`` has one leading axis per
-    reduced mesh axis (sharded over that axis) followed by the ``(I_n, C)``
-    output dims sharded like the factor the MTTKRP updates.  Thread the dict
-    through :func:`dist_mttkrp_compressed` calls; it is ordinary sweep state
-    (checkpointable, donate-able) exactly like the residuals of
-    ``make_compressed_dp_step``.
-    """
-    _validate(shape, mode_axes, mesh)
-    errs: dict[int, Array] = {}
-    for n in range(len(shape)):
-        reduce_axes = _reduce_axes(mode_axes, keep_modes=(n,))
-        if not reduce_axes:
-            continue
-        lead = tuple(mesh.shape[a] for a in reduce_axes)
-        e = jnp.zeros(lead + (shape[n], rank), jnp.float32)
-        spec = P(*reduce_axes, mode_axes.get(n), None)
-        errs[n] = jax.device_put(e, NamedSharding(mesh, spec))
-    return errs
-
-
-def dist_mttkrp_compressed(
-    x: Array,
-    factors: Sequence[Array],
-    n: int,
-    mode_axes: ModeAxes,
-    mesh: Mesh,
-    err: Array,
-    method: Method = "auto",
-    tiles: Mapping[str, int] | None = None,
-    *,
-    batch_axes: Sequence[str] = (),
-    collective: str = "flat",
-    node_axis: str | None = None,
-) -> tuple[Array, Array]:
-    """Mode-``n`` MTTKRP completed by the int8 error-feedback collective.
-
-    Same local kernel and placement as :func:`dist_mttkrp`, but the
-    completing fp32 psum is replaced by
-    :func:`repro.dist.collectives.compressed_psum` over the same mesh axes:
-    each device quantizes ``partial + err`` to int8 with a private scale,
-    all-gathers the payloads, and dequant-sums locally.  ``err`` is this
-    mode's entry of :func:`init_mttkrp_error_state`; returns ``(result,
-    new_err)``.  The carried residual keeps the accumulated quantization
-    error bounded by one int8 step, which is what lets compressed CP-ALS
-    track the exact fit across sweeps.
-
-    Batched tensors thread a batched residual (global layout: reduce-axis
-    leads, then the batch axis, then the output dims); the quantize /
-    all-gather / dequant path is shape-agnostic, so nothing else changes.
-
-    ``collective="hierarchical"`` splits the levels around the compressor:
-    the ``node_axis`` (intra-node) reduction runs as an *exact* psum on the
-    fast links first, then only the cross-node exchange is quantized --
-    every device in a node compresses the identical node-sum, so the
-    residual layout and carry semantics are unchanged while the int8 wire
-    traffic spans ``m`` nodes instead of ``k * m`` devices.
-    """
-    _validate_collective(collective)
-    batched = x.ndim == len(factors) + 1
-    shape = x.shape[1:] if batched else x.shape
-    _validate(shape, mode_axes, mesh)
-    reduce_axes = _reduce_axes(mode_axes, keep_modes=(n,))
-    if not reduce_axes:
-        out = dist_mttkrp(
-            x, factors, n, mode_axes, mesh,
-            method=method, tiles=tiles, batch_axes=batch_axes,
-        )
-        return out, err
-    if batched:
-        _validate_batch(x.shape[0], batch_axes, mode_axes, mesh)
-    order = len(shape)
-    entry = _batch_entry(batch_axes)
-    intra_first = (
-        collective == "hierarchical"
-        and node_axis in reduce_axes
-        and len(reduce_axes) > 1
-    )
-    gather_axes = (
-        tuple(a for a in reduce_axes if a != node_axis)
-        if intra_first
-        else reduce_axes
-    )
-    if batched:
-        err_spec = P(*reduce_axes, entry, mode_axes.get(n), None)
-        out_spec = P(entry, mode_axes.get(n), None)
-    else:
-        err_spec = P(*reduce_axes, mode_axes.get(n), None)
-        out_spec = P(mode_axes.get(n), None)
-
-    def local_fn(x_blk, err_blk, *f_blks):
-        if batched:
-            m = mttkrp_batched(x_blk, list(f_blks), n, method=method, tiles=tiles)
-        else:
-            m = mttkrp(x_blk, list(f_blks), n, method=method, tiles=tiles)
-        if intra_first:
-            m = jax.lax.psum(m, (node_axis,))
-        total, new_e = compressed_psum(m, gather_axes, err_blk.reshape(m.shape))
-        return total, new_e.reshape(err_blk.shape)
-
-    fn = jax.shard_map(
-        local_fn,
-        mesh=mesh,
-        in_specs=(
-            _x_spec(order, mode_axes, batched=batched, batch_axes=batch_axes),
-            err_spec,
-            *_factor_specs(order, mode_axes, batched=batched, batch_axes=batch_axes),
-        ),
-        out_specs=(out_spec, err_spec),
-        check_vma=False,
-    )
-    return fn(x, err, *factors)
-
-
 # --------------------------------------------------------------------------
 # Per-node contractions of a general dimension-tree schedule.  Every node of
 # repro.plan.schedule is one of two shapes -- a range contraction of the raw
@@ -582,8 +463,7 @@ def dist_mttkrp_compressed(
 # shard_map + the minimal psum over the axes mapped to the modes contracted
 # AT THAT NODE (parents were already reduced when they were built).  The
 # overlapped variants chunk along the node's leading kept mode so each
-# slab's psum hides behind the next slab's contraction; the compressed
-# variants run the node psum through the int8 error-feedback collective.
+# slab's psum hides behind the next slab's contraction.
 # --------------------------------------------------------------------------
 def _node_reduce_axes(mode_axes: ModeAxes, contracted: Sequence[int]) -> tuple[str, ...]:
     """Mesh axes of the mapped modes contracted at one node, in mode order."""
@@ -603,28 +483,24 @@ def _dist_contract(
     *,
     from_root: bool,
     n_chunks: int = 1,
-    err: Array | None = None,
     batch_axes: Sequence[str] = (),
     collective: str = "flat",
     node_axis: str | None = None,
 ):
-    """Shared core of the four per-node contraction entry points.
+    """Shared core of the two per-node contraction entry points.
 
     Derives the node's (contracted modes, reduce axes, specs) once, runs
     the matching local contraction -- :func:`partial_mttkrp_range` off the
     raw tensor, :func:`contract_from_partial` off a partial -- and
     completes it with this node's collective: per-slab psums along mode
-    ``lo`` when exact (``err is None``), the int8 error-feedback
-    ``compressed_psum`` otherwise.  ``collective="hierarchical"`` swaps
-    each exact psum for the two-level decomposition around ``node_axis``
-    (reduce-scatter / cross-node psum / all-gather); the compressed path
-    runs the intra-node level as an exact psum first and quantizes only
-    the cross-node exchange.
+    ``lo``.  ``collective="hierarchical"`` swaps each psum for the
+    two-level decomposition around ``node_axis`` (reduce-scatter /
+    cross-node psum / all-gather).
 
     Batchedness is inferred from ``src.ndim`` (one extra leading axis over
     the unbatched shape for the node's topology); the local contraction is
     then vmapped over the device's batch slab and every spec -- source,
-    factors, residual, output -- gains a leading ``batch_axes`` entry.
+    factors, output -- gains a leading ``batch_axes`` entry.
     Batch axes never appear in ``reduce_axes``: problems are independent.
     """
     _validate_collective(collective)
@@ -646,7 +522,6 @@ def _dist_contract(
             else P(entry, *[mode_axes.get(k) for k in range(parent_lo, parent_hi)], None)
         )
         out_spec = P(entry, *keep_axes, None)
-        err_spec = P(*reduce_axes, entry, *keep_axes, None)
     else:
         f_specs = [P(mode_axes.get(m), None) for m in contracted]
         src_spec = (
@@ -655,7 +530,6 @@ def _dist_contract(
             else P(*[mode_axes.get(k) for k in range(parent_lo, parent_hi)], None)
         )
         out_spec = P(*keep_axes, None)
-        err_spec = P(*reduce_axes, *keep_axes, None)
     lo_local = src.shape[lead + lo - parent_lo] // (
         mesh.shape[mode_axes[lo]] if lo in mode_axes else 1
     )
@@ -676,18 +550,7 @@ def _dist_contract(
             return jax.vmap(one)(src_blk, *cf)
         return one(src_blk, *cf)
 
-    intra_first = (
-        collective == "hierarchical"
-        and node_axis in reduce_axes
-        and len(reduce_axes) > 1
-    )
-    gather_axes = (
-        tuple(a for a in reduce_axes if a != node_axis)
-        if intra_first
-        else reduce_axes
-    )
-
-    def local_exact(src_blk, *cf):
+    def local_fn(src_blk, *cf):
         out = contract_local(src_blk, cf)
         if not reduce_axes:
             return out
@@ -701,31 +564,14 @@ def _dist_contract(
         ]
         return slabs[0] if len(slabs) == 1 else jnp.concatenate(slabs, axis=lead)
 
-    def local_compressed(src_blk, err_blk, *cf):
-        out = contract_local(src_blk, cf)
-        if intra_first:
-            out = jax.lax.psum(out, (node_axis,))
-        total, new_e = compressed_psum(out, gather_axes, err_blk.reshape(out.shape))
-        return total, new_e.reshape(err_blk.shape)
-
-    contracted_factors = [factors[m] for m in contracted]
-    if err is None:
-        fn = jax.shard_map(
-            local_exact,
-            mesh=mesh,
-            in_specs=(src_spec, *f_specs),
-            out_specs=out_spec,
-            check_vma=False,
-        )
-        return fn(src, *contracted_factors)
     fn = jax.shard_map(
-        local_compressed,
+        local_fn,
         mesh=mesh,
-        in_specs=(src_spec, err_spec, *f_specs),
-        out_specs=(out_spec, err_spec),
+        in_specs=(src_spec, *f_specs),
+        out_specs=out_spec,
         check_vma=False,
     )
-    return fn(src, err, *contracted_factors)
+    return fn(src, *[factors[m] for m in contracted])
 
 
 def dist_contract_range(
@@ -796,193 +642,3 @@ def dist_contract_partial(
         from_root=False, n_chunks=n_chunks, batch_axes=batch_axes,
         collective=collective, node_axis=node_axis,
     )
-
-
-def dist_contract_range_compressed(
-    x: Array,
-    factors: Sequence[Array],
-    lo: int,
-    hi: int,
-    mode_axes: ModeAxes,
-    mesh: Mesh,
-    err: Array,
-    *,
-    batch_axes: Sequence[str] = (),
-    collective: str = "flat",
-    node_axis: str | None = None,
-) -> tuple[Array, Array]:
-    """:func:`dist_contract_range` with the node psum compressed.
-
-    The completing fp32 psum of the partial tensor runs through
-    :func:`repro.dist.collectives.compressed_psum` over the same axes, with
-    ``err`` the node's persistent error-feedback residual (see
-    :func:`init_mttkrp_error_state` for the layout convention); returns
-    ``(partial, new_err)``.  Falls back to the exact path when the node
-    needs no collective.
-    """
-    order = len(factors)
-    _validate(x.shape[1:] if x.ndim == order + 1 else x.shape, mode_axes, mesh)
-    contracted = [m for m in range(order) if not lo <= m < hi]
-    if not _node_reduce_axes(mode_axes, contracted):
-        return (
-            dist_contract_range(
-                x, factors, lo, hi, mode_axes, mesh, batch_axes=batch_axes
-            ),
-            err,
-        )
-    return _dist_contract(
-        x, factors, lo, hi, 0, order, mode_axes, mesh,
-        from_root=True, err=err, batch_axes=batch_axes,
-        collective=collective, node_axis=node_axis,
-    )
-
-
-def dist_contract_partial_compressed(
-    t: Array,
-    factors: Sequence[Array],
-    lo: int,
-    hi: int,
-    parent_lo: int,
-    parent_hi: int,
-    mode_axes: ModeAxes,
-    mesh: Mesh,
-    err: Array,
-    *,
-    batch_axes: Sequence[str] = (),
-    collective: str = "flat",
-    node_axis: str | None = None,
-) -> tuple[Array, Array]:
-    """:func:`dist_contract_partial` with the node psum compressed.
-
-    Same placement, but the node's completing collective is the int8
-    error-feedback gather with ``err`` as this node's persistent residual;
-    returns ``(result, new_err)``.  Exact path when no collective is needed.
-    """
-    contracted = [m for m in range(parent_lo, parent_hi) if not lo <= m < hi]
-    if not _node_reduce_axes(mode_axes, contracted):
-        return (
-            dist_contract_partial(
-                t, factors, lo, hi, parent_lo, parent_hi, mode_axes, mesh,
-                batch_axes=batch_axes,
-            ),
-            err,
-        )
-    return _dist_contract(
-        t, factors, lo, hi, parent_lo, parent_hi, mode_axes, mesh,
-        from_root=False, err=err, batch_axes=batch_axes,
-        collective=collective, node_axis=node_axis,
-    )
-
-
-# --------------------------------------------------------------------------
-# Sharded ALS sweeps.  Only the X-sized contractions run inside shard_map;
-# the C x C Gram/Hadamard/pinv algebra and the (I_k, C) factor updates run
-# at the global-array level (GSPMD inserts the small factor collectives),
-# which is what keeps the distributed iterates numerically aligned with
-# cp_als/als_sweep.  The algebra itself lives ONCE in repro.plan.sweep;
-# these wrappers build the sharded plan + executor for the old signatures.
-# --------------------------------------------------------------------------
-def dist_als_sweep(
-    x: Array,
-    factors: list[Array],
-    weights: Array,
-    norm_x: Array,
-    it: Array,
-    mode_axes: ModeAxes,
-    mesh: Mesh,
-    method: Method = "auto",
-    normalize: bool = True,
-) -> tuple[list[Array], Array, Array]:
-    """One distributed ALS sweep; mirrors :func:`repro.core.cpals.als_sweep`."""
-    from repro import plan as planlib
-
-    return planlib.legacy_sweep(
-        x, factors, weights, norm_x, it,
-        strategy=method, normalize=normalize, mode_axes=mode_axes, mesh=mesh,
-    )
-
-
-def dist_dimtree_sweep(
-    x: Array,
-    factors: list[Array],
-    weights: Array,
-    norm_x: Array,
-    it: Array,
-    mode_axes: ModeAxes,
-    mesh: Mesh,
-    *,
-    normalize: bool = True,
-    split: int | None = None,
-) -> tuple[list[Array], Array, Array]:
-    """Distributed dimension-tree sweep; same iterates as the standard sweep.
-
-    Two distributed X-sized partial contractions per sweep (instead of N
-    full MTTKRPs): ``T_L`` from the old right factors, the per-mode updates
-    of the left half from ``T_L``, then ``T_R`` from the *fresh* left
-    factors and the right-half updates -- the schedule of the shared engine's
-    dimtree path, so it reproduces standard-ALS iterates while reading the
-    distributed tensor twice per sweep.
-    """
-    from repro import plan as planlib
-
-    return planlib.legacy_sweep(
-        x, factors, weights, norm_x, it,
-        strategy="dimtree", normalize=normalize, split=split,
-        mode_axes=mode_axes, mesh=mesh,
-    )
-
-
-def dist_cp_als(
-    x: Array,
-    rank: int,
-    mode_axes: ModeAxes,
-    mesh: Mesh,
-    n_iters: int = 50,
-    tol: float = 1.0e-5,
-    *,
-    seed: int = 0,
-    method: Method = "auto",
-    normalize: bool = True,
-    dimtree: bool = False,
-    init_factors: list[Array] | None = None,
-    executor: str = "sharded",
-) -> tuple[list[Array], Array, Array]:
-    """Sharded CP-ALS driver; same init/stop logic as core ``cp_als``.
-
-    Returns ``(factors, weights, fit)`` with factors row-distributed per
-    ``mode_axes``.  ``dimtree=True`` swaps in the distributed
-    dimension-tree sweep (identical iterates, 2 tensor reads per sweep).
-
-    ``executor`` picks the communication strategy of the factor all-reduce:
-    ``"sharded"`` (the frozen default -- plain psum), ``"overlapping"``
-    (chunked psum hidden behind the local GEMMs; exact),
-    ``"compressed"`` (int8 error-feedback all-gather; approximate, with the
-    per-mode residuals threaded through the sweep), or ``"auto"`` to let
-    :func:`repro.plan.select_executor` cost-argmin among them.
-
-    Back-compat wrapper over the single :func:`repro.plan.cp_als` driver.
-    """
-    from repro import plan as planlib
-
-    problem = planlib.Problem.from_tensor(x, rank, mode_axes=mode_axes, mesh=mesh)
-    # the executor kind propagates verbatim (any executor now pairs with any
-    # schedule: overlapping chunks and compressed compresses the dimtree
-    # partials per node); the tree shape stays pinned to the wrapper's
-    # historical behavior -- flat per-mode, or the binary split for dimtree
-    sweep_plan = planlib.plan_sweep(
-        problem,
-        strategy="dimtree" if dimtree else method,
-        normalize=normalize,
-        executor=executor,
-        schedule=None if dimtree else "flat",
-    )
-    st = planlib.cp_als(
-        x,
-        sweep_plan,
-        executor=planlib.make_executor(sweep_plan.executor, mesh, mode_axes),
-        n_iters=n_iters,
-        tol=tol,
-        seed=seed,
-        init_factors=init_factors,
-    )
-    return st.factors, st.weights, st.fit

@@ -3,9 +3,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Dry-run of the paper's own workload at pod scale: distributed CP-ALS.
 
-Lowers one full distributed ALS sweep (dist/dist_mttkrp.dist_als_sweep) on the
-production mesh for a pod-scale dense tensor (default: a 2048 time x 1024
-subject x 400 x 400 region functional-connectivity tensor, 1.34 TB fp32 --
+Lowers one full distributed ALS sweep (repro.plan.als_sweep on a
+ShardedExecutor) on the production mesh for a pod-scale dense tensor
+(default: a 2048 time x 1024 subject x 400 x 400 region
+functional-connectivity tensor, 1.34 TB fp32 --
 the paper's fMRI application grown to the scale its Sec. 3 calls for), and
 records the same cost/memory/collective stats as the LM dry-run.
 
@@ -13,6 +14,8 @@ The MTTKRP method is selectable -- this is the SPerf hillclimb axis:
   1step : paper Alg. 3 with the explicit KRP (materializes K_L (.) K_R)
   2step : paper Alg. 4 (partial MTTKRP + multi-TTV)
   auto  : paper's recommended mix (Sec. 5.3.3)
+  dimtree: the binary dimension tree (two X-sized partials a sweep)
+The non-tree methods run the flat per-mode schedule.
 
     PYTHONPATH=src python -m repro.launch.dryrun_cp --method auto --mesh pod
 """
@@ -26,16 +29,10 @@ import jax.numpy as jnp  # noqa: E402
 
 
 def run(shape, rank, method, mesh_kind, mode_axes, out_dir):
-    from functools import partial
-
     from repro.analysis.roofline import parse_collectives
-    from repro.dist.dist_mttkrp import (
-        _factor_specs,
-        _x_spec,
-        dist_als_sweep,
-        dist_dimtree_sweep,
-    )
+    from repro.dist.dist_mttkrp import _factor_specs, _x_spec
     from repro.launch.mesh import make_production_mesh
+    from repro.plan import Problem, ShardedExecutor, SweepState, als_sweep, plan_sweep
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multipod"))
@@ -57,10 +54,20 @@ def run(shape, rank, method, mesh_kind, mode_axes, out_dir):
         jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P())),
     ]
 
-    if method == "dimtree":
-        fn = partial(dist_dimtree_sweep, mode_axes=mode_axes, mesh=mesh)
-    else:
-        fn = partial(dist_als_sweep, mode_axes=mode_axes, mesh=mesh, method=method)
+    problem = Problem.from_tensor(x_struct, rank, mode_axes=mode_axes, mesh=mesh)
+    plan = plan_sweep(
+        problem, strategy=method, executor="sharded",
+        schedule=None if method == "dimtree" else "flat",
+    )
+    executor = ShardedExecutor(mesh, mode_axes)
+
+    def fn(x, factors, weights, norm_x, it):
+        state = SweepState(
+            x=x, factors=list(factors), weights=weights, norm_x=norm_x, it=it
+        )
+        out = als_sweep(problem, plan, executor, state)
+        return out.factors, out.weights, out.fit
+
     t0 = time.perf_counter()
     lowered = jax.jit(fn).lower(x_struct, f_structs, *scalars)
     compiled = lowered.compile()

@@ -14,18 +14,16 @@ The solver API redesigned around four pieces:
   shape (:func:`enumerate_schedules`), each root leaf's MTTKRP algorithm
   (1-step / 2-step-left / 2-step-right / fused), and -- via
   :func:`select_executor` -- the executor kind (local / sharded /
-  overlapping / compressed) under the bounded-overlap model, per-node
+  overlapping, all exact) under the bounded-overlap model, per-node
   (:func:`node_cost`); :meth:`SweepPlan.describe` exposes the predictions
   so benchmarks report predicted-vs-measured, and calibrated
   ``serial_fractions`` from ``bench_mttkrp --calibrate`` feed back in.
 * :class:`Executor` -- where contractions run: :class:`LocalExecutor`
   (single device), :class:`ShardedExecutor` (``shard_map`` + minimal
-  per-node psum over a device mesh), :class:`OverlappingExecutor` (chunked
-  psums hidden behind the local GEMMs -- full MTTKRPs and tree partials
-  alike; exact), or :class:`CompressedShardedExecutor` (int8 error-feedback
-  collectives with per-node residuals; approximate).
-  :func:`make_executor` builds the instance a ``SweepPlan.executor`` kind
-  names.
+  per-node psum over a device mesh), or :class:`OverlappingExecutor`
+  (chunked psums hidden behind the local GEMMs -- full MTTKRPs and tree
+  partials alike; exact).  :func:`make_executor` builds the instance a
+  ``SweepPlan.executor`` kind names.
 
 * :mod:`repro.plan.autotune` -- the measured side of the loop:
   :func:`tune` times candidate Pallas tilings and candidate
@@ -58,10 +56,10 @@ The solver API redesigned around four pieces:
 
 Exactly one :func:`als_sweep` engine (a schedule walker) and one
 :func:`cp_als` driver (sync-free: ``sweeps_per_sync`` sweeps per device
-dispatch under ``lax.scan``, bitwise-identical iterates) consume them; the pre-redesign entry points
-(``core.cpals.cp_als``, ``core.dimtree.dimtree_sweep``,
-``dist.dist_mttkrp.dist_cp_als`` / ``dist_dimtree_sweep``) remain as frozen
-thin wrappers that build the corresponding plan.
+dispatch under ``lax.scan``, bitwise-identical iterates, returning a
+:class:`CPState`) consume them.  This package is the only CP-ALS API and
+the only owner of the ALS algebra: :mod:`repro.core`, :mod:`repro.dist` and
+:mod:`repro.kernels` supply contractions and never import it.
 """
 
 from .autotune import (
@@ -78,7 +76,6 @@ from .cost import (
     PP_EXACT_FRACTION,
     ModeCost,
     collective_level_bytes,
-    compressed_allgather_bytes,
     dimtree_mode_cost,
     executor_mode_cost,
     hierarchical_applicable,
@@ -92,7 +89,6 @@ from .cost import (
     validate_executor,
 )
 from .executor import (
-    CompressedShardedExecutor,
     Executor,
     LocalExecutor,
     OverlappingExecutor,
@@ -120,7 +116,7 @@ from .schedule import (
     flat_schedule,
     pp_pairs,
 )
-from .sweep import PPState, SweepState, als_sweep, cp_als, legacy_sweep
+from .sweep import CPState, PPState, SweepState, als_sweep, cp_als
 
 __all__ = [
     "ALGORITHMS",
@@ -129,7 +125,7 @@ __all__ = [
     "PP_EXACT_FRACTION",
     "SCHEDULE_NAMES",
     "STRATEGIES",
-    "CompressedShardedExecutor",
+    "CPState",
     "ContractionNode",
     "Executor",
     "LocalExecutor",
@@ -151,7 +147,6 @@ __all__ = [
     "build_schedule",
     "chain_schedule",
     "collective_level_bytes",
-    "compressed_allgather_bytes",
     "cp_als",
     "default_tuning_cache",
     "dimtree_mode_cost",
@@ -159,7 +154,6 @@ __all__ = [
     "executor_mode_cost",
     "flat_schedule",
     "hierarchical_applicable",
-    "legacy_sweep",
     "lookup_measurements",
     "make_executor",
     "mode_cost",

@@ -471,8 +471,8 @@ def _tune_nodes(
     """Measure every node of every candidate (schedule x executor) plan.
 
     Walks each candidate schedule exactly like the sweep engine (parents'
-    outputs cached for their children, carry-bearing executors measured
-    through their carry path), timing each deduped :func:`node_key` once.
+    outputs cached for their children), timing each deduped
+    :func:`node_key` once.
     Root leaves are measured under every competing algorithm -- ``fused``
     with ``fused_tiles`` and ``matrix_free`` with ``matrix_free_tiles``
     (the already-tuned tilings), so the argmin times exactly the
@@ -496,9 +496,7 @@ def _tune_nodes(
     from .schedule import enumerate_schedules
     from .sweep import _reads_view, prepare_operands, view_splits
 
-    kinds = (
-        ("sharded", "overlapping", "compressed") if problem.sharded else ("local",)
-    )
+    kinds = ("sharded", "overlapping") if problem.sharded else ("local",)
     # flat first: its leaves are the full per-mode MTTKRPs every tree shares,
     # so a tight budget still measures the comparisons that matter most
     schedules = sorted(enumerate_schedules(problem), key=lambda s: not s.is_flat)
@@ -509,9 +507,6 @@ def _tune_nodes(
         xs, fs = ex.prepare(problem, x, list(factors))
         for sched in schedules:
             plan = plan_sweep(problem, schedule=sched, executor=kind)
-            carry = (
-                ex.init_carry(plan, xs, fs) if hasattr(ex, "init_carry") else None
-            )
             splits = view_splits(plan, ex)
             views = (
                 prepare_operands(xs, splits=splits, batched=problem.batched)[1]
@@ -547,61 +542,33 @@ def _tune_nodes(
                     run_out = None
                     for coll in colls:
                         key = node_key(node, alg, kind, coll)
-                        if carry is not None:
+                        if reads_view:
                             fn = jax.jit(
-                                lambda s, f, c, node=node, alg=alg, tl=tl, coll=coll: (
-                                    ex.contract_carry(
-                                        node, s, f, alg, c, tiles=tl, collective=coll
+                                lambda v, f, node=node: ex.contract_view(node, v, f)
+                            )
+                        else:
+                            fn = jax.jit(
+                                lambda s, f, node=node, alg=alg, tl=tl, coll=coll: (
+                                    ex.contract(
+                                        node, s, f, alg, tiles=tl, collective=coll
                                     )
                                 )
                             )
-                            if key not in seen and not budget.exhausted():
-                                seen.add(key)
-                                rows.append(
-                                    {
-                                        "key": key,
-                                        "executor": kind,
-                                        "algorithm": alg,
-                                        "collective": coll,
-                                        "schedule": sched.name,
-                                        "node": node.id,
-                                        "measured_s": _time(
-                                            lambda: fn(src, fs, carry)[0], reps
-                                        ),
-                                    }
-                                )
-                            if alg == planned and coll == "flat":
-                                run_out, carry = fn(src, fs, carry)
-                        else:
-                            if reads_view:
-                                fn = jax.jit(
-                                    lambda v, f, node=node: ex.contract_view(node, v, f)
-                                )
-                            else:
-                                fn = jax.jit(
-                                    lambda s, f, node=node, alg=alg, tl=tl, coll=coll: (
-                                        ex.contract(
-                                            node, s, f, alg, tiles=tl, collective=coll
-                                        )
-                                    )
-                                )
-                            if key not in seen and not budget.exhausted():
-                                seen.add(key)
-                                rows.append(
-                                    {
-                                        "key": key,
-                                        "executor": kind,
-                                        "algorithm": alg,
-                                        "collective": coll,
-                                        "schedule": sched.name,
-                                        "node": node.id,
-                                        "measured_s": _time(
-                                            lambda: fn(src, fs), reps
-                                        ),
-                                    }
-                                )
-                            if alg == planned and coll == "flat":
-                                run_out = fn(src, fs)
+                        if key not in seen and not budget.exhausted():
+                            seen.add(key)
+                            rows.append(
+                                {
+                                    "key": key,
+                                    "executor": kind,
+                                    "algorithm": alg,
+                                    "collective": coll,
+                                    "schedule": sched.name,
+                                    "node": node.id,
+                                    "measured_s": _time(lambda: fn(src, fs), reps),
+                                }
+                            )
+                        if alg == planned and coll == "flat":
+                            run_out = fn(src, fs)
                     if run_out is not None:
                         out = run_out
                 if not node.is_leaf:

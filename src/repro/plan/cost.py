@@ -43,6 +43,7 @@ from typing import Mapping
 from repro.analysis.roofline import DCN_BW, HBM_BW, ICI_BW, PEAK_FLOPS
 from repro.core.mttkrp import mttkrp_flops
 from repro.core.tensor_ops import dims_split
+from repro.dist.dist_mttkrp import DEFAULT_OVERLAP_CHUNKS
 
 from .problem import Problem
 from .schedule import (
@@ -65,19 +66,7 @@ ALGORITHMS = (
 )
 
 # Executor kinds the planner dispatches over (repro.plan.executor classes).
-EXECUTORS = ("local", "sharded", "overlapping", "compressed")
-
-# Default chunk count of the overlapping executor's double-buffered psum
-# pipeline: the serialization fraction is ~1/n_chunks, so 4 already hides
-# 75% of the hidable term while keeping per-chunk GEMMs large enough to
-# stay compute-efficient.
-DEFAULT_OVERLAP_CHUNKS = 4
-
-# compressed_psum payload: 1 int8 byte per element + one fp32 scale per
-# sender (so the wire ratio vs the uncompressed dtype depends on itemsize:
-# 1/4 for fp32, 1/2 for bf16, 1/8 for f64).
-_INT8_ITEMSIZE = 1.0
-_SCALE_BYTES = 4.0
+EXECUTORS = ("local", "sharded", "overlapping")
 
 # Assumed long-run fraction of pairwise-perturbation sweeps that
 # re-materialize the cache (factor drift crossing ``pp_tol``).  Late ALS
@@ -92,7 +81,7 @@ def validate_executor(problem: Problem, executor: str) -> None:
 
     Every (schedule, executor) pair is either costed or rejected here --
     schedules themselves never restrict the executor (any node's psum can
-    be overlapped or compressed), so validity depends only on the problem's
+    be overlapped), so validity depends only on the problem's
     placement: ``local`` cannot run sharded problems, and the
     communication-hiding kinds need a sharded problem to have anything to
     hide.  Raises a single-format ``ValueError`` on rejection.
@@ -102,10 +91,10 @@ def validate_executor(problem: Problem, executor: str) -> None:
     reason = None
     if executor == "local" and problem.sharded:
         reason = "it runs on one device but the problem maps modes/batch to mesh axes"
-    elif executor in ("overlapping", "compressed") and not problem.mode_axes:
+    elif executor == "overlapping" and not problem.mode_axes:
         # batch-parallel-only placements have zero reduce traffic: nothing
-        # to overlap or compress (mode_axes, not sharded, is the predicate)
-        reason = "it reschedules/compresses psums but the problem has none"
+        # to overlap (mode_axes, not sharded, is the predicate)
+        reason = "it reschedules psums but the problem has none"
     if reason is not None:
         raise ValueError(f"executor {executor!r} cannot run this problem: {reason}")
 
@@ -208,27 +197,6 @@ class ModeCost:
             "measured_s": self.measured_s,
             "expected_s": self.expected_s,
         }
-
-
-def compressed_allgather_bytes(
-    block_bytes: float, participants: int, itemsize: float = 4.0
-) -> float:
-    """Per-device wire bytes of ``dist.collectives.compressed_psum``.
-
-    The compressed collective is an all-gather of int8 payloads (scales are
-    private per sender, so summation happens after dequantization on every
-    receiver): each device receives ``participants - 1`` remote blocks at
-    one byte per element -- ``block_bytes / itemsize`` -- plus one fp32
-    scale each.  Versus the fp32 ring all-reduce (``2 B (p-1)/p``) the
-    ratio is ``p/8`` -- a real win for few participants (4x at p=2) that
-    *vanishes at p=8* and inverts beyond, which is exactly why executor
-    selection is cost-driven rather than a flag.  Pass the problem's
-    ``itemsize`` for non-fp32 dtypes (bf16 compresses only 2x per element).
-    """
-    if participants <= 1:
-        return 0.0
-    payload = block_bytes * _INT8_ITEMSIZE / itemsize
-    return (participants - 1) * (payload + _SCALE_BYTES)
 
 
 def _level_shards(problem: Problem, reduce_axes) -> tuple[int, int]:
@@ -503,67 +471,15 @@ def mode_cost(
     )
 
 
-def _compress_terms(
-    problem: Problem,
-    base: ModeCost,
-    block_bytes: float,
-    participants: int,
-    *,
-    reduce_axes=(),
-    collective: str = "flat",
-) -> ModeCost:
-    """Replace a node's ring all-reduce with the int8 error-feedback gather:
-    wire bytes become :func:`compressed_allgather_bytes` of the local output
-    block, and HBM traffic grows by the quantize (write + read the int8
-    block) and dequantize (read the ``p-1`` gathered payloads) passes.
-
-    On a two-level problem, ``collective="hierarchical"`` prices the split
-    the executors actually run: an *exact* fp32 ring within the node (the
-    intra level stays uncompressed -- it is cheap) plus the int8 gather
-    across the ``m`` nodes only, so the compressed payload count drops from
-    ``k * m - 1`` to ``m - 1`` senders.
-    """
-    s = problem.itemsize
-    int8_block = block_bytes * _INT8_ITEMSIZE / s
-    k, m = _level_shards(problem, reduce_axes)
-    if collective == "hierarchical" and k > 1 and m > 1:
-        intra = ring_allreduce_bytes(block_bytes, k)
-        inter = compressed_allgather_bytes(block_bytes, m, s)
-        return replace(
-            base,
-            collective_bytes=intra + inter,
-            inter_bytes=inter,
-            bytes=base.bytes + (m + 1) * int8_block,
-        )
-    coll = compressed_allgather_bytes(block_bytes, participants, s)
-    inter = coll if (problem.intra_axes and m > 1) else 0.0
-    return replace(
-        base,
-        collective_bytes=coll,
-        inter_bytes=inter,
-        bytes=base.bytes + (participants + 1) * int8_block,
-    )
-
-
 def _adjust(
-    problem: Problem,
     base: ModeCost,
     executor: str,
     *,
     chunk_extent: int,
     n_chunks: int,
-    block_bytes: float,
-    participants: int,
     serial_fractions: Mapping[str, float] | None,
-    reduce_axes=(),
-    collective: str = "flat",
 ) -> ModeCost:
-    """Full executor adjustment: compression terms, then schedule fraction."""
-    if executor == "compressed" and base.collective_bytes > 0.0:
-        base = _compress_terms(
-            problem, base, block_bytes, participants,
-            reduce_axes=reduce_axes, collective=collective,
-        )
+    """Executor adjustment: the unhidable fraction of the smaller term."""
     fitted = (serial_fractions or {}).get(executor)
     if base.collective_bytes <= 0.0:
         return base
@@ -597,11 +513,6 @@ def executor_mode_cost(
       double-buffered pipeline hides all but ``1/n_chunks`` of the smaller
       of compute/collective time (chunk count is capped by the local row
       count of mode ``n``).
-    * ``"compressed"`` -- the fp32 ring all-reduce is replaced by the int8
-      error-feedback all-gather: wire bytes become
-      :func:`compressed_allgather_bytes`, and HBM traffic grows by the
-      quantize/dequantize passes (write + read the int8 block, read the
-      gathered payloads).
 
     ``serial_fractions`` (executor kind -> fitted unhidable fraction, from
     ``bench_mttkrp --calibrate``) overrides the analytic defaults.
@@ -610,21 +521,12 @@ def executor_mode_cost(
     """
     validate_executor(problem, executor)
     base = mode_cost(problem, n, algorithm, collective=collective)
-    _, in_local, _ = dims_split(problem.local_shape, n)
-    block = in_local * problem.rank * problem.itemsize * problem.local_batch
-    axes = problem.reduce_axes_for(n)
-    p = math.prod(problem.axis_sizes[a] for a in axes)
     return _adjust(
-        problem,
         base,
         executor,
         chunk_extent=problem.local_shape[n],
         n_chunks=n_chunks,
-        block_bytes=block,
-        participants=p,
         serial_fractions=serial_fractions,
-        reduce_axes=axes,
-        collective=collective,
     )
 
 
@@ -712,18 +614,12 @@ def node_cost(
             collective_bytes=coll,
             inter_bytes=inter,
         )
-    block = t_elems * s
     return _adjust(
-        problem,
         base,
         executor,
         chunk_extent=problem.local_shape[node.lo],
         n_chunks=n_chunks,
-        block_bytes=block,
-        participants=node.psum_participants,
         serial_fractions=serial_fractions,
-        reduce_axes=node.reduce_axes,
-        collective=collective,
     )
 
 

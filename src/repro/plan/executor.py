@@ -2,7 +2,8 @@
 
 The sweep engine (:mod:`repro.plan.sweep`) is executor-agnostic: it walks
 the plan's contraction schedule asking for "this node's contraction" and
-never touches placement.  Four executors implement the protocol:
+never touches placement.  Three executors implement the protocol, all
+exact:
 
 * :class:`LocalExecutor` -- the paper's shared-memory kernels, one device.
 * :class:`ShardedExecutor` -- the ``shard_map`` + minimal-``psum`` placement
@@ -12,10 +13,6 @@ never touches placement.  Four executors implement the protocol:
   contraction -- full MTTKRPs *and* the partial contractions of a
   dimension-tree schedule -- is chunked so chunk ``k``'s psum overlaps
   chunk ``k+1``'s GEMM (communication hiding; exact).
-* :class:`CompressedShardedExecutor` -- every node psum runs through the
-  int8 error-feedback collective, with per-node residuals threaded through
-  the sweep as carry state (communication compression; approximate but
-  convergent).
 
 ``plan_sweep(executor="auto")`` picks among them by predicted cost; use
 :func:`make_executor` to turn the chosen ``SweepPlan.executor`` kind into
@@ -24,11 +21,10 @@ an instance bound to a concrete mesh.
 
 from __future__ import annotations
 
-from typing import Any, Mapping, Protocol, Sequence, runtime_checkable
+from typing import Mapping, Protocol, Sequence, runtime_checkable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.dimtree import (
     contract_from_partial,
@@ -39,11 +35,8 @@ from repro.core.mttkrp import mttkrp, mttkrp_batched
 from repro.core.tensor_ops import mode_letters
 from repro.dist.dist_mttkrp import (
     dist_contract_partial,
-    dist_contract_partial_compressed,
     dist_contract_range,
-    dist_contract_range_compressed,
     dist_mttkrp,
-    dist_mttkrp_compressed,
     dist_mttkrp_overlapped,
     dist_pp_pairs,
     shard_problem,
@@ -73,16 +66,10 @@ class Executor(Protocol):
     The schedule walker drives everything through :meth:`contract` -- one
     entry point per :class:`repro.plan.schedule.ContractionNode`, whether
     the node is a full mode MTTKRP, a root-level partial GEMM, or a
-    partial-to-partial multi-TTV.  Executors that carry state across
-    contractions (e.g. per-node error-feedback residuals) additionally
-    implement the optional carry extension -- ``init_carry(plan, x,
-    factors)`` and ``contract_carry(node, src, factors, algorithm, carry)
-    -> (out, carry)`` -- which the engine threads through
-    ``SweepState.carry`` when present (``hasattr`` duck typing; stateless
-    executors skip both).  Executors that can contract a root partial from
-    the tensor's matrix view implement ``contract_view(node, view,
-    factors)``; :func:`repro.plan.sweep.cp_als` then builds each view once
-    a solve and hands it to the node (:class:`LocalExecutor` only; the
+    partial-to-partial multi-TTV.  Executors that can contract a root
+    partial from the tensor's matrix view implement ``contract_view(node,
+    view, factors)``; :func:`repro.plan.sweep.cp_als` then builds each view
+    once a solve and hands it to the node (:class:`LocalExecutor` only; the
     others read the tensor as before).
     """
 
@@ -272,9 +259,8 @@ class ShardedExecutor:
         local einsum inside ``shard_map`` + the minimal psum over the axes
         mapped to the contracted modes (both kept modes ride their own
         axes, exactly like the factor rows they later update).  The PP
-        cache build stays *exact* on every sharded executor -- overlapping
-        changes only psum scheduling and compression only applies to the
-        per-sweep factor reductions, so both inherit this verbatim."""
+        cache build is the same on every sharded executor -- overlapping
+        changes only psum scheduling, so it inherits this verbatim."""
         return dist_pp_pairs(
             x, list(factors), self.mode_axes, self.mesh,
             batch_axes=self.batch_axes,
@@ -325,95 +311,6 @@ class OverlappingExecutor(ShardedExecutor):
         )
 
 
-class CompressedShardedExecutor(ShardedExecutor):
-    """Communication-compressing sharded executor (approximate, convergent).
-
-    Runs every node psum -- the per-mode factor all-reduces *and* the
-    partial contractions of dimension-tree schedules -- through the int8
-    error-feedback collective: each device quantizes its partial result
-    plus its carried residual, all-gathers the int8 payloads, and
-    dequant-sums locally.  The per-node residuals are persistent sweep
-    state -- created by :meth:`init_carry`, threaded through
-    :meth:`contract_carry` by the engine -- so the accumulated quantization
-    error at every node stays bounded by one int8 step and compressed
-    CP-ALS converges to the exact fit.  Nodes whose mapping needs no psum
-    run the exact path.
-    """
-
-    def init_carry(self, plan, x: Array, factors: Sequence[Array]) -> dict[int, Array]:
-        """Zero per-node error-feedback residuals for every schedule node
-        whose contraction completes with a psum, placed on the mesh (one
-        leading axis per reduced mesh axis, then -- for a batched problem --
-        the batch dim sharded over ``batch_axes``, then the node's global
-        output dims sharded like the output itself)."""
-        prob = plan.problem
-        batched = bool(getattr(prob, "batched", False))
-        batch_entry = tuple(self.batch_axes) or None
-        errs: dict[int, Array] = {}
-        for node in plan.resolved_schedule.walk():
-            if not node.reduce_axes:
-                continue
-            lead = tuple(self.mesh.shape[a] for a in node.reduce_axes)
-            mid = (prob.batch,) if batched else ()
-            e = jnp.zeros(lead + mid + node.shape, jnp.float32)
-            spec = P(
-                *node.reduce_axes,
-                *((batch_entry,) if batched else ()),
-                *[self.mode_axes.get(m) for m in node.modes],
-                None,
-            )
-            errs[node.id] = jax.device_put(e, NamedSharding(self.mesh, spec))
-        return errs
-
-    def contract_carry(
-        self,
-        node: ContractionNode,
-        src: Array,
-        factors: Sequence[Array],
-        algorithm: str,
-        carry: Any,
-        tiles: Mapping[str, int] | None = None,
-        collective: str = "flat",
-    ) -> tuple[Array, Any]:
-        """Compressed node contraction; returns ``(result, new_carry)``.
-
-        Dispatches to the compressed variant matching the node's topology
-        when a residual exists for it, the exact path otherwise; ``tiles``
-        threads the plan's tuned kernel tiling into the local contraction.
-        With ``collective="hierarchical"`` the intra-node slice of the psum
-        runs exact first and only the cross-node stage is compressed --
-        same residual layout and carry semantics, less wire traffic.
-        """
-        if carry is None or node.id not in carry:
-            return (
-                self.contract(
-                    node, src, factors, algorithm, tiles=tiles, collective=collective
-                ),
-                carry,
-            )
-        err = carry[node.id]
-        if node.from_root and node.is_leaf:
-            out, new_err = dist_mttkrp_compressed(
-                src, list(factors), node.mode, self.mode_axes, self.mesh, err,
-                method=algorithm, tiles=tiles, batch_axes=self.batch_axes,
-                collective=collective, node_axis=self.node_axis,
-            )
-        elif node.from_root:
-            out, new_err = dist_contract_range_compressed(
-                src, list(factors), node.lo, node.hi, self.mode_axes, self.mesh,
-                err, batch_axes=self.batch_axes,
-                collective=collective, node_axis=self.node_axis,
-            )
-        else:
-            out, new_err = dist_contract_partial_compressed(
-                src, list(factors), node.lo, node.hi, node.parent_lo,
-                node.parent_hi, self.mode_axes, self.mesh, err,
-                batch_axes=self.batch_axes,
-                collective=collective, node_axis=self.node_axis,
-            )
-        return out, {**carry, node.id: new_err}
-
-
 def make_executor(
     kind: str,
     mesh=None,
@@ -443,9 +340,7 @@ def make_executor(
         raise ValueError(f"executor {kind!r} needs mesh and mode_axes")
     if kind == "sharded":
         return ShardedExecutor(mesh, mode_axes, batch_axes, node_axis)
-    if kind == "overlapping":
-        return OverlappingExecutor(
-            mesh, mode_axes, n_chunks=n_chunks, batch_axes=batch_axes,
-            node_axis=node_axis,
-        )
-    return CompressedShardedExecutor(mesh, mode_axes, batch_axes, node_axis)
+    return OverlappingExecutor(
+        mesh, mode_axes, n_chunks=n_chunks, batch_axes=batch_axes,
+        node_axis=node_axis,
+    )

@@ -16,12 +16,12 @@ the tree shapes of :func:`repro.plan.schedule.enumerate_schedules` (the
 flat per-mode sweep, the binary split at every boundary, and the
 multi-level chain for order >= 4) and, via ``executor='auto'``, over the
 executor kinds of :data:`repro.plan.cost.EXECUTORS` under the
-bounded-overlap model -- so dimension-tree reuse, communication hiding and
-compression are all planner decisions, not call-site flags.  Any (schedule,
-executor) pair is valid (:func:`repro.plan.cost.validate_executor` is the
-one predicate): the overlapping and compressed executors chunk/compress the
-partial contractions of tree schedules just like full MTTKRPs.  The chosen
-kind lands on ``SweepPlan.executor``;
+bounded-overlap model -- so dimension-tree reuse and communication hiding
+are planner decisions, not call-site flags.  Every executor kind is exact.
+Any (schedule, executor) pair is valid
+(:func:`repro.plan.cost.validate_executor` is the one predicate): the
+overlapping executor chunks the partial contractions of tree schedules
+just like full MTTKRPs.  The chosen kind lands on ``SweepPlan.executor``;
 :func:`repro.plan.executor.make_executor` turns it into the matching
 executor instance given the concrete mesh.
 """
@@ -79,11 +79,6 @@ SCHEDULE_NAMES = ("flat", "binary", "chain")
 # alone decides only clear wins.  The same margin breaks schedule near-ties
 # toward the flat per-mode sweep (the shape the paper measured).
 _NEAR_TIE = 0.9
-
-# the compressed executor changes numerics (int8 + error feedback), so it
-# must beat the best *exact* executor by >10% predicted time to be selected
-# -- mirroring the _NEAR_TIE convention of the algorithm dispatch.
-_COMPRESS_MARGIN = 0.9
 
 
 @dataclass(frozen=True)
@@ -518,9 +513,8 @@ def _best_executor(
 ) -> tuple[str, tuple[NodePlan, ...], float, float | None]:
     """Cost-argmin executor for one schedule among ``candidates``.
 
-    Exact kinds compete head-to-head (ties resolve to the earlier, plainer
-    kind); ``compressed`` changes numerics, so it must beat the best exact
-    kind by >10% (``_COMPRESS_MARGIN``).  When every candidate's node plan
+    Every kind is exact, so they compete head-to-head (ties resolve to the
+    earlier, plainer kind).  When every candidate's node plan
     is fully measured (autotune), the comparison runs over measured sweep
     seconds instead of the analytic predictions -- mixed sets stay on the
     analytic basis so measured CPU milliseconds never race nominal-constant
@@ -544,23 +538,8 @@ def _best_executor(
         if fully_measured
         else pred
     )
-
-    def result(ex: str) -> tuple[str, tuple[NodePlan, ...], float, float | None]:
-        meas = (
-            sum(np_.cost.measured_s for np_ in plans[ex]) if fully_measured else None
-        )
-        return ex, plans[ex], pred[ex], meas
-
-    exacts = [ex for ex in candidates if ex != "compressed"]
-    if not exacts:  # compressed was forced explicitly
-        return result(candidates[0])
-    best = exacts[0]
-    for ex in exacts[1:]:
-        if totals[ex] < totals[best]:
-            best = ex
-    if "compressed" in candidates and totals["compressed"] < _COMPRESS_MARGIN * totals[best]:
-        best = "compressed"
-    return result(best)
+    best = min(candidates, key=totals.__getitem__)
+    return best, plans[best], pred[best], totals[best] if fully_measured else None
 
 
 def _resolve_schedules(
@@ -602,13 +581,11 @@ def select_executor(
 
     Unsharded problems run locally.  Sharded plans compare the plain
     ``sharded`` executor against ``overlapping`` (communication hidden
-    behind chunked contractions) and ``compressed`` (int8 error-feedback
-    all-gather) on total predicted sweep seconds -- jointly with the
-    schedule shapes the strategy admits, exactly as
-    :func:`plan_sweep` does; ``compressed`` changes numerics, so it must
-    beat the best exact executor by >10% (``_COMPRESS_MARGIN``) -- ties
-    resolve to the exact executor.  Dimension-tree schedules compete on the
-    same footing: their partial contractions overlap and compress per node.
+    behind chunked contractions) on total predicted sweep seconds --
+    jointly with the schedule shapes the strategy admits, exactly as
+    :func:`plan_sweep` does; ties resolve to ``sharded``.  Both are exact.
+    Dimension-tree schedules compete on the same footing: their partial
+    contractions overlap per node.
     """
     return plan_sweep(
         problem, strategy, executor="auto", n_chunks=n_chunks,
@@ -639,8 +616,7 @@ def plan_sweep(
     cost).  Near-ties (within 10%) break toward the flat per-mode sweep,
     the shape the paper measured.  ``'dimtree'`` forces the classic binary
     tree (``split`` defaults to the balanced half); any other value forces
-    that algorithm on every mode of the flat schedule (the old ``method=``
-    passthrough, kept for the back-compat wrappers).
+    that algorithm on every mode of the flat schedule.
 
     ``schedule`` pins the tree shape regardless of strategy: a
     :class:`repro.plan.schedule.Schedule` built for this problem, or one of
@@ -649,7 +625,7 @@ def plan_sweep(
     ``executor='auto'`` additionally picks the executor kind by the same
     cost argmin (any (schedule, executor) pair is either costed or rejected
     by :func:`repro.plan.cost.validate_executor` -- tree schedules overlap
-    and compress per node like everything else); pass an explicit kind from
+    per node like everything else); pass an explicit kind from
     :data:`repro.plan.cost.EXECUTORS` to force one.  ``n_chunks`` sizes the
     overlapping executor's psum pipeline; ``serial_fractions`` threads
     calibrated per-executor overlap constants (from ``bench_mttkrp
@@ -754,7 +730,7 @@ def plan_sweep(
                 return None  # forced kind invalid on the alternate candidate
             candidates = (executor,)
         elif prob.mode_axes:
-            candidates = ("sharded", "overlapping", "compressed")
+            candidates = ("sharded", "overlapping")
         elif prob.batch_axes:
             # batch-parallel placements have no psums: only the plain
             # sharded executor applies (see validate_executor)

@@ -4,15 +4,16 @@ Per mode-n update (alternating least squares, paper Sec. 2.2):
     M   = MTTKRP(X, {U_k}, n)               (bottleneck; executor + plan decide how)
     H   = *_{k != n} (U_k^T U_k)            (Hadamard of Gram matrices)
     U_n = M @ inv(H) (by LU);  column-normalize -> lambda
-with the fit tracked through the factored identity reusing the last MTTKRP.
+with the fit tracked through the factored identity (no residual tensor):
+    ||X - Y||^2 = ||X||^2 - 2 <X, Y> + ||Y||^2
+    <X, Y>      = sum(M_last * (U_last * lambda))   (reuses the last MTTKRP)
+    ||Y||^2     = lambda^T ( *_k U_k^T U_k ) lambda
 
 The engine walks the plan's contraction schedule (:mod:`repro.plan.schedule`)
 node by node -- the flat per-mode sweep and every dimension-tree shape are
-the same walk over different trees.  This module replaces the four
-hand-written sweeps (``core.cpals.als_sweep``, ``core.dimtree.dimtree_sweep``,
-``dist.dist_mttkrp.dist_als_sweep`` and ``dist_dimtree_sweep``), which
-survive as thin wrappers building the corresponding plan + executor.  The
-Gram/Hadamard/solve/normalize/fit algebra exists ONLY here.
+the same walk over different trees.  The Gram/Hadamard/solve/normalize/fit
+algebra exists ONLY here; :mod:`repro.core` and :mod:`repro.dist` supply
+the contractions and never import this package.
 """
 
 from __future__ import annotations
@@ -27,18 +28,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.cpals import (
-    CPState,
-    fit_from_last_mttkrp,
-    grams,
-    hadamard_except,
-    normalize_columns,
-)
 from repro.core.dimtree import matrix_view, view_split
 from repro.core.tensor_ops import random_factors, tensor_norm
 
-from .executor import Executor, LocalExecutor, ShardedExecutor
-from .planner import SweepPlan, plan_sweep
+from .executor import Executor, LocalExecutor
+from .planner import SweepPlan
 from .problem import Problem
 from .schedule import ROOT, ContractionNode, pp_pairs as pp_pair_meta
 
@@ -59,17 +53,86 @@ _read_fits = np.asarray
 
 
 @dataclass
+class CPState:
+    """What :func:`cp_als` returns: the factors, the lambdas and the fit,
+    with the counters the benchmark reads."""
+
+    factors: list[Array]
+    weights: Array  # lambda, shape (C,) -- or (B, C) for batched problems
+    fit: Array  # scalar in [.., 1] -- or shape (B,) for batched problems
+    it: int = 0
+    # Exact (re-materializing) sweeps executed when the run used pairwise
+    # perturbation (Problem.pp_tol > 0); None for classic exact-only runs.
+    pp_exact_sweeps: int | None = None
+    # Times cp_als blocked the host on the device: the one read of each
+    # chunk's fits, which is its wait, and the read of pp_exact_sweeps.
+    host_syncs: int = 0
+    # Tensor-sized matrix views of the tensor cp_als built for the solve
+    # (once, before the first sweep); 0 where the sweeps read the tensor.
+    prepared_views: int = 0
+
+
+def grams(factors: Sequence[Array]) -> list[Array]:
+    """The Gram matrix ``U^T U`` of every factor.  Rank-polymorphic:
+    ``(I, C) -> (C, C)``, and ``(B, I, C) -> (B, C, C)``."""
+    return [jnp.swapaxes(u, -1, -2) @ u for u in factors]
+
+
+def hadamard_except(gs: Sequence[Array], n: int) -> Array:
+    """The elementwise product of every Gram in ``gs`` but the ``n``-th."""
+    out = None
+    for k, g in enumerate(gs):
+        if k == n:
+            continue
+        out = g if out is None else out * g
+    assert out is not None
+    return out
+
+
+def fit_from_last_mttkrp(
+    gs: Sequence[Array],
+    weights: Array,
+    m_last: Array,
+    last_factor: Array,
+    norm_x: Array,
+) -> Array:
+    """Fit via the factored identity, reusing the final mode's MTTKRP:
+    ||X - Y||^2 = ||X||^2 - 2 <X, Y> + ||Y||^2  with
+    <X, Y> = sum(M_last * (U_last * lambda)) and
+    ||Y|| ^2 = lambda^T ( *_k U_k^T U_k ) lambda.
+
+    Rank-polymorphic: with batched arguments (leading ``B`` axis on every
+    operand, ``norm_x`` of shape ``(B,)``) the return is the per-problem fit
+    vector ``(B,)``; unbatched it stays the classic scalar."""
+    n_modes = len(gs)
+    full_h = gs[-1] * hadamard_except(gs, n_modes - 1)
+    norm_y_sq = jnp.einsum("...c,...cd,...d->...", weights, full_h, weights)
+    inner = jnp.sum(
+        m_last * (last_factor * weights[..., None, :]), axis=(-2, -1)
+    )
+    resid_sq = jnp.maximum(norm_x**2 - 2.0 * inner + norm_y_sq, 0.0)
+    return 1.0 - jnp.sqrt(resid_sq) / norm_x
+
+
+def normalize_columns(u: Array, it: int) -> tuple[Array, Array]:
+    """Column norms -> lambda.  First sweep uses 2-norm, later sweeps use
+    max(1, norm) (the Tensor Toolbox convention that keeps lambdas stable).
+    Rank-polymorphic: norms are taken over the row axis (``-2``), so a
+    batched ``(B, I, C)`` factor yields ``(B, C)`` lambdas."""
+    norms = jnp.linalg.norm(u, axis=-2)
+    norms = jnp.where(it == 0, norms, jnp.maximum(norms, 1.0))
+    return u / norms[..., None, :], norms
+
+
+@dataclass
 class SweepState:
     """Pytree carried across sweeps: the tensor rides along unchanged so the
     jitted sweep is a pure ``state -> state`` function.
 
-    ``carry`` is executor-private state threaded through the sweep (e.g. the
-    per-mode error-feedback residuals of
-    :class:`repro.plan.executor.CompressedShardedExecutor`); ``None`` for
-    stateless executors.  ``grams`` carries the per-factor Gram matrices
-    ``U_k^T U_k`` across sweeps: each mode's update refreshes its own Gram,
-    so the next sweep starts from exact values without recomputing all N --
-    ``None`` (the single-shot default) recomputes them from the factors.
+    ``grams`` carries the per-factor Gram matrices ``U_k^T U_k`` across
+    sweeps: each mode's update refreshes its own Gram, so the next sweep
+    starts from exact values without recomputing all N -- ``None`` (the
+    single-shot default) recomputes them from the factors.
 
     ``pp`` is the pairwise-perturbation cache (:class:`PPState`) when the
     plan enabled PP sweeps, ``None`` otherwise -- and ``None`` keeps the
@@ -89,7 +152,6 @@ class SweepState:
     norm_x: Array
     it: Array
     fit: Array | float = 0.0
-    carry: Any = None
     grams: list[Array] | None = None
     pp: Any = None
     views: dict[int, Array] | None = None
@@ -98,8 +160,7 @@ class SweepState:
 jax.tree_util.register_pytree_node(
     SweepState,
     lambda s: (
-        (s.x, s.factors, s.weights, s.norm_x, s.it, s.fit, s.carry, s.grams, s.pp,
-         s.views),
+        (s.x, s.factors, s.weights, s.norm_x, s.it, s.fit, s.grams, s.pp, s.views),
         None,
     ),
     lambda _, c: SweepState(*c),
@@ -249,9 +310,7 @@ def _exact_sweep(
     factors = list(state.factors)
     weights = state.weights
     it = state.it
-    carry = state.carry
     views = state.views or {}
-    use_carry = hasattr(executor, "contract_carry")
     gs = list(state.grams) if state.grams is not None else grams(factors)
     m_last = None
 
@@ -269,10 +328,6 @@ def _exact_sweep(
                 out = executor.contract_view(
                     node, views[view_split(node.lo, node.hi, problem.ndim)], factors
                 )
-            elif use_carry:
-                out, carry = executor.contract_carry(
-                    node, src, factors, alg, carry, tiles=tiles, collective=coll
-                )
             else:
                 out = executor.contract(
                     node, src, factors, alg, tiles=tiles, collective=coll
@@ -288,7 +343,7 @@ def _exact_sweep(
         fit = fit_from_last_mttkrp(gs, weights, m_last, factors[-1], state.norm_x)
     return SweepState(
         x=x, factors=factors, weights=weights, norm_x=state.norm_x, it=it, fit=fit,
-        carry=carry, grams=gs, pp=state.pp, views=state.views,
+        grams=gs, pp=state.pp, views=state.views,
     )
 
 
@@ -331,7 +386,7 @@ def _pp_sweep(
     )
     return SweepState(
         x=state.x, factors=factors, weights=weights, norm_x=state.norm_x,
-        it=it, fit=fit, carry=state.carry, grams=gs, pp=new_pp, views=state.views,
+        it=it, fit=fit, grams=gs, pp=new_pp, views=state.views,
     )
 
 
@@ -339,10 +394,10 @@ def _with_payload(state: SweepState, payload) -> SweepState:
     """Rebuild a :class:`SweepState` from the sweep-mutable payload tuple
     (the ``lax.cond`` outputs of the PP gate), keeping the tensor, the PP
     cache, and the other sweep-invariant fields from ``state``."""
-    factors, weights, fit, carry, gs = payload
+    factors, weights, fit, gs = payload
     return SweepState(
         x=state.x, factors=list(factors), weights=weights, norm_x=state.norm_x,
-        it=state.it, fit=fit, carry=carry, grams=gs, pp=state.pp, views=state.views,
+        it=state.it, fit=fit, grams=gs, pp=state.pp, views=state.views,
     )
 
 
@@ -362,11 +417,6 @@ def als_sweep(
     as fresh as standard ALS requires -- any valid schedule reproduces the
     standard iterates (see :mod:`repro.plan.schedule`).
 
-    Executors implementing the carry extension (``contract_carry``; see the
-    :class:`repro.plan.executor.Executor` protocol) have their private state
-    -- e.g. per-node error-feedback residuals -- threaded through
-    ``state.carry`` across every node contraction, partials included.
-
     Gram matrices ride ``state.grams`` when the caller threads them across
     sweeps (``cp_als`` does): each update refreshes exactly the changed
     factor's Gram, so carried Grams are identical to recomputing all N from
@@ -384,7 +434,7 @@ def als_sweep(
     Gate structure: cond outputs cannot alias their operands, so everything
     routed through a cond's output is a fresh buffer every sweep.  The
     per-sweep gate therefore carries only what a sweep actually rewrites --
-    factors, weights, fit, carry, grams; the tensor (and norm_x/it) and the
+    factors, weights, fit, grams; the tensor (and norm_x/it) and the
     pair cache stay outside.  The cache (``ref``/``pairs``/``base``, by far
     the largest conditional state) crosses exactly one minimal cond whose
     predicate -- "this was an exact sweep whose own step settled under the
@@ -411,7 +461,7 @@ def _gated_sweep(
     use_pp = jnp.max(pp0.drift) < problem.pp_tol
 
     def _payload(st: SweepState):
-        return (st.factors, st.weights, st.fit, st.carry, st.grams)
+        return (st.factors, st.weights, st.fit, st.grams)
 
     def exact_branch(payload):
         out = _exact_sweep(problem, plan, executor, _with_payload(state, payload))
@@ -458,50 +508,10 @@ def _gated_sweep(
     out = _with_payload(state, payload)
     return SweepState(
         x=out.x, factors=out.factors, weights=out.weights, norm_x=out.norm_x,
-        it=out.it, fit=out.fit, carry=out.carry, grams=out.grams,
+        it=out.it, fit=out.fit, grams=out.grams,
         pp=PPState(ref=ref, pairs=pairs, base=base, drift=drift, n_exact=n_exact),
         views=out.views,
     )
-
-
-def legacy_sweep(
-    x: Array,
-    factors: Sequence[Array],
-    weights: Array,
-    norm_x: Array,
-    it,
-    *,
-    strategy: str,
-    normalize: bool = True,
-    split: int | None = None,
-    mode_axes=None,
-    mesh=None,
-) -> tuple[list[Array], Array, Array]:
-    """The one bridge behind the pre-redesign sweep signatures.
-
-    Builds the Problem/plan/executor for an old-style ``(x, factors,
-    weights, norm_x, it)`` call -- sharded when ``mesh`` is given -- runs
-    the engine, and returns the historical ``(factors, weights, fit)``
-    triple.  All four back-compat wrappers delegate here so the legacy
-    plumbing exists once.
-    """
-    problem = Problem.from_tensor(
-        x, factors[0].shape[1], mode_axes=mode_axes, mesh=mesh
-    )
-    # legacy wrappers are frozen on the exact executors AND the pre-schedule
-    # tree shapes (flat per-mode, or the binary split for dimtree): plan and
-    # execution must keep matching the pre-redesign behavior.
-    plan = plan_sweep(
-        problem, strategy=strategy, split=split, normalize=normalize,
-        executor="sharded" if mesh is not None else "local",
-        schedule=None if strategy == "dimtree" else "flat",
-    )
-    executor = ShardedExecutor(mesh, mode_axes) if mesh is not None else LocalExecutor()
-    state = SweepState(
-        x=x, factors=list(factors), weights=weights, norm_x=norm_x, it=jnp.asarray(it)
-    )
-    out = als_sweep(problem, plan, executor, state)
-    return out.factors, out.weights, out.fit
 
 
 def _reads_view(node: ContractionNode) -> bool:
@@ -541,39 +551,36 @@ def prepare_operands(x: Array, *, splits: tuple[int, ...], batched: bool):
 
 def sweep_chunk(plan: SweepPlan, executor: Executor, donate: tuple[int, ...] = ()):
     """The jitted program one :func:`cp_als` dispatch runs:
-    ``chunk(x, norm_x, it0, factors, weights, gs, carry, pp, views=None, *,
+    ``chunk(x, norm_x, it0, factors, weights, gs, pp, views=None, *,
     length)`` runs ``length`` sweeps under ``lax.scan`` and returns
-    ``(factors, weights, gs, carry, pp, it, fits, fit)``: ``it`` is
+    ``(factors, weights, gs, pp, it, fits, fit)``: ``it`` is
     ``it0 + length``, the next chunk's ``it0``, and ``fit`` the last
     sweep's entry of the per-sweep ``fits``, so the host loop takes no
     slice of ``fits`` and sends no counter to the device.
 
     Only the evolving buffers go out (returning ``x`` would make XLA emit a
     full-tensor copy every chunk); ``donate`` names the arguments donated
-    in, so off-CPU backends update factors/Grams/carry/PP-cache in place.
+    in, so off-CPU backends update factors/weights/Grams/PP-cache in place.
     With ``views`` the root partials read them, and ``x`` is ``None``
     unless a root leaf or the PP cache reads it.
     """
     problem = plan.problem
 
-    def _chunk(x, norm_x, it0, factors, weights, gs, carry, pp, views=None, *, length):
+    def _chunk(x, norm_x, it0, factors, weights, gs, pp, views=None, *, length):
         def body(c, _):
-            factors, weights, gs, carry, pp, it = c
+            factors, weights, gs, pp, it = c
             state = SweepState(
                 x=x, factors=factors, weights=weights, norm_x=norm_x,
-                it=it, carry=carry, grams=gs, pp=pp, views=views,
+                it=it, grams=gs, pp=pp, views=views,
             )
             out = als_sweep(problem, plan, executor, state)
-            return (
-                (out.factors, out.weights, out.grams, out.carry, out.pp, it + 1),
-                out.fit,
-            )
+            return (out.factors, out.weights, out.grams, out.pp, it + 1), out.fit
 
-        init = (factors, weights, gs, carry, pp, it0)
-        (factors, weights, gs, carry, pp, it), fits = jax.lax.scan(
+        init = (factors, weights, gs, pp, it0)
+        (factors, weights, gs, pp, it), fits = jax.lax.scan(
             body, init, None, length=length
         )
-        return factors, weights, gs, carry, pp, it, fits, fits[-1]
+        return factors, weights, gs, pp, it, fits, fits[-1]
 
     return jax.jit(_chunk, static_argnames=("length",), donate_argnums=donate)
 
@@ -595,18 +602,16 @@ def cp_als(
 ) -> CPState:
     """THE CP-ALS driver: init, sync-free chunked sweep loop, convergence stop.
 
-    Replaces both ``core.cpals.cp_als`` and ``dist.dist_mttkrp.dist_cp_als``
-    (which wrap it).  ``executor`` defaults to :class:`LocalExecutor` for
-    local plans; for sharded plans pass the matching instance (build one
-    from ``plan.executor`` with :func:`repro.plan.executor.make_executor`)
-    -- ``prepare`` places the tensor/factors before the loop, and executors
-    with carry state (compressed collectives) have it initialized here and
-    threaded across iterations.  Per-iteration wall times go through
-    ``callback(it, fit, seconds)`` so benchmarks can record them.
+    ``executor`` defaults to :class:`LocalExecutor` for local plans; for
+    sharded plans pass the matching instance (build one from
+    ``plan.executor`` with :func:`repro.plan.executor.make_executor`) --
+    ``prepare`` places the tensor/factors before the loop.  Per-iteration
+    wall times go through ``callback(it, fit, seconds)`` so benchmarks can
+    record them.
 
     ``sweeps_per_sync`` makes the hot loop sync-free: each device dispatch
     runs that many sweeps inside one compiled ``lax.scan`` (factor, Gram,
-    weight and carry buffers donated off-CPU) and the host blocks exactly
+    weight and PP-cache buffers donated off-CPU) and the host blocks exactly
     once per chunk -- the per-sweep iterates are bitwise identical to
     ``sweeps_per_sync=1``, only the host round-trips change (one per chunk
     instead of one per sweep).  That one block is the chunk's sync: the read
@@ -695,18 +700,14 @@ def cp_als(
         # pass caller arrays through unchanged (LocalExecutor), so donation is
         # keyed off the backend (a no-op-with-warning on CPU) and caller-owned
         # init_factors are copied once rather than invalidated under the caller.
-        donate = (3, 4, 5, 6, 7) if jax.default_backend() != "cpu" else ()
+        # The donated arguments of the chunk: factors, weights, gs, pp.
+        donate = (3, 4, 5, 6) if jax.default_backend() != "cpu" else ()
         if donate and init_factors is not None:
             factors = [jnp.array(u, copy=True) for u in factors]
         lead = (problem.batch,) if problem.batched else ()
         weights = jnp.ones(lead + (problem.rank,), x.dtype)
         splits = view_splits(plan, executor)
         norm_x, views = prepare_operands(x, splits=splits, batched=problem.batched)
-        carry = (
-            executor.init_carry(plan, x, factors)
-            if hasattr(executor, "init_carry")
-            else None
-        )
         # Grams are computed once here and carried across sweeps (each update
         # refreshes exactly the changed factor's Gram inside the sweep), at
         # the sweep's precision: the first sweep's updates solve with them,
@@ -745,9 +746,9 @@ def cp_als(
         length = min(k, n_iters - it)
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("cp_als.dispatch"):
-            factors, weights, gs, carry, pp, it_dev, fits, fit = chunk(
-                x_arg, norm_x, it_dev, factors, weights, gs, carry, pp,
-                views, length=length,
+            factors, weights, gs, pp, it_dev, fits, fit = chunk(
+                x_arg, norm_x, it_dev, factors, weights, gs, pp, views,
+                length=length,
             )
             fits.copy_to_host_async()
         with jax.profiler.TraceAnnotation("cp_als.wait"):

@@ -13,15 +13,10 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.core import CPConfig, cp_als, cp_full, mttkrp_einsum, random_factors, random_tensor  # noqa: E402
-from repro.dist.collectives import (  # noqa: E402
-    compressed_psum,
-    init_error_state,
-    make_compressed_dp_step,
-)
-from repro.dist.dist_mttkrp import dist_cp_als, dist_mttkrp, shard_problem  # noqa: E402
-from repro.launch import mesh as meshlib  # noqa: E402
+from repro.core import cp_full, mttkrp_einsum, random_factors, random_tensor  # noqa: E402
+from repro.dist.dist_mttkrp import dist_mttkrp, shard_problem  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
+from repro.plan import Problem, cp_als, make_executor, plan_sweep  # noqa: E402
 
 
 def case_dist_mttkrp():
@@ -73,19 +68,21 @@ def case_dist_cpals():
     planted = random_factors(key, (12, 8, 8), 3)
     x = cp_full(None, planted)
     mode_axes = {0: "data", 1: "model"}
-    fs, w, fit = dist_cp_als(x, rank=3, mode_axes=mode_axes, mesh=mesh, n_iters=120, tol=1e-9)
+    problem = Problem.from_tensor(x, 3, mode_axes=mode_axes, mesh=mesh)
+    plan = plan_sweep(problem, executor="sharded", schedule="flat")
+    ex = make_executor(plan.executor, mesh, mode_axes)
+    fit = cp_als(x, plan, executor=ex, n_iters=120, tol=1e-9).fit
     assert float(fit) > 0.99, float(fit)
     # cross-check against the single-device driver
-    st = cp_als(x, CPConfig(rank=3, n_iters=120, tol=1e-9, seed=0))
+    st = cp_als(x, plan_sweep(Problem.from_tensor(x, 3)), n_iters=120, tol=1e-9, seed=0)
     assert abs(float(fit) - float(st.fit)) < 5e-3, (float(fit), float(st.fit))
     print("dist_cpals OK fit=", float(fit))
 
 
 def case_dist_dimtree():
     """Distributed dimension-tree sweep == single-device standard ALS sweep."""
-    from repro.core.cpals import als_sweep
     from repro.core.tensor_ops import tensor_norm
-    from repro.dist.dist_mttkrp import dist_dimtree_sweep, shard_problem
+    from repro.plan import LocalExecutor, ShardedExecutor, SweepState, als_sweep
 
     mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(5)
@@ -95,16 +92,22 @@ def case_dist_dimtree():
     xs, fs = shard_problem(x, factors, mode_axes, mesh)
     w = jnp.ones((3,), x.dtype)
     norm_x = tensor_norm(x)
+    local = Problem.from_tensor(x, 3)
+    plan_ref = plan_sweep(local, strategy="2step", schedule="flat")
+    sharded = Problem.from_tensor(x, 3, mode_axes=mode_axes, mesh=mesh)
+    plan_dist = plan_sweep(sharded, strategy="dimtree", executor="sharded")
+    ex = ShardedExecutor(mesh, mode_axes)
+
+    def sweep(problem, plan, ex, x, fs, w, it):
+        st = SweepState(x=x, factors=list(fs), weights=w, norm_x=norm_x, it=jnp.asarray(it))
+        out = als_sweep(problem, plan, ex, st)
+        return out.factors, out.weights, out.fit
 
     f_ref, w_ref = list(factors), w
     f_dist, w_dist = fs, w
     for it in range(3):
-        f_ref, w_ref, fit_ref = als_sweep(
-            x, f_ref, w_ref, norm_x, jnp.asarray(it), method="2step", normalize=True
-        )
-        f_dist, w_dist, fit_dist = dist_dimtree_sweep(
-            xs, f_dist, w_dist, norm_x, jnp.asarray(it), mode_axes, mesh
-        )
+        f_ref, w_ref, fit_ref = sweep(local, plan_ref, LocalExecutor(), x, f_ref, w_ref, it)
+        f_dist, w_dist, fit_dist = sweep(sharded, plan_dist, ex, xs, f_dist, w_dist, it)
         for a, b in zip(f_ref, f_dist):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-4
@@ -209,19 +212,15 @@ def case_schedule_overlap():
     """Overlapped-dimtree == sharded-dimtree BITWISE: every node of the
     binary schedule is a partial contraction whose chunked per-slab psums
     cover disjoint rows of the same reduction, so overlap changes the
-    schedule, never a bit of the result.  Also exercises the chain schedule
-    and the compressed executor on tree partials (error-feedback carry)."""
+    schedule, never a bit of the result.  Also exercises the chain
+    schedule."""
     from repro.core.tensor_ops import tensor_norm
     from repro.plan import (
-        CompressedShardedExecutor,
         OverlappingExecutor,
-        Problem,
         ShardedExecutor,
         SweepState,
         als_sweep,
         enumerate_schedules,
-        make_executor,
-        plan_sweep,
         select_executor,
     )
 
@@ -240,7 +239,7 @@ def case_schedule_overlap():
     # the planner enumerates trees and may pair a dimtree schedule with any
     # executor; the restriction is gone
     assert sum(not s.is_flat for s in enumerate_schedules(problem)) >= 3
-    assert select_executor(problem, "dimtree") in ("overlapping", "compressed")
+    assert select_executor(problem, "dimtree") == "overlapping"
     plan = plan_sweep(problem, strategy="dimtree", executor="overlapping")
     assert plan.executor == "overlapping" and plan.kind == "dimtree"
 
@@ -267,106 +266,7 @@ def case_schedule_overlap():
     out_b = als_sweep(problem, chain_plan, OverlappingExecutor(mesh, mode_axes, n_chunks=3), st_b)
     for a, b in zip(out_a.factors, out_b.factors):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
-
-    # compressed executor on the dimtree schedule: per-node residual carry
-    # threads through the sweep and converges to the exact fit
-    plan_c = plan_sweep(problem, strategy="dimtree", executor="compressed")
-    ex_c = make_executor("compressed", mesh, mode_axes)
-    assert isinstance(ex_c, CompressedShardedExecutor)
-    carry = ex_c.init_carry(plan_c, xs, fs)
-    assert carry  # at least one node needs a psum on this mapping
-    st_c = SweepState(x=xs, factors=list(fs), weights=w, norm_x=norm_x, it=jnp.asarray(0), carry=carry)
-    out_c = als_sweep(problem, plan_c, ex_c, st_c)
-    assert out_c.carry is not carry  # residuals were updated
-    for a, b in zip(out_c.factors, f_sh):
-        assert np.all(np.isfinite(np.asarray(a)))
-        assert np.asarray(a).shape == np.asarray(b).shape
     print("schedule_overlap OK")
-
-
-def case_compressed_cpals():
-    """Error-feedback convergence: CP-ALS with the compressed factor
-    all-reduce reaches the uncompressed fit within tolerance on a fixed
-    iteration budget (seeded planted problem)."""
-    mesh = make_mesh((2, 4), ("data", "model"))
-    planted = random_factors(jax.random.PRNGKey(7), (8, 8, 8), 3)
-    x = cp_full(None, planted)
-    mode_axes = {0: "data", 1: "model"}
-    budget = 40
-    f_e, w_e, fit_exact = dist_cp_als(
-        x, rank=3, mode_axes=mode_axes, mesh=mesh, n_iters=budget, tol=1e-9,
-        executor="sharded",
-    )
-    f_c, w_c, fit_comp = dist_cp_als(
-        x, rank=3, mode_axes=mode_axes, mesh=mesh, n_iters=budget, tol=1e-9,
-        executor="compressed",
-    )
-    assert float(fit_comp) > 0.75, float(fit_comp)
-    assert abs(float(fit_comp) - float(fit_exact)) < 2e-2, (
-        float(fit_comp), float(fit_exact),
-    )
-    # selection surface: a few-participant, collective-bound problem picks
-    # compressed; this planted shape keeps an exact executor
-    from repro.plan import Problem, select_executor
-
-    p2 = Problem(shape=(2, 64, 2), rank=4096, mode_axes={0: "data"}, axis_sizes={"data": 2})
-    assert select_executor(p2) == "compressed", select_executor(p2)
-    print("compressed_cpals OK", float(fit_comp), float(fit_exact))
-
-
-def case_compressed_psum():
-    mesh = make_mesh((8,), ("data",))
-    from jax import shard_map
-
-    x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8) / 7.0
-
-    def f(x_blk, err):
-        s, ne = compressed_psum(x_blk[0], "data", err[0])
-        return s[None], ne[None]
-
-    from jax.sharding import PartitionSpec as P
-
-    err0 = jnp.zeros((8, 8), jnp.float32)
-    s, ne = shard_map(
-        f, mesh=mesh, in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data")),
-        check_vma=False,
-    )(x, err0)
-    exact = jnp.sum(x, 0)
-    # every replica row should approximate the exact sum within int8 step
-    scale = float(jnp.max(jnp.abs(x))) / 127.0 * 8
-    for r in range(8):
-        np.testing.assert_allclose(np.asarray(s[r]), np.asarray(exact), atol=scale + 1e-5)
-    # error feedback: residuals bounded by one quantization step
-    assert float(jnp.max(jnp.abs(ne))) <= float(jnp.max(jnp.abs(x))) / 127.0 + 1e-6
-    print("compressed_psum OK")
-
-
-def case_compressed_dp_trainer():
-    from repro.configs import get_config
-    from repro.data.pipeline import DataConfig, SyntheticLM
-    from repro.models import build_model
-    from repro.train.optimizer import OptConfig, init_opt_state
-
-    mesh = make_mesh((8, 1), ("data", "model"))
-    cfg = get_config("olmo-1b").reduced()
-    model = build_model(cfg)
-    with meshlib.use_mesh(mesh):
-        params = model.init(jax.random.PRNGKey(0))
-        data = SyntheticLM(DataConfig(vocab=32, seq_len=16, global_batch=8))
-        opt_cfg = OptConfig(lr=3e-3, warmup_steps=0, total_steps=100)
-        step_c = jax.jit(make_compressed_dp_step(model, opt_cfg, mesh, compress=True))
-        step_e = jax.jit(make_compressed_dp_step(model, opt_cfg, mesh, compress=False))
-        pc = pe = params
-        oc = oe = init_opt_state(params)
-        err = init_error_state(params)
-        for i in range(8):
-            batch = {k: jnp.asarray(v) for k, v in data.batch(i).items()}
-            pc, oc, err, mc = step_c(pc, oc, err, batch)
-            pe, oe, _, me = step_e(pe, oe, jax.tree.map(jnp.zeros_like, err), batch)
-        lc, le = float(mc["loss"]), float(me["loss"])
-        assert np.isfinite(lc) and np.isfinite(le)
-        assert abs(lc - le) < 0.3, (lc, le)  # compressed tracks exact closely
-    print("compressed_dp OK", lc, le)
 
 
 def case_pp_sharded():
@@ -427,10 +327,8 @@ def case_hierarchical_psum():
 
     ``hierarchical_psum`` (reduce-scatter within the node + cross-node psum
     of the shard + all-gather back) is an exact regrouping of the same sum,
-    so every exact path -- the raw collective, ``dist_mttkrp``, and the
-    overlapped variant -- must match its flat twin allclose; the compressed
-    variant keeps its error-feedback carry semantics (residual shape and
-    bound) while compressing only the cross-node stage.  Ends with the
+    so every path -- the raw collective, ``dist_mttkrp``, and the
+    overlapped variant -- must match its flat twin allclose.  Ends with the
     acceptance sweep: a ``plan_sweep(executor="auto")`` plan over the
     two-level problem executes hierarchical node collectives and matches
     the flat-psum plan's factors allclose.
@@ -439,11 +337,7 @@ def case_hierarchical_psum():
     from jax.sharding import PartitionSpec as P
 
     from repro.dist.collectives import hierarchical_psum
-    from repro.dist.dist_mttkrp import (
-        dist_mttkrp_compressed,
-        dist_mttkrp_overlapped,
-        init_mttkrp_error_state,
-    )
+    from repro.dist.dist_mttkrp import dist_mttkrp_overlapped
     from repro.launch.mesh import make_node_mesh
     from repro.plan import Problem, SweepState, als_sweep, make_executor, plan_sweep
 
@@ -485,28 +379,6 @@ def case_hierarchical_psum():
         np.testing.assert_allclose(
             np.asarray(ov), np.asarray(ref), rtol=1e-5, atol=1e-6, err_msg=f"ov mode {n}"
         )
-
-    # compressed + hierarchical: intra-node stage exact, cross-node stage
-    # int8 error-feedback -- output within one quantization step of exact,
-    # residual carry keeps its shape and stays bounded across a second call
-    n = 1
-    err = init_mttkrp_error_state(x.shape, 7, mode_axes, mesh)[n]
-    exact = dist_mttkrp(xs, fs, n, mode_axes, mesh)
-    out_c, err1 = dist_mttkrp_compressed(
-        xs, fs, n, mode_axes, mesh, err,
-        collective="hierarchical", node_axis="device",
-    )
-    assert err1.shape == err.shape, (err1.shape, err.shape)
-    scale = float(jnp.max(jnp.abs(exact))) / 127.0 * 8
-    np.testing.assert_allclose(np.asarray(out_c), np.asarray(exact), atol=scale + 1e-5)
-    out_c2, err2 = dist_mttkrp_compressed(
-        xs, fs, n, mode_axes, mesh, err1,
-        collective="hierarchical", node_axis="device",
-    )
-    assert err2.shape == err.shape
-    # error feedback: second pass corrects toward exact, residual bounded
-    np.testing.assert_allclose(np.asarray(out_c2), np.asarray(exact), atol=scale + 1e-5)
-    assert float(jnp.max(jnp.abs(err2))) <= 2.1 * float(jnp.max(jnp.abs(exact))) / 127.0 + 1e-6
 
     # acceptance sweep: auto plan on the two-level problem (hierarchical
     # node collectives) == the same plan forced flat, allclose factors
@@ -555,9 +427,6 @@ if __name__ == "__main__":
         "elastic_restore": case_elastic_restore,
         "overlap_mttkrp": case_overlap_mttkrp,
         "schedule_overlap": case_schedule_overlap,
-        "compressed_cpals": case_compressed_cpals,
-        "compressed_psum": case_compressed_psum,
-        "compressed_dp": case_compressed_dp_trainer,
         "pp_sharded": case_pp_sharded,
         "hierarchical_psum": case_hierarchical_psum,
     }[sys.argv[1]]()

@@ -220,6 +220,37 @@ def test_autotune_recalibrates_serial_fractions_from_cache():
     assert dict(forced.serial_fractions) == {"overlapping": 0.25}
 
 
+def test_cache_entries_of_a_removed_executor_are_ignored(tmp_path):
+    """A cache file written while an int8 "compressed" executor existed
+    still loads, and plans exactly as the same file without those rows:
+    keys of an executor the planner no longer has are never looked up."""
+    from repro.plan import EXECUTORS, enumerate_schedules
+
+    problem = Problem(
+        shape=(8, 16, 16), rank=5, mode_axes={0: "data"}, axis_sizes={"data": 2},
+    )
+    rows = []
+    for kind in ("sharded", "overlapping"):
+        for sched in enumerate_schedules(problem):
+            plan = plan_sweep(problem, schedule=sched, executor=kind)
+            for node in sched.walk():
+                alg = plan.node_plan(node.id).algorithm
+                rows.append({"key": node_key(node, alg, kind), "measured_s": 1e-3})
+    stale = [
+        dict(r, key="compressed|" + r["key"].split("|", 1)[1], measured_s=1e-6)
+        for r in rows
+    ]
+    plans = []
+    for name, nodes in (("exact", rows), ("stale", rows + stale)):
+        path = tmp_path / f"{name}.json"
+        TuningCache(path).put(problem_key(problem), {"nodes": nodes, "tiles": {}})
+        cache = TuningCache(path)  # loaded back from disk
+        assert len(cache.get(problem_key(problem))["nodes"]) == len(nodes)
+        plans.append(plan_sweep(problem, strategy="autotune", tuning_cache=cache))
+    assert plans[1].executor in EXECUTORS
+    assert plans[1].describe() == plans[0].describe()
+
+
 # ------------------------------------------------- sync-free chunked driver
 def _planted(shape=(10, 8, 6), rank=2, seed=4):
     planted = random_factors(jax.random.PRNGKey(seed), shape, rank)
@@ -297,7 +328,7 @@ def test_grams_carried_across_sweeps_match_recompute():
     """SweepState.grams threading is exact: a sweep fed the previous sweep's
     Grams produces bitwise the state of one fed nothing (which recomputes),
     and the emitted Grams always equal grams(out.factors)."""
-    from repro.core.cpals import grams
+    from repro.plan.sweep import grams
     from repro.core import tensor_norm
     from repro.plan import LocalExecutor, SweepState, als_sweep
 

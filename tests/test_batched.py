@@ -137,28 +137,6 @@ def test_mode_parallel_batched_equals_local():
             )
 
 
-@pytest.mark.skipif(N_DEV < 2, reason="needs a multi-device runtime")
-def test_compressed_batched_tracks_fit():
-    """The int8 error-feedback collective threads batched residuals: the
-    compressed run's per-problem fits track the exact ones."""
-    B, shape, rank = 4, (8, 8, 6), 4
-    x, init = _fleet(B, shape, rank, seed=11)
-    mesh = make_mesh((N_DEV,), ("s",))
-    mode_axes = {0: "s"}
-    prob = Problem(
-        shape=shape, rank=rank, batch=B,
-        mode_axes=mode_axes, axis_sizes={"s": N_DEV},
-    )
-    plan = plan_sweep(prob, executor="compressed")
-    ex = make_executor("compressed", mesh, mode_axes)
-    st = cp_als(x, plan, executor=ex, n_iters=4, tol=0.0, init_factors=init)
-    st_lo = cp_als(x, plan_sweep(Problem.from_tensor(x, rank, batch=B)),
-                   n_iters=4, tol=0.0, init_factors=init)
-    np.testing.assert_allclose(
-        np.asarray(st.fit), np.asarray(st_lo.fit), rtol=0.05, atol=0.05
-    )
-
-
 # -------------------------------------------------------- placement argmin
 def test_plan_sweep_selects_batch_parallel_for_fleet():
     """Acceptance: for a fleet of small tensors given mode-parallel, the

@@ -10,16 +10,17 @@ import pytest
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core import mttkrp, mttkrp_einsum, random_factors, random_tensor
-from repro.core.cpals import als_sweep
 from repro.core.tensor_ops import tensor_norm
-from repro.dist.collectives import compressed_psum, init_error_state
-from repro.dist.dist_mttkrp import (
-    dist_als_sweep,
-    dist_dimtree_sweep,
-    dist_mttkrp,
-    shard_problem,
-)
+from repro.dist.dist_mttkrp import dist_mttkrp, shard_problem
 from repro.launch import mesh as meshlib
+from repro.plan import (
+    LocalExecutor,
+    Problem,
+    ShardedExecutor,
+    SweepState,
+    als_sweep,
+    plan_sweep,
+)
 
 
 @pytest.fixture(scope="module")
@@ -114,8 +115,25 @@ def test_dist_mttkrp_size1_mesh_reduces_to_core(mesh1, mode_axes, method):
         )
 
 
+def _sweep(x, fs, w, norm_x, strategy, mode_axes=None, mesh=None):
+    """One ``repro.plan.als_sweep`` from ``it=0``: on a ShardedExecutor over
+    ``mesh`` when one is given, else locally.  Non-tree strategies run the
+    flat schedule.  Returns ``(factors, weights, fit)``."""
+    problem = Problem.from_tensor(x, fs[0].shape[1], mode_axes=mode_axes, mesh=mesh)
+    plan = plan_sweep(
+        problem, strategy=strategy,
+        executor="sharded" if mesh is not None else "local",
+        schedule=None if strategy == "dimtree" else "flat",
+    )
+    ex = ShardedExecutor(mesh, mode_axes) if mesh is not None else LocalExecutor()
+    state = SweepState(x=x, factors=list(fs), weights=w, norm_x=norm_x, it=jnp.asarray(0))
+    out = als_sweep(problem, plan, ex, state)
+    return out.factors, out.weights, out.fit
+
+
 def test_dist_sweeps_match_single_device_sweep(mesh1):
-    """als + dimtree distributed sweeps == core als_sweep on a size-1 mesh."""
+    """Flat and dimtree sweeps on a ShardedExecutor == the local flat
+    sweep on a size-1 mesh."""
     mode_axes = {0: "data", 1: "model"}
     x = random_tensor(jax.random.PRNGKey(4), (6, 4, 4))
     fs = random_factors(jax.random.PRNGKey(5), x.shape, 3)
@@ -123,45 +141,11 @@ def test_dist_sweeps_match_single_device_sweep(mesh1):
     w = jnp.ones((3,), x.dtype)
     norm_x = tensor_norm(x)
 
-    f_ref, w_ref, fit_ref = als_sweep(
-        x, list(fs), w, norm_x, jnp.asarray(0), method="2step", normalize=True
-    )
-    f_als, _, fit_als = dist_als_sweep(
-        xs, fss, w, norm_x, jnp.asarray(0), mode_axes, mesh1, method="2step"
-    )
-    f_dt, _, fit_dt = dist_dimtree_sweep(
-        xs, fss, w, norm_x, jnp.asarray(0), mode_axes, mesh1
-    )
+    f_ref, w_ref, fit_ref = _sweep(x, fs, w, norm_x, "2step")
+    f_als, _, fit_als = _sweep(xs, fss, w, norm_x, "2step", mode_axes, mesh1)
+    f_dt, _, fit_dt = _sweep(xs, fss, w, norm_x, "dimtree", mode_axes, mesh1)
     for a, b, c in zip(f_ref, f_als, f_dt):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(float(fit_ref), float(fit_als), atol=1e-5)
     np.testing.assert_allclose(float(fit_ref), float(fit_dt), atol=1e-5)
-
-
-def test_compressed_psum_size1_axis_and_error_bound(mesh1):
-    x = jnp.linspace(-2.0, 3.0, 16).reshape(4, 4)
-    err0 = jnp.zeros_like(x)
-
-    def f(x_blk, e_blk):
-        return compressed_psum(x_blk, "data", e_blk)
-
-    s, ne = jax.shard_map(
-        f, mesh=mesh1, in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False
-    )(x, err0)
-    step = float(jnp.max(jnp.abs(x))) / 127.0
-    np.testing.assert_allclose(np.asarray(s), np.asarray(x), atol=step / 2 + 1e-6)
-    assert float(jnp.max(jnp.abs(ne))) <= step / 2 + 1e-6
-    # second round with carried residual stays bounded (error feedback)
-    s2, ne2 = jax.shard_map(
-        f, mesh=mesh1, in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False
-    )(x, ne)
-    assert float(jnp.max(jnp.abs(ne2))) <= float(jnp.max(jnp.abs(x + ne))) / 254.0 + 1e-6
-
-
-def test_init_error_state_shapes():
-    params = {"a": jnp.zeros((3, 2)), "b": {"c": jnp.zeros((4,))}}
-    err = init_error_state(params, n_shards=2)
-    assert err["a"].shape == (2, 3, 2)
-    assert err["b"]["c"].shape == (2, 4)
-    assert all(e.dtype == jnp.float32 for e in jax.tree.leaves(err))

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.cp_layers import (
+from repro.models.cp_layers import (
     compress_ffn,
     factorize_expert_stack,
     factorize_linear,

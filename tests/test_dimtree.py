@@ -5,20 +5,33 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.cpals import als_sweep
 from repro.core.dimtree import (
-    dimtree_sweep,
     mttkrp_from_partial,
     partial_mttkrp_left,
     partial_mttkrp_right,
 )
 from repro.core import mttkrp_einsum, random_factors, random_tensor, tensor_norm
+from repro.plan import LocalExecutor, Problem, SweepState, als_sweep, plan_sweep
 
 
 def _problem(shape, c=4, seed=0):
     x = random_tensor(jax.random.PRNGKey(seed), shape)
     factors = random_factors(jax.random.PRNGKey(seed + 1), shape, c)
     return x, factors
+
+
+def _sweep(x, factors, w, norm_x, it, strategy, schedule=None):
+    """One ``repro.plan.als_sweep`` on the plan of ``strategy``; returns
+    ``(factors, weights, fit)``."""
+    plan = plan_sweep(
+        Problem.from_tensor(x, factors[0].shape[1]), strategy=strategy,
+        schedule=schedule,
+    )
+    state = SweepState(
+        x=x, factors=list(factors), weights=w, norm_x=norm_x, it=jnp.asarray(it)
+    )
+    out = als_sweep(plan.problem, plan, LocalExecutor(), state)
+    return out.factors, out.weights, out.fit
 
 
 @pytest.mark.parametrize("shape", [(5, 6, 7), (4, 5, 6, 3), (3, 4, 2, 3, 4)])
@@ -49,9 +62,8 @@ def test_dimtree_sweep_matches_standard_als_exactly(shape):
     f_a, w_a = list(factors), w
     f_b, w_b = list(factors), w
     for it in range(4):
-        f_a, w_a, fit_a = als_sweep(x, f_a, w_a, norm_x, jnp.asarray(it),
-                                    method="2step", normalize=True)
-        f_b, w_b, fit_b = dimtree_sweep(x, f_b, w_b, norm_x, jnp.asarray(it))
+        f_a, w_a, fit_a = _sweep(x, f_a, w_a, norm_x, it, "2step", "flat")
+        f_b, w_b, fit_b = _sweep(x, f_b, w_b, norm_x, it, "dimtree")
         for ua, ub in zip(f_a, f_b):
             np.testing.assert_allclose(
                 np.asarray(ua), np.asarray(ub), rtol=2e-3, atol=2e-4
@@ -69,5 +81,5 @@ def test_dimtree_converges_on_planted():
     norm_x = tensor_norm(x)
     fit = 0.0
     for it in range(60):
-        factors, w, fit = dimtree_sweep(x, factors, w, norm_x, jnp.asarray(it))
+        factors, w, fit = _sweep(x, factors, w, norm_x, it, "dimtree")
     assert float(fit) > 0.99, float(fit)

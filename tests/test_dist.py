@@ -56,21 +56,6 @@ def test_schedule_overlapped_dimtree_bitwise_matches_sharded():
     assert "schedule_overlap OK" in out
 
 
-def test_compressed_cpals_reaches_exact_fit():
-    out = _run("compressed_cpals")
-    assert "compressed_cpals OK" in out
-
-
-def test_compressed_psum_error_feedback():
-    out = _run("compressed_psum")
-    assert "compressed_psum OK" in out
-
-
-def test_compressed_dp_trainer_tracks_exact():
-    out = _run("compressed_dp")
-    assert "compressed_dp OK" in out
-
-
 def test_pp_sharded_matches_local():
     out = _run("pp_sharded")
     assert "pp_sharded OK" in out

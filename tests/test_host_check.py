@@ -70,7 +70,7 @@ class _Recorder:
         self.calls.append({
             "it0": (int(it0), it0.dtype, it0.weak_type),
             "length": length,
-            "fits": np.asarray(out[6]),
+            "fits": np.asarray(out[5]),
         })
         return out
 

@@ -11,7 +11,8 @@ import pytest
 
 import repro.plan.sweep as sweeplib
 from repro.core import cp_full, random_factors, random_tensor
-from repro.core.cpals import CPState, grams
+from repro.plan import CPState
+from repro.plan.sweep import grams
 from repro.plan import Problem, cp_als, plan_sweep
 
 SPANS = ("cp_als.init", "cp_als.dispatch", "cp_als.wait", "cp_als.check")
@@ -29,7 +30,7 @@ def _chunk_op_names(schedule):
     st = cp_als(x, plan, n_iters=1, dispatch_cache=cache, dispatch_key=0)
     fs = list(st.factors)
     lowered = cache[0].lower(x, jnp.float32(1.0), jnp.asarray(0), fs, st.weights, grams(fs),
-                             None, None, length=1)
+                             None, length=1)
     return plan, re.findall(r'op_name="([^"]+)"', lowered.compile().as_text())
 
 

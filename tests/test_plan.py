@@ -1,6 +1,7 @@
 """The plan front door: dispatch, cost-model agreement, plan-vs-direct
 numerics for every algorithm, and sharded == local on a size-1 mesh."""
 
+import functools
 import json
 import re
 
@@ -16,17 +17,23 @@ from repro.core import (
     random_tensor,
     tensor_norm,
 )
-from repro.core.cpals import grams, hadamard_except, normalize_columns
 from repro.core.tensor_ops import dims_split
 from repro.plan import (
+    EXECUTORS,
     LocalExecutor,
     Problem,
-    ShardedExecutor,
     SweepState,
     als_sweep,
     cp_als,
+    make_executor,
     mode_cost,
     plan_sweep,
+)
+from repro.plan.sweep import (
+    fit_from_last_mttkrp,
+    grams,
+    hadamard_except,
+    normalize_columns,
 )
 
 # paper bench shapes: cubic, N in {3..6}, default (~16M) and paper (~750M) scale
@@ -142,8 +149,6 @@ def test_problem_validation_errors():
 # ----------------------------------------------------- plan-vs-direct sweeps
 def _reference_sweep(x, factors, weights, norm_x, it):
     """Independent oracle sweep: einsum MTTKRP + the textbook update algebra."""
-    from repro.core.cpals import fit_from_last_mttkrp
-
     factors = list(factors)
     gs = grams(factors)
     m_last = None
@@ -184,8 +189,12 @@ def test_planned_sweep_matches_reference_for_every_algorithm(strategy):
     np.testing.assert_allclose(float(out.fit), float(fit_ref), atol=1e-3)
 
 
-def test_sharded_executor_equals_local_on_size1_mesh():
-    """ShardedExecutor == LocalExecutor exactly when every axis has 1 device."""
+@pytest.mark.parametrize("kind", ["sharded", "overlapping"])
+def test_sharded_executor_equals_local_on_size1_mesh(kind):
+    """Every sharded executor the planner can return == LocalExecutor
+    when every axis has 1 device: all of them are exact.  ``sharded`` is
+    bitwise; ``overlapping`` runs its MTTKRP as slab GEMMs, which reorder
+    the float sum, so it agrees to rounding."""
     from repro.launch import mesh as meshlib
 
     mesh = meshlib.make_host_mesh(1, 1)
@@ -198,7 +207,7 @@ def test_sharded_executor_equals_local_on_size1_mesh():
     problem = Problem.from_tensor(x, rank, mode_axes=mode_axes, mesh=mesh)
     assert problem.local_shape == shape  # size-1 axes shard nothing
     for strategy in ("auto", "dimtree"):
-        plan = plan_sweep(problem, strategy=strategy)
+        plan = plan_sweep(problem, strategy=strategy, executor=kind)
         assert plan.total_cost()["collective_bytes"] == 0.0
 
         def state():
@@ -207,18 +216,20 @@ def test_sharded_executor_equals_local_on_size1_mesh():
                 norm_x=norm_x, it=jnp.asarray(0),
             )
 
-        sharded_ex = ShardedExecutor(mesh, mode_axes)
+        sharded_ex = make_executor(kind, mesh, mode_axes)
         xs, fss = sharded_ex.prepare(problem, x, factors)
         st_sharded = SweepState(
             x=xs, factors=fss, weights=w, norm_x=norm_x, it=jnp.asarray(0)
         )
         out_local = als_sweep(problem, plan, LocalExecutor(), state())
         out_sharded = als_sweep(problem, plan, sharded_ex, st_sharded)
+        if kind == "sharded":
+            check = np.testing.assert_array_equal
+        else:
+            check = functools.partial(np.testing.assert_allclose, rtol=1e-5, atol=1e-6)
         for a, b in zip(out_local.factors, out_sharded.factors):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        np.testing.assert_array_equal(
-            np.asarray(out_local.fit), np.asarray(out_sharded.fit)
-        )
+            check(np.asarray(a), np.asarray(b))
+        check(np.asarray(out_local.fit), np.asarray(out_sharded.fit))
 
 
 def test_plan_cp_als_driver_converges_with_dimtree():
@@ -289,43 +300,10 @@ def test_overlapping_cost_hides_all_but_one_chunk():
     assert ov.serial_fraction == pytest.approx(1.0 / 2)
 
 
-def test_compressed_cost_wire_ratio():
-    """int8 all-gather wire bytes: (p-1) * B/4 vs the ring's 2B(p-1)/p --
-    a 4x win at p=2 that vanishes at p=8."""
-    from repro.plan import compressed_allgather_bytes, ring_allreduce_bytes
-
-    B = 1e6
-    assert compressed_allgather_bytes(B, 1) == 0.0
-    assert compressed_allgather_bytes(B, 2) == pytest.approx(
-        ring_allreduce_bytes(B, 2) / 4, rel=1e-4
-    )
-    assert compressed_allgather_bytes(B, 8) == pytest.approx(
-        ring_allreduce_bytes(B, 8), rel=1e-4
-    )
-    # int8 payload is per-*element*: bf16 blocks compress 2x, not 4x
-    assert compressed_allgather_bytes(B, 2, itemsize=2.0) == pytest.approx(
-        B / 2, rel=1e-4
-    )
-    # and the executor cost threads the problem dtype through
-    from repro.plan import executor_mode_cost
-
-    for dtype, ratio in (("float32", 4.0), (jnp.bfloat16, 2.0), ("float64", 8.0)):
-        p = Problem(
-            shape=(2, 64, 2), rank=32, dtype=dtype,
-            mode_axes={0: "data"}, axis_sizes={"data": 2},
-        )
-        sh = executor_mode_cost(p, 1, "1step", "sharded")
-        co = executor_mode_cost(p, 1, "1step", "compressed")
-        # ring moves 2B(p-1)/p = B at p=2; the gather moves ~B/ratio
-        assert co.collective_bytes == pytest.approx(
-            sh.collective_bytes / ratio, rel=1e-2
-        )
-
-
 def test_select_executor_cost_argmin():
-    from repro.plan import EXECUTORS, select_executor
+    from repro.plan import select_executor
 
-    assert EXECUTORS == ("local", "sharded", "overlapping", "compressed")
+    assert EXECUTORS == ("local", "sharded", "overlapping")
     # unsharded -> local
     assert select_executor(Problem(shape=(8, 8, 8), rank=4)) == "local"
     # sharded with real collectives -> overlapping (hiding is free in the model)
@@ -334,16 +312,14 @@ def test_select_executor_cost_argmin():
         mode_axes={0: "data", 2: "model"}, axis_sizes={"data": 2, "model": 4},
     )
     assert select_executor(sharded) == "overlapping"
-    # few participants + collective-bound -> compressed beats exact by >10%
+    # few participants + collective-bound: still an exact executor
     bound = Problem(
         shape=(2, 64, 2), rank=4096, mode_axes={0: "data"}, axis_sizes={"data": 2}
     )
-    assert select_executor(bound) == "compressed"
+    assert select_executor(bound) == "overlapping"
     # dimtree plans compete on the same footing: their per-node psums can be
-    # overlapped or compressed too, so selection is a cost argmin, not a
-    # forced "sharded" (this tiny collective-bound tree clears the >10%
-    # compression margin; the exact executors remain selectable by force)
-    assert select_executor(sharded, "dimtree") in ("overlapping", "compressed")
+    # overlapped too, so selection is a cost argmin, not a forced "sharded"
+    assert select_executor(sharded, "dimtree") == "overlapping"
     assert plan_sweep(sharded, strategy="dimtree", executor="overlapping").executor == "overlapping"
     # plan_sweep agrees and stamps the choice on the plan
     for problem in (sharded, bound):
@@ -363,17 +339,17 @@ def test_plan_executor_validation():
     with pytest.raises(ValueError, match="cannot run this problem"):
         plan_sweep(Problem(shape=(4, 4), rank=2), executor="overlapping")
     # any (schedule, executor) pair is costed or rejected by the one shared
-    # predicate: dimtree + compressed/overlapping is now a valid pairing...
+    # predicate: dimtree + overlapping is a valid pairing...
     sharded3 = Problem(
         shape=(4, 4, 4), rank=2, mode_axes={0: "data"}, axis_sizes={"data": 2}
     )
-    plan = plan_sweep(sharded3, strategy="dimtree", executor="compressed")
-    assert plan.executor == "compressed" and plan.kind == "dimtree"
-    # ...and compressed on an unsharded problem is rejected with the same
+    plan = plan_sweep(sharded3, strategy="dimtree", executor="overlapping")
+    assert plan.executor == "overlapping" and plan.kind == "dimtree"
+    # ...and overlapping on an unsharded problem is rejected with the same
     # message the flat schedule gets
     with pytest.raises(ValueError, match="cannot run this problem"):
         plan_sweep(
-            Problem(shape=(4, 4, 4), rank=2), strategy="dimtree", executor="compressed"
+            Problem(shape=(4, 4, 4), rank=2), strategy="dimtree", executor="overlapping"
         )
     with pytest.raises(ValueError):
         plan_sweep(Problem(shape=(4, 4), rank=2), executor="nope")
@@ -389,13 +365,7 @@ def test_plan_executor_validation():
 
 def test_make_executor_builds_matching_kinds():
     from repro.launch import mesh as meshlib
-    from repro.plan import (
-        CompressedShardedExecutor,
-        LocalExecutor,
-        OverlappingExecutor,
-        ShardedExecutor,
-        make_executor,
-    )
+    from repro.plan import OverlappingExecutor, ShardedExecutor
 
     mesh = meshlib.make_host_mesh(1, 1)
     mode_axes = {0: "data"}
@@ -404,7 +374,28 @@ def test_make_executor_builds_matching_kinds():
     assert isinstance(sh, ShardedExecutor) and not isinstance(sh, OverlappingExecutor)
     ov = make_executor("overlapping", mesh, mode_axes, n_chunks=7)
     assert isinstance(ov, OverlappingExecutor) and ov.n_chunks == 7
-    assert isinstance(make_executor("compressed", mesh, mode_axes), CompressedShardedExecutor)
+
+
+def _compressed_plan():
+    return plan_sweep(
+        Problem(shape=(4, 4, 4), rank=2, mode_axes={0: "data"}, axis_sizes={"data": 2}),
+        executor="compressed",
+    )
+
+
+def _compressed_executor():
+    from repro.launch import mesh as meshlib
+
+    return make_executor("compressed", meshlib.make_host_mesh(1, 1), {0: "data"})
+
+
+@pytest.mark.parametrize("call", [_compressed_plan, _compressed_executor])
+def test_no_approximate_executor_is_reachable(call):
+    """The int8 error-feedback executor is gone: asking for it by name
+    fails with the unknown-executor error, which names the exact kinds."""
+    with pytest.raises(ValueError, match="unknown executor") as err:
+        call()
+    assert str(EXECUTORS) in str(err.value)
 
 
 # ------------------------------------------------------------- dispatch cache

@@ -15,12 +15,18 @@ import numpy as np
 import pytest
 
 from repro.core import cp_full, random_factors, random_tensor
-from repro.core.cpals import grams
 from repro.core.dimtree import matrix_view, partial_from_view, partial_mttkrp_range, view_split
 from repro.core.tensor_ops import tensor_norm
 from repro.plan import LocalExecutor, Problem, ShardedExecutor, cp_als, plan_sweep
 from repro.plan.schedule import build_schedule
-from repro.plan.sweep import SweepState, _pp_init, als_sweep, prepare_operands, view_splits
+from repro.plan.sweep import (
+    SweepState,
+    _pp_init,
+    als_sweep,
+    grams,
+    prepare_operands,
+    view_splits,
+)
 
 N_SWEEPS = 4
 

@@ -128,7 +128,7 @@ def test_validate_executor_is_the_single_predicate():
     validate_executor(sharded, "sharded")  # no raise
     validate_executor(local, "local")
     msgs = []
-    for problem, executor in ((sharded, "local"), (local, "overlapping"), (local, "compressed")):
+    for problem, executor in ((sharded, "local"), (local, "overlapping")):
         with pytest.raises(ValueError, match="cannot run this problem") as ei:
             validate_executor(problem, executor)
         msgs.append(str(ei.value))
@@ -173,9 +173,8 @@ def test_auto_enumerates_trees_and_can_pick_overlapping_dimtree():
     # flat sweep's four by far more than the 10% near-tie margin
     plan = plan_sweep(bench)
     assert plan.kind == "dimtree", plan.resolved_schedule.name
-    # sharded order-3 with an 8-way psum: compression is at wire parity
-    # (p=8) so the argmin lands on the exact overlapping executor -- for
-    # the dimtree schedule too
+    # sharded order-3 with an 8-way psum: the argmin lands on the
+    # overlapping executor -- for the dimtree schedule too
     p = Problem(
         shape=(8, 32, 8), rank=8, mode_axes={0: "shard"}, axis_sizes={"shard": 8}
     )
@@ -195,26 +194,6 @@ def test_plan_sweep_accepts_explicit_and_named_schedules():
         plan_sweep(Problem(shape=(5, 4, 6, 3), rank=4), schedule=custom)
     with pytest.raises(ValueError, match="unknown schedule"):
         plan_sweep(p, schedule="nope")
-
-
-def test_legacy_wrappers_keep_flat_and_binary_shapes():
-    """The frozen wrappers must not silently adopt tree schedules."""
-    from repro.plan import legacy_sweep  # noqa: F401  (the bridge they share)
-
-    x = random_tensor(jax.random.PRNGKey(0), (6, 5, 4))
-    factors = random_factors(jax.random.PRNGKey(1), x.shape, 3)
-    w = jnp.ones((3,), x.dtype)
-    norm_x = tensor_norm(x)
-    from repro.core.cpals import als_sweep as core_sweep
-    from repro.core.dimtree import dimtree_sweep
-
-    f1, w1, fit1 = core_sweep(
-        x, list(factors), w, norm_x, jnp.asarray(0), method="auto", normalize=True
-    )
-    f2, w2, fit2 = dimtree_sweep(x, list(factors), w, norm_x, jnp.asarray(0))
-    for a, b in zip(f1, f2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-4)
-    np.testing.assert_allclose(float(fit1), float(fit2), atol=1e-4)
 
 
 # ----------------------------------------- any schedule == flat ALS iterates

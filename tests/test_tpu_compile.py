@@ -166,11 +166,11 @@ def test_cp_als_sweep_reads_its_view_without_a_relayout(one_chip):
     assert not re.search(r"= f32\[225,59,200,200\]\S* (multiply|square)\(", entry)
 
     views = {2: _spec((225 * 59, 200 * 200), one_chip)}
-    chunk = sweep_chunk(plan, ex, donate=(3, 4, 5, 6, 7))
+    chunk = sweep_chunk(plan, ex, donate=(3, 4, 5, 6))
     compiled = chunk.lower(
         None, _spec((), one_chip), jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
         _factors(FMRI, one_chip), _spec((RANK,), one_chip),
-        [_spec((RANK, RANK), one_chip)] * len(FMRI), None, None, views, length=1,
+        [_spec((RANK, RANK), one_chip)] * len(FMRI), None, views, length=1,
     ).compile()
     assert _tensor_sized_relayouts(compiled.as_text(), size) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * size / 100
